@@ -244,8 +244,8 @@ def main():
     executor = Executor()
     corpus = read_corpus([src], num_blocks=4 * executor.num_local_workers)
     # One-time warmups outside the timed region (multi-GB runs amortize
-    # them): tokenizer construction (builds the native .so on first use),
-    # the device-link probe, and the jit masking kernel compile.
+    # them): tokenizer construction (builds the native .so on first use)
+    # and, when LDDL_BENCH_MASK=device, the jit masking kernel compile.
     from lddl_tpu.ops import mask_partition_device, resolve_mask_backend
     from lddl_tpu.preprocess.bert import _get_tokenizer
     try:  # pyarrow lazily imports pandas (when present) on first table

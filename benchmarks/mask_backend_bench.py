@@ -4,8 +4,7 @@ Produces the evidence PERF.md's device-masking claims rest on, as three
 JSON lines (tee to ``benchmarks/results/mask_backend_<chip>.txt``):
 
   1. ``link``: measured host->device and device->host bandwidth of the
-     attached chip (what the ``auto`` probe decides on, reported instead
-     of just thresholded);
+     attached chip;
   2. ``parity``: the full fast-engine preprocess run twice on the same
      corpus — ``--mask-backend host`` vs ``device`` — asserting the
      non-masking columns are byte-identical and the device-masked rows
@@ -14,8 +13,7 @@ JSON lines (tee to ``benchmarks/results/mask_backend_<chip>.txt``):
   3. ``timing``: wall-clock of the host path (assemble + vectorized
      Philox masking) vs the device path (fused gather+mask kernel,
      including transfers, post-compile) over a partition-sized batch
-     sweep, with the implied winner per size — the measured crossover
-     that calibrates ``resolve_mask_backend``'s probe.
+     sweep, with the implied winner per size.
 
 Usage: python benchmarks/mask_backend_bench.py [--rows 2048 8192 32768]
 """

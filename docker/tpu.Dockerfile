@@ -20,10 +20,11 @@ RUN apt-get update -qq && \
         git vim tmux g++ make libjemalloc-dev wget && \
     rm -rf /var/lib/apt/lists/*
 
-# TPU-enabled jax + the framework's Python dependencies.
+# The one installation the code is written against (setup.py pins the
+# same versions; README "Running on the chip").
 RUN pip install -U pip && \
-    pip install "jax[tpu]" -f https://storage.googleapis.com/jax-releases/libtpu_releases.html && \
-    pip install flax optax orbax-checkpoint chex einops \
+    pip install jax==0.9.0 jaxlib==0.9.0 libtpu==0.0.34 \
+        flax==0.12.3 optax==0.2.6 orbax-checkpoint==0.11.32 \
         numpy pyarrow transformers requests tqdm pytest
 
 # The preprocessor is malloc-heavy on the host side; jemalloc is the same

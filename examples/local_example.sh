@@ -22,8 +22,7 @@ set -euo pipefail
 
 readonly repo="$(cd "$(dirname "${BASH_SOURCE[0]}")/.." && pwd)"
 readonly workdir="${1:-$(mktemp -d -t lddl_tpu_example_XXXX)}"
-# Append (never overwrite) PYTHONPATH: TPU runtimes may be registered
-# through it.
+# Append (never overwrite) PYTHONPATH: the environment may already use it.
 export PYTHONPATH="${repo}:${PYTHONPATH:-}"
 
 readonly bin_size=64

@@ -730,8 +730,7 @@ def ensure_jax_distributed():
   """
   import jax
 
-  from ..core.compat import distributed_is_initialized
-  if distributed_is_initialized():
+  if jax.distributed.is_initialized():
     return True
   addr = os.environ.get('LDDL_COORDINATOR_ADDRESS')
   if addr:
@@ -758,6 +757,19 @@ def ensure_jax_distributed():
         f'jax.distributed.initialize() found no cluster ({e}); '
         'continuing single-process')
     return False
+
+
+def _coordination_client():
+  """The coordination-service client (KV store + ``wait_at_barrier``) of
+  the running ``jax.distributed`` runtime, or None when it is down.
+
+  A *private* jax reach (``jax._src.distributed.global_state``): jax
+  0.9 has no public accessor for the client, and the CPU backend has no
+  cross-process XLA collectives, so the multi-process CPU worlds the
+  tests run carry their host-level collectives over this KV store.
+  """
+  from jax._src import distributed
+  return distributed.global_state.client
 
 
 #: Per-collective wait bound on the coordination-service fallback path.
@@ -807,8 +819,7 @@ class JaxProcessBackend(CommBackend):
     """Coordination-service client when XLA can't do the collective."""
     if self._jax.default_backend() != 'cpu' or self.world_size <= 1:
       return None
-    from ..core.compat import distributed_client
-    return distributed_client()
+    return _coordination_client()
 
   def lease_store(self, namespace):
     """KV-backed lease store (any device platform — the coordination
@@ -816,11 +827,7 @@ class JaxProcessBackend(CommBackend):
     distributed client is reachable (single-process: nothing to lease)."""
     if self.world_size <= 1:
       return None
-    try:
-      from ..core.compat import distributed_client
-      client = distributed_client()
-    except Exception:
-      return None
+    client = _coordination_client()
     if client is None:
       return None
     return KVLeaseStore(client, namespace, self.rank)

@@ -10,9 +10,9 @@ This module provides the ingredients:
     BERT MLM+NSP train step over a padded ``[batch, seq]`` batch (standard
     transformer accounting: 24·B·S·d² + 4·B·S²·d per layer forward, MLM
     head 2·B·S·d·(d+V), backward = 2× forward);
-  - :func:`peak_flops_per_device` — best-known bf16 peak for the running
-    chip generation (override with the harness's ``--peak-tflops`` when
-    the table is stale or the platform is unknown);
+  - :func:`peak_flops_per_device` — published bf16 peak for the running
+    chip generation (None on the CPU backend; an accelerator the table
+    does not know raises);
   - :func:`peak_hbm_bytes_per_device` / :func:`machine_balance` — the
     memory axis of the roofline: published HBM bandwidth per chip, and
     the FLOPs/byte ridge point that separates compute-bound from
@@ -60,26 +60,25 @@ def _lookup_peak(table, device, scale, what, flag):
   for key, peak in table:
     if key in kind:
       return peak * scale
-  if 'tpu' in kind:
-    import warnings
-    warnings.warn(
-        f'no peak-{what} entry for device_kind {device.device_kind!r}; '
-        f'the roofline {what} axis will be omitted — set {flag} to '
-        'report it')
-  return None
+  if getattr(device, 'platform', None) == 'cpu':
+    return None
+  raise ValueError(
+      f'no peak-{what} entry for device_kind {device.device_kind!r}: add '
+      f'the chip to the table in {__name__} or set {flag}')
 
 
 def peak_flops_per_device(device=None):
-  """Peak bf16 FLOP/s of ``device`` (default: jax.devices()[0]), or None
-  when the chip generation is not in the table (e.g. the CPU backend)."""
+  """Peak bf16 FLOP/s of ``device`` (default: jax.devices()[0]). None on
+  the CPU backend (no published peak; MFU is omitted there); an
+  accelerator whose ``device_kind`` is not in the table raises."""
   return _lookup_peak(_PEAK_TFLOPS_BF16, device, 1e12, 'FLOPs',
                       'LDDL_PEAK_TFLOPS')
 
 
 def peak_hbm_bytes_per_device(device=None):
-  """Peak HBM bandwidth (bytes/s) of ``device``, or None when the chip
-  generation is not in the table (override with ``LDDL_PEAK_HBM_GBPS``,
-  in GB/s per device)."""
+  """Peak HBM bandwidth (bytes/s) of ``device``; None on the CPU backend,
+  raises for an accelerator not in the table (``LDDL_PEAK_HBM_GBPS``, in
+  GB/s per device, overrides the table where the callers read it)."""
   return _lookup_peak(_PEAK_HBM_GBPS, device, 1e9, 'HBM-bandwidth',
                       'LDDL_PEAK_HBM_GBPS')
 
@@ -87,8 +86,7 @@ def peak_hbm_bytes_per_device(device=None):
 def machine_balance(device=None):
   """The roofline ridge point of ``device`` in FLOPs/byte (peak FLOP/s ÷
   peak HBM bytes/s): kernels whose arithmetic intensity exceeds this are
-  compute-bound, below it memory-bound. None when either peak is
-  unknown."""
+  compute-bound, below it memory-bound. None on the CPU backend."""
   flops = peak_flops_per_device(device)
   bw = peak_hbm_bytes_per_device(device)
   if not flops or not bw:

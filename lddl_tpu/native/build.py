@@ -1,19 +1,26 @@
 """Build/load the native shared library.
 
-Compiles ``src/wordpiece.cpp`` with g++ into ``_lddl_native.<abi>.so`` next
-to this file. A content hash of the source is embedded in the filename so
-editing the C++ transparently rebuilds; a file lock serializes concurrent
-builders (many worker processes may race on first use).
+Compiles ``src/*.cpp`` with g++ into ``_lddl_native.<digest>.so`` next to
+this file. The digest covers everything that determines the bytes — the
+sources, the compiler flags and the CPU that ``-march=native`` resolves
+against — so editing the C++ transparently rebuilds, and a tree copied
+to a machine with another CPU builds its own library instead of loading
+one compiled for the first. A file lock serializes concurrent builders
+(many worker processes may race on first use).
 """
 
 import ctypes
+import functools
 import glob
 import hashlib
 import os
+import platform
 import subprocess
 import tempfile
 
 _SRC_DIR = os.path.join(os.path.dirname(__file__), 'src')
+_FLAGS = ('-O3', '-march=native', '-shared', '-fPIC', '-std=c++17',
+          '-pthread')
 _LIB_CACHE = {}
 
 
@@ -21,11 +28,31 @@ def _sources():
   return sorted(glob.glob(os.path.join(_SRC_DIR, '*.cpp')))
 
 
+@functools.lru_cache(maxsize=None)
+def _target_cpu():
+  """Identity of the CPU ``-march=native`` compiles for: the machine
+  type plus the first processor's model and feature lines of
+  ``/proc/cpuinfo`` (``platform.processor()`` where there is none)."""
+  lines = [platform.machine()]
+  try:
+    with open('/proc/cpuinfo', encoding='utf-8') as f:
+      for line in f:
+        if not line.strip():
+          break  # end of the first processor's block
+        if line.startswith(('model name', 'flags', 'Features', 'CPU part')):
+          lines.append(line.strip())
+  except OSError:
+    lines.append(platform.processor())
+  return '\n'.join(lines)
+
+
 def _lib_path():
   h = hashlib.sha256()
   for src in _sources():
     with open(src, 'rb') as f:
       h.update(f.read())
+  h.update(' '.join(_FLAGS).encode())
+  h.update(_target_cpu().encode())
   digest = h.hexdigest()[:12]
   return os.path.join(os.path.dirname(__file__), f'_lddl_native.{digest}.so')
 
@@ -44,10 +71,7 @@ def build_library(verbose=False):
       return path
     with tempfile.TemporaryDirectory(dir=os.path.dirname(path)) as tmp:
       tmp_so = os.path.join(tmp, 'out.so')
-      cmd = [
-          'g++', '-O3', '-march=native', '-shared', '-fPIC', '-std=c++17',
-          '-pthread', '-o', tmp_so, *_sources()
-      ]
+      cmd = ['g++', *_FLAGS, '-o', tmp_so, *_sources()]
       if verbose:
         print('building native library:', ' '.join(cmd))
       subprocess.run(cmd, check=True, capture_output=not verbose)

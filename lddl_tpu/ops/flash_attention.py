@@ -50,8 +50,10 @@ Differentiation is a ``jax.custom_vjp``: forward saves (out, lse); the
 backward runs two Pallas kernels — dq over q-blocks, (dk, dv) over
 k-blocks — each recomputing P = exp(s - lse) blockwise.
 
-Off TPU the kernels run in Pallas interpret mode, so the CPU test suite
-exercises the identical code path.
+On the ``cpu`` backend the kernels run in Pallas interpret mode, so the
+CPU test suite exercises the identical code path. Every other backend
+compiles them (Mosaic) or raises — there is no silent interpreter
+fallback for an accelerator that announces itself under another name.
 """
 
 import functools
@@ -72,8 +74,10 @@ NEG_INF = -1e9
 _L_FLOOR = 1e-30
 
 
-def _interpret():
-  return jax.devices()[0].platform != 'tpu'
+def _interpret(backend=None):
+  """Only the ``cpu`` backend interprets; any other name compiles the
+  kernel or fails (``backend`` defaults to the running one)."""
+  return (backend or jax.default_backend()) == 'cpu'
 
 
 def _padded_len(s):
@@ -580,7 +584,6 @@ def make_flash_attention(mesh, q_spec=None, mask_spec=None,
   """
   from jax.sharding import PartitionSpec as P
 
-  from ..core.compat import shard_map
   if dict(zip(mesh.axis_names, mesh.devices.shape)).get('seq', 1) > 1:
     raise ValueError(
         "flash attention does not shard the sequence axis; use "
@@ -593,22 +596,22 @@ def make_flash_attention(mesh, q_spec=None, mask_spec=None,
 
   if with_segment_ids:
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(q_spec, q_spec, q_spec, mask_spec, mask_spec),
         out_specs=q_spec,
-        check=False)
+        check_vma=False)
     def _sharded_seg(q, k, v, mask, segment_ids):
       return flash_attention(q, k, v, mask, segment_ids, segment_ids)
 
     return _sharded_seg
 
   @functools.partial(
-      shard_map,
+      jax.shard_map,
       mesh=mesh,
       in_specs=(q_spec, q_spec, q_spec, mask_spec),
       out_specs=q_spec,
-      check=False)
+      check_vma=False)
   def _sharded(q, k, v, mask):
     return flash_attention(q, k, v, mask)
 

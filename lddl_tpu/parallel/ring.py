@@ -32,8 +32,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from ..core.compat import axis_size
-
 
 def _block_attn(q, k, v, bias, scale):
   """One block's contribution: returns (scores_max, exp_scores @ v, denom)."""
@@ -83,7 +81,7 @@ def ring_attention(q, k, v, kv_mask=None, axis_name='seq',
   if (q_segment_ids is None) != (kv_segment_ids is None):
     raise ValueError('q_segment_ids and kv_segment_ids must be given '
                      'together')
-  n = axis_size(axis_name)
+  n = lax.axis_size(axis_name)
   scale = 1.0 / (q.shape[-1] ** 0.5)
   qf = q.astype(jnp.float32)
   neg = jnp.float32(-1e9)
@@ -181,17 +179,16 @@ def make_ring_attention(mesh, q_spec=None, mask_spec=None, axis_name='seq',
   extra ``segment_ids`` ``[batch, seq]`` operand (used for both q and
   kv — self-attention), sharded like the mask.
   """
-  from ..core.compat import shard_map
   q_spec = q_spec or P(('data', 'fsdp'), 'tensor', axis_name, None)
   mask_spec = mask_spec or P(('data', 'fsdp'), axis_name)
 
   if with_segment_ids:
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(q_spec, q_spec, q_spec, mask_spec, mask_spec),
         out_specs=q_spec,
-        check=False)
+        check_vma=False)
     def _sharded_seg(q, k, v, kv_mask, segment_ids):
       return ring_attention(q, k, v, kv_mask, axis_name=axis_name,
                             block_impl=block_impl,
@@ -201,11 +198,11 @@ def make_ring_attention(mesh, q_spec=None, mask_spec=None, axis_name='seq',
     return _sharded_seg
 
   @functools.partial(
-      shard_map,
+      jax.shard_map,
       mesh=mesh,
       in_specs=(q_spec, q_spec, q_spec, mask_spec),
       out_specs=q_spec,
-      check=False)
+      check_vma=False)
   def _sharded(q, k, v, kv_mask):
     return ring_attention(q, k, v, kv_mask, axis_name=axis_name,
                           block_impl=block_impl)

@@ -36,10 +36,33 @@ def param_shardings(mesh, abs_params):
   return jax.tree_util.tree_unflatten(tree, shardings)
 
 
-def init_params(model, mesh, rng, seq_len=128, batch=2):
+def state_shardings(mesh, params, opt_state):
+  """NamedSharding trees for ``(params, opt_state)``, from tree paths and
+  shapes alone (abstract and traced trees work). Params follow
+  :func:`lddl_tpu.models.spec_for_param`; every optimizer-state subtree
+  that mirrors the params tree (Adam's ``mu``/``nu``) inherits the
+  params' layout; the rest (step counters) is replicated."""
+  p_sh = param_shardings(mesh, params)
+  p_def = jax.tree_util.tree_structure(params)
+
+  def mirrors(node):
+    return jax.tree_util.tree_structure(node) == p_def
+
+  rep = NamedSharding(mesh, P())
+  o_sh = jax.tree_util.tree_map(
+      lambda node: p_sh if mirrors(node) else rep, opt_state,
+      is_leaf=mirrors)
+  return p_sh, o_sh
+
+
+def init_params(model, mesh, rng, seq_len=128, batch=None):
   """Initialize params directly into their mesh placement: the init
   computation is jitted with ``out_shardings`` so no single device ever
-  holds the full parameter set."""
+  holds the full parameter set. The dummy init batch defaults to two
+  rows per (data, fsdp) shard: the flash/ring ``shard_map`` refuses a
+  batch the mesh does not divide, at init as at every step."""
+  if batch is None:
+    batch = 2 * mesh.shape.get('data', 1) * mesh.shape.get('fsdp', 1)
   dummy = {
       'input_ids': jnp.zeros((batch, seq_len), jnp.int32),
       'token_type_ids': jnp.zeros((batch, seq_len), jnp.int32),
@@ -173,7 +196,7 @@ def pretrain_loss(model, params, batch, dropout_rng=None,
   }
 
 
-def _train_step_body(model, tx, params, opt_state, rng, batch,
+def _train_step_body(model, tx, mesh, params, opt_state, rng, batch,
                      max_predictions=None):
   """One un-jitted train step — the single definition both
   :func:`make_train_step` and :func:`make_scan_train_step` compile, so the
@@ -193,6 +216,12 @@ def _train_step_body(model, tx, params, opt_state, rng, batch,
   metrics['grad_norm'] = optax.global_norm(grads)
   updates, opt_state = tx.update(grads, opt_state, params)
   params = optax.apply_updates(params, updates)
+  # The state leaves the step laid out as it came in. Left to itself the
+  # partitioner hands replicated-by-rule leaves (biases, norms) back
+  # split over fsdp, which the next call of an AOT-compiled step rejects
+  # (and a plain jit call silently recompiles for).
+  params, opt_state = jax.lax.with_sharding_constraint(
+      (params, opt_state), state_shardings(mesh, params, opt_state))
   metrics['loss'] = loss
   return params, opt_state, metrics
 
@@ -234,7 +263,7 @@ def make_train_step(model, tx, mesh, max_predictions=None):
 
   @functools.partial(jax.jit, donate_argnums=(0, 1))
   def step(params, opt_state, rng, batch):
-    return _train_step_body(model, tx, params, opt_state, rng, batch,
+    return _train_step_body(model, tx, mesh, params, opt_state, rng, batch,
                             max_predictions)
 
   return step
@@ -247,21 +276,19 @@ def make_scan_train_step(model, tx, mesh, max_predictions=None):
   window via ``lax.scan``, so per-step dispatch cost amortizes across the
   window.
 
-  This is the measurement mode a dispatch-latency-bound link needs (a
-  tunneled or remote chip pays ~tens of ms per program launch): with K
-  steps in one program, launch cost is paid once per window instead of
-  once per step, so the observed step time converges to device compute
-  time. It is also the idiomatic shape for production TPU training loops
-  (device-resident multi-batch windows).
+  With K steps in one program, launch cost and the host's read of the
+  step scalars are paid once per window instead of once per step, so the
+  observed step time converges to device compute time — the idiomatic
+  shape for production TPU training loops (device-resident multi-batch
+  windows).
   """
 
   @functools.partial(jax.jit, donate_argnums=(0, 1))
   def run(params, opt_state, rng, batches):
 
     def body(carry, batch):
-      params, opt_state, metrics = _train_step_body(model, tx, carry[0],
-                                                    carry[1], rng, batch,
-                                                    max_predictions)
+      params, opt_state, metrics = _train_step_body(
+          model, tx, mesh, carry[0], carry[1], rng, batch, max_predictions)
       return (params, opt_state), metrics
 
     (params, opt_state), metrics = jax.lax.scan(body, (params, opt_state),

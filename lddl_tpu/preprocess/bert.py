@@ -582,7 +582,7 @@ class BertPretrainConfig:
   tokenizer_backend: str = 'auto'
   sentence_backend: str = 'auto'
   engine: str = 'fast'  # 'fast' (columnar/device) | 'python' (reference-style)
-  mask_backend: str = 'auto'  # 'device' | 'host' | 'auto'
+  mask_backend: str = 'auto'  # 'host' | 'device'; 'auto' means 'host'
   target_seq_length: int = 128
   short_seq_prob: float = 0.1
   duplicate_factor: int = 5
@@ -708,21 +708,12 @@ def run(corpus, sink_dir, cfg, executor=None, num_shuffle_partitions=None):
       local = 'native' if _get_tokenizer(cfg).native is not None else 'hf'
     resolved = executor.comm.broadcast_object(local, root=0)
     cfg = dataclasses.replace(cfg, tokenizer_backend=resolved)
-  if cfg.masking and cfg.engine == 'fast' and cfg.mask_backend == 'auto':
-    # Masking backends have independent RNG streams, so which one runs is
-    # part of the output contract: resolve once here, not per pool worker
-    # (workers racing for an exclusive accelerator would otherwise make
-    # shard bits depend on OS scheduling). Pool workers cannot share one
-    # chip, so 'device' only applies to single-worker executors until the
-    # per-host device feeder lands.
-    local = None
-    if executor.comm.rank == 0:
-      from ..ops.masking import resolve_mask_backend
-      local = resolve_mask_backend('auto')
-      if local == 'device' and executor.num_local_workers > 1:
-        local = 'host'
-    resolved = executor.comm.broadcast_object(local, root=0)
-    cfg = dataclasses.replace(cfg, mask_backend=resolved)
+  # Masking backends have independent RNG streams, so which one runs is
+  # part of the output contract: 'auto' is resolved here, once, to the
+  # same answer on every host (never per pool worker, never by probing).
+  from ..ops.masking import resolve_mask_backend
+  cfg = dataclasses.replace(
+      cfg, mask_backend=resolve_mask_backend(cfg.mask_backend))
   # Resolve the shard format once up front: it is part of the output
   # contract (and invalid combinations — delta without masking, delta on
   # the python engine — must fail loudly before any worker starts).
@@ -768,7 +759,9 @@ def attach_args(parser):
                       'python: reference-style per-document loop')
   parser.add_argument('--mask-backend', type=str, default='auto',
                       choices=['auto', 'device', 'host'],
-                      help='where batched MLM masking runs (fast engine)')
+                      help='where batched MLM masking runs (fast engine); '
+                      "'auto' means 'host' — 'device' (a different RNG "
+                      'stream, so different shard bytes) only by request')
   parser.add_argument('--sentence-backend', type=str, default='auto',
                       choices=['auto', 'punkt', 'rules'])
   parser.add_argument('--target-seq-length', type=int, default=128)

@@ -110,9 +110,11 @@ def build_loop(args):
   pretrain.TrainLoop` from CLI args — every knob the LR schedule, model
   shapes, or data stream depend on must match the original run, or the
   replayed arithmetic (correctly) diverges."""
+  from ..core.compile_cache import use_compile_cache
   from ..models import BertConfig
   from ..parallel import make_mesh
   from ..training.pretrain import MODEL_SIZES, TrainLoop
+  use_compile_cache()  # a replayed step is one compile of the recorded run
   tokenizer, vocab = None, args.vocab_size
   if vocab is None:
     from ..tokenization.wordpiece import load_bert_tokenizer

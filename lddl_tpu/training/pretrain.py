@@ -29,35 +29,6 @@ import os
 import time
 
 
-def _place_opt_state(opt_state, params, mesh):
-  """Give every optimizer-state leaf an explicit mesh placement.
-
-  Adam's ``mu``/``nu`` mirror the params tree, so each leaf inherits the
-  sharding of the params leaf whose tree path it ends with (longest
-  suffix wins); everything else (step counters, schedule scalars) is
-  replicated. Without this the layout is whatever jit happened to pick —
-  fine for one run, but a checkpoint restore reproduces it faithfully
-  and then conflicts with the mesh-sharded params inside the jitted
-  step.
-  """
-  import jax
-  from jax.sharding import NamedSharding, PartitionSpec
-  from jax.tree_util import (keystr, tree_flatten_with_path,
-                             tree_unflatten)
-  param_paths = sorted(
-      ((keystr(p), leaf.sharding)
-       for p, leaf in tree_flatten_with_path(params)[0]),
-      key=lambda kv: -len(kv[0]))
-  rep = NamedSharding(mesh, PartitionSpec())
-  flat, treedef = tree_flatten_with_path(opt_state)
-  placed = []
-  for path, leaf in flat:
-    ks = keystr(path)
-    sharding = next((sh for pp, sh in param_paths if ks.endswith(pp)), rep)
-    placed.append(jax.device_put(leaf, sharding))
-  return tree_unflatten(treedef, placed)
-
-
 class CompiledStepCache:
   """Per-bin compiled train-step cache.
 
@@ -211,7 +182,7 @@ class TrainLoop:
                           get_packed_pretrain_data_loader)
     from ..models import BertForPretraining
     from ..parallel import make_train_step
-    from ..parallel.train import init_params
+    from ..parallel.train import init_params, state_shardings
 
     model = BertForPretraining(model_cfg, mesh=mesh)
     schedule = optax.warmup_cosine_decay_schedule(
@@ -263,7 +234,14 @@ class TrainLoop:
           **(loader_kwargs or {}))
     params = init_params(model, mesh, jax.random.key(seed),
                          seq_len=min(128, max_seq_length))
-    opt_state = _place_opt_state(jax.jit(tx.init)(params), params, mesh)
+    # Every optimizer-state leaf gets an explicit mesh placement (the
+    # same one the step keeps it in): a layout jit happened to pick
+    # would be reproduced faithfully by a checkpoint restore and then
+    # conflict with the mesh-sharded params inside the jitted step.
+    opt_state = jax.jit(
+        tx.init,
+        out_shardings=state_shardings(
+            mesh, params, jax.eval_shape(tx.init, params))[1])(params)
     if max_predictions is not None:
       from ..parallel.train import check_max_predictions
       check_max_predictions(
@@ -728,9 +706,9 @@ class TrainLoop:
 def _peak_flops_total():
   """Per-process peak FLOP/s for the MFU denominator: per-device peak x
   local device count. ``LDDL_PEAK_TFLOPS`` (per device, in TFLOP/s)
-  overrides the chip table — required on hosts the table cannot identify
-  (CPU runs, unreleased chips), where it returns None and MFU is
-  omitted."""
+  overrides the chip table. On the CPU backend without the override it
+  returns None and MFU is omitted; an accelerator the table does not
+  know raises (:func:`~lddl_tpu.models.flops.peak_flops_per_device`)."""
   import jax
 
   from ..models.flops import peak_flops_per_device
@@ -794,9 +772,9 @@ def attach_args(parser):
   parser.add_argument('--remat', action='store_true')
   parser.add_argument('--prng', default='threefry',
                       choices=['threefry', 'rbg'],
-                      help="jax PRNG impl; 'rbg' makes per-step dropout "
-                      'draws ~free on TPU (+2 MFU points measured at '
-                      's=512, benchmarks/results/mfu_v5e_scan_512_r5.txt)')
+                      help="jax PRNG impl; 'rbg' draws dropout bits with "
+                      'the hardware generator (its step-time effect is '
+                      'not measured on the current chip)')
   parser.add_argument('--dp', type=int, default=1)
   parser.add_argument('--fsdp', type=int, default=1)
   parser.add_argument('--tp', type=int, default=1)
@@ -853,11 +831,13 @@ def main(args=None):
     jax.config.update('jax_default_prng_impl', args.prng)
 
   from ..comm import get_backend
+  from ..core.compile_cache import use_compile_cache
   from ..models import BertConfig
   from ..parallel import make_mesh, mesh_summary
   from ..tokenization.wordpiece import load_bert_tokenizer
 
   comm = get_backend(args.comm)  # bootstraps jax.distributed under --comm jax
+  use_compile_cache()
   from ..telemetry.trace import get_tracer
   tracer = get_tracer()
   if tracer.enabled:
@@ -875,8 +855,10 @@ def main(args=None):
       **MODEL_SIZES[args.model])
   mesh = make_mesh(data=args.dp, fsdp=args.fsdp, tensor=args.tp,
                    seq=args.sp)
-  print(f'mesh: {mesh_summary(mesh)}; model={args.model} '
-        f'attention={args.attention}')
+  print(f'backend={jax.default_backend()} '
+        f'device_kind={jax.devices()[0].device_kind!r} '
+        f'devices={jax.device_count()}; mesh: {mesh_summary(mesh)}; '
+        f'model={args.model} attention={args.attention}')
 
   samples_seen = 0
   resume = False

@@ -8,17 +8,21 @@ setup(
     description=('TPU-native language dataset preprocessing and data '
                  'loading for large-scale pretraining'),
     packages=find_packages(include=['lddl_tpu', 'lddl_tpu.*']),
-    python_requires='>=3.10',
+    # The one installation the code is written and run against (README
+    # "Running on the chip"): no shims for other versions are kept.
+    python_requires='>=3.12',
     install_requires=[
         'numpy',
         'pyarrow>=4.0.1',
-        'jax',
-        'flax',
-        'optax',
-        'orbax-checkpoint',
+        'jax==0.9.0',
+        'jaxlib==0.9.0',
+        'flax==0.12.3',
+        'optax==0.2.6',
+        'orbax-checkpoint==0.11.32',
         'transformers',
     ],
     extras_require={
+        'tpu': ['libtpu==0.0.34'],
         'download': ['requests', 'tqdm', 'wikiextractor', 'gdown',
                      'news-please'],
         'test': ['pytest'],

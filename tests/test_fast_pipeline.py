@@ -201,6 +201,25 @@ class TestMaskingBackends:
       assert ta.column('is_random_next').equals(tb.column('is_random_next'))
 
 
+def test_auto_mask_backend_is_host_without_probing(monkeypatch):
+  """'auto' decides which RNG stream writes the shards, so it must be a
+  constant: no clock, no device probe, no jax import can change it."""
+  import sys
+  import time
+
+  from lddl_tpu.ops.masking import resolve_mask_backend
+
+  def no_clock(*a, **k):
+    raise AssertionError('resolve_mask_backend read a clock')
+
+  for name in ('time', 'perf_counter', 'monotonic'):
+    monkeypatch.setattr(time, name, no_clock)
+  monkeypatch.setitem(sys.modules, 'jax', None)  # `import jax` would raise
+  assert resolve_mask_backend('auto') == 'host'
+  assert resolve_mask_backend('host') == 'host'
+  assert resolve_mask_backend('device') == 'device'
+
+
 class TestMaskingOps:
 
   def test_mask_batch_host_exact_k(self):

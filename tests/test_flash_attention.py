@@ -222,3 +222,13 @@ def test_multiblock_kv_grid(monkeypatch, caps):
   for a, b, name in zip(gf, gd, 'qkv'):
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4,
                                atol=2e-4, err_msg=f'd{name}')
+
+
+def test_interpret_rule_is_cpu_only():
+  """Only the cpu backend interprets the kernels; an accelerator under
+  any name compiles them or raises — never a silent interpreter."""
+  from lddl_tpu.ops.flash_attention import _interpret
+  assert _interpret('cpu')
+  for backend in ('tpu', 'gpu', 'some-plugin'):
+    assert not _interpret(backend)
+  assert _interpret() == (jax.default_backend() == 'cpu')

@@ -135,6 +135,52 @@ class TestBertModel:
     assert np.isfinite(float(metrics['loss']))
     assert not np.array_equal(old_val, new_val)
 
+  def test_step_keeps_state_layout(self, mesh):
+    """The step hands the state back laid out as it came in, so an
+    AOT-compiled step (CompiledStepCache) accepts its own output on the
+    next call. Left to the partitioner, replicated-by-rule leaves
+    (biases, norms) came back split over fsdp and the second call of
+    the compiled step raised on this fsdp x tensor mesh."""
+    model = BertForPretraining(TINY)
+    params = init_params(model, mesh, jax.random.key(0), seq_len=32)
+    tx = optax.adamw(1e-3)
+    opt_state = tx.init(params)
+    rng = np.random.default_rng(3)
+    b, s = 4, 32
+    batch = shard_batch(
+        {
+            'input_ids': rng.integers(0, 64, (b, s)).astype(np.int32),
+            'token_type_ids': np.zeros((b, s), np.int32),
+            'attention_mask': np.ones((b, s), np.int32),
+            'labels': np.where(
+                rng.random((b, s)) < 0.15,
+                rng.integers(0, 64, (b, s)), -100).astype(np.int32),
+            'next_sentence_labels': rng.integers(0, 2,
+                                                 (b,)).astype(np.int32),
+        }, mesh)
+    key = jax.random.key(1)
+    from lddl_tpu.parallel.train import state_shardings
+    state = (params, opt_state)
+    want = jax.tree_util.tree_leaves(state_shardings(mesh, *state))
+    compiled = make_train_step(model, tx, mesh).lower(
+        *state, key, batch).compile()
+    for _ in range(2):
+      *state, metrics = compiled(*state, key, batch)
+    assert np.isfinite(float(metrics['loss']))
+    for x, sharding in zip(jax.tree_util.tree_leaves(state), want):
+      assert x.sharding.is_equivalent_to(sharding, x.ndim)
+
+  def test_init_divides_its_dummy_batch_over_the_mesh(self):
+    """flash/ring attention run under shard_map, which refuses a batch
+    the (data, fsdp) axes do not divide — at init as at every step; the
+    old fixed two-row dummy failed on any mesh with data x fsdp > 2."""
+    import dataclasses
+    mesh = make_mesh(data=4, fsdp=2, tensor=1, seq=1)
+    model = BertForPretraining(
+        dataclasses.replace(TINY, attention_impl='flash'), mesh=mesh)
+    params = init_params(model, mesh, jax.random.key(0), seq_len=32)
+    assert jax.tree_util.tree_leaves(params)
+
   def test_ring_model_matches_dense(self, mesh):
     # Same params, attention_impl dense vs ring on a seq-sharded mesh.
     seq_mesh = make_mesh(data=2, fsdp=1, tensor=1, seq=4)

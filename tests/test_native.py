@@ -243,3 +243,17 @@ def test_pairing_falls_back_without_toolchain(monkeypatch):
                                              backend='python')
   assert np.array_equal(a, a2) and np.array_equal(b, b2)
   monkeypatch.setattr(pairing, '_NATIVE_PLANNER', None)  # re-probe later
+
+
+def test_library_name_covers_flags_and_target_cpu(monkeypatch):
+  """The .so is compiled with -march=native, so its name must change
+  with the CPU (and the flags), not with the sources alone: a checkout
+  copied to another machine must not load this machine's binary."""
+  from lddl_tpu.native import build
+  here = build._lib_path()
+  assert build._lib_path() == here  # stable on one machine
+  monkeypatch.setattr(build, '_target_cpu', lambda: 'another cpu')
+  elsewhere = build._lib_path()
+  assert elsewhere != here
+  monkeypatch.setattr(build, '_FLAGS', build._FLAGS + ('-g',))
+  assert build._lib_path() not in (here, elsewhere)

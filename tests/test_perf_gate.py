@@ -2,10 +2,11 @@
 
 The load-bearing contracts:
 
-  - the repo's REAL ``BENCH_r01..r05.json`` trajectory passes the gate
-    (its swings are growth noise, not cliffs — the acceptance
-    criterion), while a fixture history with an injected cliff exits
-    non-zero and benign MAD-scale noise does not;
+  - the trajectory of the driver's five recorded ``BENCH_r01..r05.json``
+    rounds (values inlined below; the records themselves were deleted
+    with the plug-in they were captured through) passes the gate — its
+    swings are growth noise, not cliffs — while a fixture history with
+    an injected cliff exits non-zero and benign MAD-scale noise does not;
   - median ± MAD statistics with the min-rel-drop floor: a single
     outlier in the baseline cannot poison the scale, and near-constant
     series never flag measurement jitter;
@@ -29,6 +30,21 @@ from lddl_tpu.telemetry.perf import (append_history, gather_series,
                                      metric_direction, robust_stats)
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+# The five driver rounds recorded before PR 1 (dup=5 host preprocess).
+RECORDED_ROUNDS = [0.801, 8.28, 10.433, 16.049, 6.913]
+
+
+@pytest.fixture
+def recorded_rounds_root(tmp_path):
+  """A root dir holding the recorded trajectory as ``BENCH_r*.json``."""
+  for i, v in enumerate(RECORDED_ROUNDS):
+    (tmp_path / f'BENCH_r0{i + 1}.json').write_text(json.dumps(
+        {'n': i + 1, 'rc': 0,
+         'parsed': {'metric': 'bert_preprocess_mb_per_sec_per_chip',
+                    'value': v, 'unit': 'MB/s/chip'}}))
+  return str(tmp_path)
 
 
 def _write_history(path, values, metric='tput_rows_per_sec'):
@@ -56,8 +72,7 @@ class TestJudgeSeries:
   def test_wide_growth_trajectory_passes(self):
     # The shape of the repo's real rounds: orders-of-magnitude growth
     # with a final value below the median. Robust scale must absorb it.
-    v = judge_series('mb_per_sec_per_chip',
-                     [0.801, 8.28, 10.433, 16.049, 6.913])
+    v = judge_series('mb_per_sec_per_chip', RECORDED_ROUNDS)
     assert v['status'] == 'ok'
 
   def test_improvement_never_flags(self):
@@ -100,11 +115,10 @@ class TestJudgeSeries:
 
 class TestLoaders:
 
-  def test_real_bench_rounds_load(self):
-    series = load_bench_rounds(REPO_ROOT)
-    values = series.get('bert_preprocess_mb_per_sec_per_chip')
-    assert values and len(values) >= 5
-    assert values[0] == pytest.approx(0.801)
+  def test_recorded_bench_rounds_load(self, recorded_rounds_root):
+    series = load_bench_rounds(recorded_rounds_root)
+    assert series['bert_preprocess_mb_per_sec_per_chip'] == \
+        pytest.approx(RECORDED_ROUNDS)
 
   def test_real_multichip_rounds_load(self):
     series = load_multichip_rounds(REPO_ROOT)
@@ -143,8 +157,9 @@ class TestLoaders:
 
 class TestGateCli:
 
-  def test_real_repo_trajectory_passes_gate(self, capsys):
-    assert main(['--root', REPO_ROOT, '--gate']) == 0
+  def test_recorded_trajectory_passes_gate(self, recorded_rounds_root,
+                                           capsys):
+    assert main(['--root', recorded_rounds_root, '--gate']) == 0
     out = capsys.readouterr().out
     assert 'bert_preprocess_mb_per_sec_per_chip' in out
 

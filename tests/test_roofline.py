@@ -148,6 +148,27 @@ class TestResolvePeaks:
     assert peak_flops_per_device(_V5e()) == pytest.approx(197e12)
     assert peak_hbm_bytes_per_device(_V5e()) == pytest.approx(819e9)
 
+  def test_unknown_accelerator_raises_but_cpu_is_none(self):
+    from lddl_tpu.models.flops import (machine_balance,
+                                       peak_flops_per_device,
+                                       peak_hbm_bytes_per_device)
+
+    class _Unknown:
+      platform = 'tpu'
+      device_kind = 'TPU v99'
+
+    with pytest.raises(ValueError, match='TPU v99'):
+      peak_flops_per_device(_Unknown())
+    with pytest.raises(ValueError, match='TPU v99'):
+      peak_hbm_bytes_per_device(_Unknown())
+
+    class _Cpu:
+      platform = 'cpu'
+      device_kind = 'cpu'
+
+    assert peak_flops_per_device(_Cpu()) is None
+    assert machine_balance(_Cpu()) is None
+
 
 # ---------------------------------------------------------------------------
 # the windowed verdict (pure arithmetic over merged metrics)

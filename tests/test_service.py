@@ -326,10 +326,14 @@ def test_injected_wire_corruption_caught_with_exact_frame(
 # two clients, one SIGKILLed: lease re-serve + union byte-identity
 
 
-def _union_client(rank, rdv, run_id, url, out_path, faults_spec):
+def _union_client(rank, rdv, run_id, url, out_path, faults_spec,
+                  hold_until=None):
   """Spawned client: drain epoch 0, appending one JSONL record per
   delivered batch (flushed immediately, so a SIGKILLed client's
-  delivered set survives it)."""
+  delivered set survives it). ``hold_until=(path, n)`` delays the drain
+  until the peer writing ``path`` has delivered ``n`` batches — without
+  it, a client that starts first on a loaded box can drain the whole
+  epoch before its peer reaches the pull that kills it."""
   os.environ['LDDL_DATA_SERVER'] = url
   os.environ['LDDL_COMM_HEARTBEAT'] = '0.1'
   os.environ['LDDL_LEASE_TIMEOUT'] = '10'
@@ -351,6 +355,11 @@ def _union_client(rank, rdv, run_id, url, out_path, faults_spec):
 
   comm = FileBackend(rdv, rank=rank, world_size=2, run_id=run_id)
   src = NetworkBatchSource(comm=comm, timeout=10, retries=2)
+  if hold_until is not None:
+    path, n = hold_until
+    deadline = time.monotonic() + 60
+    while len(_read_records(path)) < n and time.monotonic() < deadline:
+      time.sleep(0.05)
   with open(out_path, 'w') as f:
     for gi, batch in src.iter_steps(0):
       f.write(json.dumps({'gi': gi, 'digest': digest(batch)}) + '\n')
@@ -390,7 +399,10 @@ def test_two_client_union_byte_identity(tmp_path, kill_spec):
   procs = [
       ctx.Process(target=_union_client,
                   args=(r, rdv, run_id, srv.url, outs[r],
-                        kill_spec if r == 1 else None))
+                        kill_spec if r == 1 else None,
+                        # The kill fires on client 1's 3rd pull: client 0
+                        # holds off until client 1 has delivered two.
+                        (outs[1], 2) if kill_spec and r == 0 else None))
       for r in range(2)
   ]
   try:
