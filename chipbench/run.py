@@ -1,0 +1,643 @@
+"""One run of one cell of the benchmark.
+
+    python3 chipbench/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
+
+The timed window is one uninterrupted call of ``TrainLoop.run``, built as
+``lddl_tpu.training.pretrain.main`` builds it (compile cache, tokenizer,
+``BertConfig``, mesh, ``TrainLoop.build``), with the real loader,
+``prefetch_to_device`` and the ``CompiledStepCache``. The benchmark adds
+two taps of its own and nothing else: one around the host loader (what
+each batch held) and one around ``loop.step_fn`` (when each step was
+called). The window opens at the call of the first step after warm-up
+and closes at the first call ``--seconds`` later; it is ended through the
+loop's own stop path (the preemption notice).
+
+Everything that belongs to one cell is data: the configuration file, the
+traffic file, the limits file and one reader per per-layer metric, all
+found by the names in ``BENCHMARK.json`` (or, for rehearsal cells that
+are not part of the benchmark, in ``chipbench/rehearsal/``).
+
+The last line of standard output is the result. Exit codes: 0 a result
+was printed; 2 bad arguments or files; 3 no TPU, or fewer chips than the
+cell asks for.
+"""
+
+import time
+
+T_START = time.perf_counter()
+
+import argparse  # noqa: E402
+import gc  # noqa: E402
+import glob  # noqa: E402
+import hashlib  # noqa: E402
+import importlib.util  # noqa: E402
+import json  # noqa: E402
+import math  # noqa: E402
+import os  # noqa: E402
+import shutil  # noqa: E402
+import signal  # noqa: E402
+import statistics  # noqa: E402
+import subprocess  # noqa: E402
+import sys  # noqa: E402
+import tempfile  # noqa: E402
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+REPO = os.path.dirname(HERE)
+if REPO not in sys.path:
+  sys.path.insert(0, REPO)
+
+VOCAB = os.path.join(REPO, 'benchmarks', 'assets', 'bench_vocab_30522.txt')
+WORK = os.path.join(REPO, '.chipbench_work')
+COMPARED_STEPS = 3
+ADAM_B1 = 0.9
+
+
+def say(*parts):
+  print(f'[chipbench {time.perf_counter() - T_START:6.1f}s]', *parts,
+        file=sys.stderr, flush=True)
+
+
+def fail(code, message):
+  say(f'FAILED: {message}')
+  raise SystemExit(code)
+
+
+def load_json(path):
+  with open(path) as f:
+    return json.load(f)
+
+
+# ----------------------------------------------------------------------------
+# the cell, from data files
+
+
+def find_cell(name):
+  """The cell's entry, configuration, traffic and limits, by name."""
+  bench_path = os.path.join(REPO, 'BENCHMARK.json')
+  if not os.path.exists(bench_path):
+    fail(2, 'no BENCHMARK.json at the root of the checkout')
+  bench = load_json(bench_path)
+  cells = {w['name']: dict(w, official=True) for w in bench['workloads']}
+  configs = {c['name']: c['file'] for c in bench['configs']}
+  for path in sorted(glob.glob(os.path.join(HERE, 'rehearsal', '*.json'))):
+    entry = load_json(path)
+    cells.setdefault(entry['name'], dict(entry, official=False))
+    configs.setdefault(entry['config'], entry['config_file'])
+  if name not in cells:
+    fail(2, f'no workload {name!r}; known: {sorted(cells)}')
+  cell = cells[name]
+  cell['config_data'] = load_json(os.path.join(REPO, configs[cell['config']]))
+  traffic_path = os.path.join(HERE, 'traffic', cell['traffic'] + '.json')
+  cell['traffic_data'] = load_json(traffic_path)
+  cell['limits'] = load_json(os.path.join(HERE, 'limits', name + '.json'))
+  cell['bench'] = bench
+  return cell
+
+
+def require_device(cell):
+  """Name the device; an official cell runs on a TPU with enough chips
+  or not at all."""
+  import jax
+  devices = jax.devices()
+  device = {'platform': devices[0].platform,
+            'kind': devices[0].device_kind, 'count': len(devices)}
+  say(f'jax {jax.__version__}; platform={device["platform"]} '
+      f'device_kind={device["kind"]!r} devices={device["count"]}')
+  if device['count'] < cell['chips']:
+    fail(3, f'the cell asks for {cell["chips"]} chip(s), jax sees '
+         f'{device["count"]}')
+  if cell['official'] and device['platform'] != 'tpu':
+    fail(3, f'no TPU: platform is {device["platform"]!r} (JAX_PLATFORMS='
+         f'{os.environ.get("JAX_PLATFORMS")!r}); a cell of the benchmark '
+         'never falls back to another backend')
+  return device
+
+
+# ----------------------------------------------------------------------------
+# traffic: corpus -> shards, by the program's own CLIs, cached per corpus
+
+
+def _cli(*args):
+  cmd = [sys.executable, '-m', 'lddl_tpu.cli', *map(str, args)]
+  say('+ ' + ' '.join(cmd[1:]))
+  # These stages never import jax (every back-end is pinned to the host);
+  # the variable makes sure a child could not reach for the chip anyway.
+  env = dict(os.environ, JAX_PLATFORMS='cpu')
+  subprocess.run(cmd, check=True, cwd=REPO, env=env, stdout=sys.stderr)
+
+
+def prepare_data(traffic):
+  """Balanced shards for this traffic file's corpus; made once per
+  checkout (users pay preprocessing once per corpus), found again by the
+  hash of what decides their bytes."""
+  from chipbench.corpus import write_corpus
+  recipe = {k: traffic[k] for k in ('corpus', 'preprocess', 'balance')}
+  digest = hashlib.sha256(
+      json.dumps(recipe, sort_keys=True).encode()).hexdigest()[:16]
+  final = os.path.join(WORK, 'data', digest)
+  if os.path.exists(os.path.join(final, 'DONE')):
+    say(f'shards: cached under {os.path.relpath(final, REPO)}')
+    return os.path.join(final, 'balanced')
+  t0 = time.perf_counter()
+  from lddl_tpu.native.build import build_library
+  say(f'native library: {os.path.basename(build_library())} '
+      f'({time.perf_counter() - t0:.1f}s)')
+  os.makedirs(os.path.dirname(final), exist_ok=True)
+  tmp = tempfile.mkdtemp(prefix=digest + '.', dir=os.path.dirname(final))
+  corpus = traffic['corpus']
+  mb = write_corpus(os.path.join(tmp, 'source'), corpus['target_mb'],
+                    num_shards=corpus['num_shards'],
+                    seed=corpus['corpus_seed'],
+                    doc_sentences=corpus.get('doc_sentences'))
+  say(f'corpus: {mb:.1f} MB from corpus_seed {corpus["corpus_seed"]}')
+  pre = traffic['preprocess']
+  _cli(pre['cli'], '--source', os.path.join(tmp, 'source'), '--sink',
+       os.path.join(tmp, 'shards'), '--vocab-file', VOCAB, *pre['args'])
+  _cli('balance_shards', '--indir', os.path.join(tmp, 'shards'), '--outdir',
+       os.path.join(tmp, 'balanced'), '--num-shards',
+       traffic['balance']['num_shards'])
+  shutil.rmtree(os.path.join(tmp, 'source'))
+  shutil.rmtree(os.path.join(tmp, 'shards'))
+  with open(os.path.join(tmp, 'DONE'), 'w') as f:
+    f.write(json.dumps(recipe, sort_keys=True))
+  try:
+    os.rename(tmp, final)
+  except OSError:  # another run of this checkout got there first
+    shutil.rmtree(tmp, ignore_errors=True)
+  say(f'shards: made in {time.perf_counter() - t0:.1f}s')
+  return os.path.join(final, 'balanced')
+
+
+def bin_lengths(shards, train):
+  """The sequence length of every batch shape the loader can yield."""
+  if not train.get('bin_size'):
+    return [train['max_seq_length']]
+  ids = sorted({int(name.rsplit('_', 1)[1]) for name in os.listdir(shards)
+                if '.parquet_' in name})
+  align = 8 if train['data_format'] == 'pairs' else 128
+  return sorted({
+      min(-(-train['bin_size'] * (i + 1) // align) * align,
+          train['max_seq_length']) for i in ids})
+
+
+# ----------------------------------------------------------------------------
+# the two taps
+
+
+def batch_facts(batch):
+  """What the arithmetic of required work needs to know of one batch."""
+  import numpy as np
+  mask = np.asarray(batch['attention_mask'])
+  rows = mask.sum(axis=1)
+  seg = batch.get('segment_ids')
+  if seg is None:
+    units = rows
+  else:
+    seg = np.asarray(seg)
+    units = np.concatenate([np.bincount(r[r >= 0]) for r in seg])
+    units = units[units > 0]
+  return {
+      'rows': [int(n) for n in rows],
+      'units': [int(n) for n in units],
+      'masked': [int(n) for n in
+                 (np.asarray(batch['labels']) != -100).sum(axis=1)],
+  }
+
+
+class LoaderTap:
+  """The host loader, with what each batch held written down. Iteration
+  runs on the prefetch thread, as the loader's own would."""
+
+  def __init__(self, inner, keep_first):
+    self._inner = inner
+    self._keep_first = keep_first
+    self.facts = []
+    self.first = []
+
+  def __getattr__(self, name):
+    return getattr(self._inner, name)
+
+  def __iter__(self):
+    import jax
+    import numpy as np
+    it = iter(self._inner)
+    while True:
+      with jax.profiler.TraceAnnotation('chipbench.loader_next'):
+        try:
+          batch = next(it)
+        except StopIteration:
+          return
+      if len(self.first) < self._keep_first:
+        self.first.append({k: np.array(v) for k, v in batch.items()})
+      self.facts.append(batch_facts(batch))
+      yield batch
+
+
+def make_step_tap(step_fn, window):
+  """``loop.step_fn`` as the program's own ``CompiledStepCache``, with the
+  time of every call written down."""
+  import jax
+
+  from lddl_tpu.training.pretrain import CompiledStepCache
+
+  class StepTap(CompiledStepCache):
+
+    def __call__(self, params, opt_state, rng, batch):
+      now = time.perf_counter()
+      if window.recording:
+        window.on_call(now, params, opt_state, self)
+      with jax.profiler.TraceAnnotation('chipbench.step_fn'):
+        return super().__call__(params, opt_state, rng, batch)
+
+  return StepTap(step_fn)
+
+
+class Window:
+  """Opens after ``warmup`` steps, closes ``seconds`` later; between the
+  two, nothing but reading the clock."""
+
+  HISTOGRAMS = ('train.data_wait_seconds', 'train.compute_seconds',
+                'train.step_seconds', 'train.h2d_seconds')
+
+  def __init__(self, cell, seed, seconds, trace_dir):
+    self.cell, self.seed, self.seconds = cell, seed, seconds
+    self.trace_dir = trace_dir
+    plan = cell['traffic_data']['window']
+    self.warmup = plan['warmup_steps']
+    if self.warmup <= COMPARED_STEPS:
+      fail(2, f'warmup_steps must exceed the {COMPARED_STEPS} compared steps')
+    # A traced run traces `trace_steps` steady steps after warm-up and
+    # opens the window two calls after the last of them, so that stopping
+    # the trace (seconds of host time) lies before the window, not in it.
+    self.trace_steps = plan['trace_steps'] if trace_dir else 0
+    self.open_at = self.warmup + (self.trace_steps + 2 if trace_dir else 0)
+    self.recording = False
+    self.calls = []
+    self.open_index = self.close_index = None
+    self.misses = {}
+    self.telemetry = {}
+    self.grad_norms = self.change_norms = None
+
+  def _telemetry(self):
+    from lddl_tpu.telemetry import get_telemetry
+    tele = get_telemetry()
+    if not tele.enabled:
+      return None
+    return {name: (tele.histogram(name).sum, tele.histogram(name).count)
+            for name in self.HISTOGRAMS}
+
+  def on_call(self, now, params, opt_state, tap):
+    i = len(self.calls)
+    self.calls.append(now)
+    if i == 1:
+      # The state after one step: Adam's first moment is (1 - b1) times
+      # the first gradient, as the optimizer got it.
+      from chipbench import adapter
+      self.grad_norms = adapter.leaf_norms(opt_state[0].mu,
+                                           scale=1.0 / (1.0 - ADAM_B1))
+    elif i == COMPARED_STEPS:
+      # The parameters after the compared steps, before this call
+      # donates them.
+      from chipbench import adapter
+      self.change_norms = adapter.change_norms(
+          self.cell['config_data'], self.seed, params)
+    elif i == self.warmup and self.trace_dir:
+      # Armed now, the loop's own profiler hook starts the trace once this
+      # step is done and stops it `trace_steps` steps later.
+      from lddl_tpu.telemetry.profiling import get_step_profiler
+      get_step_profiler().arm(self.trace_steps, out_dir=self.trace_dir)
+    if i == self.open_at:
+      self.open_index = i
+      self.misses['open'] = tap.misses
+      self.telemetry['open'] = self._telemetry()
+    elif (self.open_index is not None and self.close_index is None and
+          now - self.calls[self.open_index] >= self.seconds):
+      self.close_index = i
+      self.misses['close'] = tap.misses
+      self.telemetry['close'] = self._telemetry()
+      # The loop's own stop path: a preemption notice, acted on at the
+      # next step boundary. No checkpoint directory, so nothing is saved.
+      os.kill(os.getpid(), signal.SIGTERM)
+
+
+# ----------------------------------------------------------------------------
+# building the loop as pretrain.main does
+
+
+def fake_batch(batch, seq, block_diagonal):
+  import numpy as np
+  out = {
+      'input_ids': np.ones((batch, seq), np.int32),
+      'token_type_ids': np.zeros((batch, seq), np.int32),
+      'attention_mask': np.ones((batch, seq), np.int32),
+      'labels': np.full((batch, seq), -100, np.int32),
+      'next_sentence_labels': np.zeros((batch,), np.int32),
+  }
+  out['labels'][:, 1::7] = 5
+  if block_diagonal:
+    out['segment_ids'] = np.zeros((batch, seq), np.int32)
+  return out
+
+
+def bert_config(cell, train):
+  """``BertConfig`` as ``pretrain.main`` makes it, from the configuration
+  file; where the file names a preset of the program, its sizes have to
+  be that preset's."""
+  from lddl_tpu.models import BertConfig
+  from lddl_tpu.training.pretrain import MODEL_SIZES
+  c = cell['config_data']
+  sizes = dict(hidden_size=c['hidden_size'],
+               num_layers=c['num_hidden_layers'],
+               num_heads=c['num_attention_heads'],
+               intermediate_size=c['intermediate_size'])
+  preset = c.get('program_preset')
+  if preset and MODEL_SIZES[preset] != sizes:
+    fail(2, f'configuration {cell["config"]!r} says preset {preset!r} but '
+         f'its sizes {sizes} are not MODEL_SIZES[{preset!r}]')
+  if c['max_position_embeddings'] != max(train['max_seq_length'], 512):
+    fail(2, 'max_position_embeddings of the configuration file is not '
+         'max(max_seq_length, 512), which is what pretrain.main builds')
+  if c['attention_probs_dropout_prob'] != 0:
+    fail(2, 'the program has no dropout on attention probabilities; the '
+         'configuration file has to say 0 and list the key as changed')
+  return BertConfig(
+      vocab_size=c['vocab_size'],
+      max_position_embeddings=c['max_position_embeddings'],
+      type_vocab_size=c['type_vocab_size'],
+      dropout_rate=c['hidden_dropout_prob'],
+      attention_impl=train['attention'], remat=train['remat'], **sizes)
+
+
+def build_loop(cell, shards, seed, window):
+  import jax
+  import jax.numpy as jnp
+
+  from chipbench import adapter
+  from lddl_tpu.core.compile_cache import use_compile_cache
+  from lddl_tpu.loader.device import make_global_batch
+  from lddl_tpu.parallel import make_mesh, mesh_summary
+  from lddl_tpu.tokenization.wordpiece import load_bert_tokenizer
+  from lddl_tpu.training.pretrain import TrainLoop
+
+  train = cell['traffic_data']['train']
+  if train['prng'] != 'threefry':
+    jax.config.update('jax_default_prng_impl', train['prng'])
+  say(f'compile cache: {use_compile_cache()}')
+  jax.config.update('jax_persistent_cache_min_compile_time_secs', 0)
+  tokenizer = load_bert_tokenizer(vocab_file=VOCAB, backend='hf')
+  vocab = ((tokenizer.vocab_size + 63) // 64) * 64
+  if vocab != cell['config_data']['vocab_size']:
+    fail(2, f'the tokenizer gives a padded vocabulary of {vocab}, the '
+         f'configuration file says {cell["config_data"]["vocab_size"]}')
+  cfg = bert_config(cell, train)
+  mesh = make_mesh(**train['mesh'])
+  say(f'mesh: {mesh_summary(mesh)}')
+  loop = TrainLoop.build(
+      shards, tokenizer, model_cfg=cfg, mesh=mesh,
+      learning_rate=train['learning_rate'],
+      warmup_steps=train['warmup_steps'], total_steps=train['total_steps'],
+      weight_decay=train['weight_decay'],
+      batch_size_per_rank=train['batch_size'], bin_size=train['bin_size'],
+      max_seq_length=train['max_seq_length'], masking=train['masking'],
+      seed=seed, max_predictions=train['max_predictions'],
+      data_format=train['data_format'],
+      block_diagonal=train['block_diagonal'])
+  say('TrainLoop.build done')
+  loop.loader = LoaderTap(loop.loader, COMPARED_STEPS)
+  tap = make_step_tap(loop.step_fn, window)
+  loop.step_fn = tap
+
+  # Every shape the loader can yield is compiled now, through the loop's
+  # own step object and the program's own placement, on a batch of
+  # nothing; the state these steps leave is thrown away.
+  for seq in bin_lengths(shards, train):
+    placed = make_global_batch(
+        fake_batch(train['batch_size'], seq, train['block_diagonal']), mesh)
+    t0 = time.perf_counter()
+    loop.params, loop.opt_state, metrics = tap(
+        loop.params, loop.opt_state, loop.rng, placed)
+    float(metrics['loss'])
+    say(f'warmed [{train["batch_size"]}, {seq}] in '
+        f'{time.perf_counter() - t0:.1f}s')
+  for key, executable in getattr(tap, '_compiled', {}).items():
+    analysis = getattr(executable, 'memory_analysis', lambda: None)()
+    if analysis is not None:
+      shape = [k for k in key if k[0] == 'input_ids'][0][1]
+      total = (analysis.argument_size_in_bytes +
+               analysis.output_size_in_bytes +
+               analysis.temp_size_in_bytes - analysis.alias_size_in_bytes)
+      say(f'memory_analysis {list(shape)}: arguments '
+          f'{analysis.argument_size_in_bytes} output '
+          f'{analysis.output_size_in_bytes} temp '
+          f'{analysis.temp_size_in_bytes} alias '
+          f'{analysis.alias_size_in_bytes} -> live {total} bytes')
+
+  # The weights the reference will start from, in the program's tree;
+  # Adam's state starts at nought.
+  # The old weights go first, so that the chip never holds both and the
+  # peak it reports is a training job's.
+  like = jax.tree.map(
+      lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding),
+      loop.params)
+  jax.tree.map(lambda x: x.delete(), loop.params)
+  loop.params = adapter.seeded_program_params(cell['config_data'], seed,
+                                              like)
+  loop.opt_state = jax.jit(
+      lambda t: jax.tree.map(jnp.zeros_like, t), donate_argnums=0)(
+          loop.opt_state)
+  jax.block_until_ready((loop.params, loop.opt_state))
+  say('seeded weights in place')
+  return loop, tap
+
+
+# ----------------------------------------------------------------------------
+# metrics
+
+
+def device_memory(device):
+  """The peak a chip held: the allocator's buffers (``peak_bytes_in_use``:
+  state, batches) plus what it reserved for the programs' scratch
+  (``peak_bytes_reserved``: activations, gradients, temporaries), which
+  ``peak_bytes_in_use`` leaves out (PERF.md section 7)."""
+  stats = device.memory_stats() or {}
+  out = {k: int(stats.get(k, 0)) for k in
+         ('peak_bytes_in_use', 'peak_bytes_reserved', 'bytes_limit')}
+  out['peak_bytes'] = out['peak_bytes_in_use'] + out['peak_bytes_reserved']
+  return out
+
+
+def read_per_layer(names, ctx):
+  """One reader file per metric; a reader that finds nothing to read
+  returns None and the metric is left out."""
+  out = {}
+  for name in names:
+    path = os.path.join(HERE, 'metrics', name + '.py')
+    spec = importlib.util.spec_from_file_location(
+        'chipbench_metric_' + name.replace('.', '_'), path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    value = module.read(ctx)
+    if value is not None:
+      out[name] = float(value)
+  return out
+
+
+def wanted(metric, cell_name):
+  return 'workloads' not in metric or cell_name in metric['workloads']
+
+
+def main(argv=None):
+  parser = argparse.ArgumentParser(description=__doc__)
+  parser.add_argument('--workload', required=True)
+  parser.add_argument('--seed', type=int, required=True)
+  parser.add_argument('--seconds', type=float, required=True)
+  parser.add_argument('--trace', type=int, choices=(0, 1), default=0)
+  args = parser.parse_args(argv)
+  # Any whole number up to a little over 2**31 is a seed; the program's
+  # key and loader want one that 31 bits hold.
+  seed = args.seed % 2147483629
+
+  cell = find_cell(args.workload)
+  if not os.path.isdir(os.path.join(REPO, 'lddl_tpu')):
+    fail(2, 'the program (lddl_tpu/) is not in this checkout: there is '
+         'nothing to measure')
+  device = require_device(cell)
+  on_tpu = device['platform'] == 'tpu'
+  traffic = cell['traffic_data']
+  train = traffic['train']
+
+  import jax
+
+  from chipbench import compare, reference, required_work, trace_reduce
+  peaks = required_work.load_peaks(device['kind']) if on_tpu else None
+
+  say('imports done')
+  shards = prepare_data(traffic)
+  trace_dir = None
+  if args.trace:
+    from lddl_tpu.telemetry import enable
+    enable()
+    trace_dir = tempfile.mkdtemp(prefix='chipbench_trace_')
+  window = Window(cell, seed, args.seconds, trace_dir)
+  loop, tap = build_loop(cell, shards, seed, window)
+  say(f'set-up before the loop: {time.perf_counter() - T_START:.1f}s')
+
+  # --- the one call of TrainLoop.run: warm-up steps, then the window ---
+  window.recording = True
+  losses = loop.run(traffic['window']['max_steps'], log_every=0)
+  window.recording = False
+  if window.close_index is None:
+    fail(2, f'the loop ended after {len(window.calls)} steps before the '
+         'window closed: raise window.max_steps in the traffic file')
+  if args.trace:
+    from lddl_tpu.telemetry.profiling import get_step_profiler
+    get_step_profiler().close()
+
+  lo, hi = window.open_index, window.close_index
+  t_open, t_close = window.calls[lo], window.calls[hi]
+  wall = t_close - t_open
+  loader_facts = loop.loader.facts
+  facts = loader_facts[lo:hi]
+  intervals_ms = [1e3 * (b - a) for a, b in
+                  zip(window.calls[lo:hi], window.calls[lo + 1:hi + 1])]
+  tokens = sum(sum(f['rows']) for f in facts)
+  compiles = window.misses['close'] - window.misses['open']
+  finite = all(math.isfinite(x) for x in losses)
+  first_batches = loop.loader.first
+  program = {'losses': losses[:COMPARED_STEPS],
+             'grad_norms': window.grad_norms,
+             'change_norms': window.change_norms}
+  memory = max((device_memory(d) for d in jax.local_devices()),
+               key=lambda m: m['peak_bytes'])
+  say(f'window: {hi - lo} steps, {tokens} real tokens in {wall:.3f}s; '
+      f'loss {losses[lo]:.4f} -> {losses[hi - 1]:.4f}; compiles inside '
+      f'{compiles}; peak_bytes_in_use {memory["peak_bytes_in_use"]} + '
+      f'peak_bytes_reserved {memory["peak_bytes_reserved"]} = '
+      f'{memory["peak_bytes"]} of {memory["bytes_limit"]}')
+
+  # --- free the program's state, then follow its first steps ---
+  del loop, tap
+  gc.collect()
+  t0 = time.perf_counter()
+  from chipbench import adapter
+  ref = reference.follow(cell['config_data'], train, seed, first_batches,
+                         stream=adapter.DROPOUT_STREAM)
+  values = compare.numbers(program, ref)
+  values['compiles_in_window'] = compiles
+  correct, compared, observed = compare.judge(values, cell['limits'])
+  correct = correct and finite
+  say(f'reference followed {COMPARED_STEPS} steps in '
+      f'{time.perf_counter() - t0:.1f}s; program losses '
+      f'{program["losses"]} reference {ref["losses"]}; worst leaves: grad '
+      f'{values["_grad_gap_at"]}, change {values["_change_gap_at"]}; left '
+      f'out of the change: {values["_left_out_of_change"]}')
+
+  bench = cell['bench']
+  ctx = {
+      'cell': cell, 'config': cell['config_data'], 'train': train,
+      'chips': cell['chips'], 'peaks': peaks, 'wall_s': wall,
+      'steps': facts, 'intervals_ms': intervals_ms,
+      'compiles_in_window': compiles, 'memory': memory,
+      'telemetry': None, 'trace': None,
+      'traced_steps': loader_facts[window.warmup + 1:
+                                   window.warmup + 1 + window.trace_steps],
+  }
+  end_to_end = {
+      'tokens_per_s': (tokens / wall, 'tokens/s'),
+      'step_ms_p90': (statistics.quantiles(intervals_ms, n=10)[8]
+                      if len(intervals_ms) >= 2 else None, 'ms'),
+      'setup_s': (t_open - T_START, 's'),
+  }
+  device_out = dict(device, memory_peak_bytes=memory['peak_bytes'])
+  result = {'correct': bool(correct), 'attempted': hi - lo,
+            'failed': sum(not math.isfinite(x) for x in losses[lo:hi])}
+  metrics = {}
+  if args.trace:
+    opened, closed = window.telemetry['open'], window.telemetry['close']
+    ctx['telemetry'] = {
+        name: {'sum': closed[name][0] - opened[name][0],
+               'count': closed[name][1] - opened[name][1]}
+        for name in opened}
+    paths = glob.glob(os.path.join(trace_dir, '**', '*.xplane.pb'),
+                      recursive=True)
+    if paths:
+      t0 = time.perf_counter()
+      ctx['trace'] = trace_reduce.reduce(trace_reduce.extract(paths[0]))
+      say(f'trace of {os.path.getsize(paths[0])} bytes reduced in '
+          f'{time.perf_counter() - t0:.1f}s')
+    shutil.rmtree(trace_dir, ignore_errors=True)
+    if on_tpu:
+      units = {m['name']: m['unit'] for m in bench['per_layer']}
+      names = [m['name'] for m in bench['per_layer']
+               if wanted(m, cell['name']) or not cell['official']]
+      for name, value in read_per_layer(names, ctx).items():
+        metrics[name] = {'value': value, 'unit': units[name]}
+    if ctx['trace']:
+      device_out['busy_s'] = ctx['trace']['busy_s']
+      device_out['window_s'] = ctx['trace']['window_s']
+      result['breakdown'] = ctx['trace']['breakdown']
+  elif on_tpu:
+    for m in bench['end_to_end']:
+      value, unit = end_to_end[m['name']]
+      if wanted(m, cell['name']) and value is not None:
+        metrics[m['name']] = {'value': value, 'unit': unit}
+  if not on_tpu:
+    # Not a chip: counts only, and never under the name of a device metric.
+    result['rehearsal'] = {
+        'note': f'platform {device["platform"]}: no device metric',
+        'steps': hi - lo, 'real_tokens': tokens,
+        'per_layer_readers': sorted(read_per_layer(
+            [m['name'] for m in bench['per_layer']], ctx)),
+    }
+  result.update(metrics=metrics, device=device_out, observed=observed,
+                compared=compared)
+  for name, value in observed.items():
+    say(f'observed {name} = {value:.6g} (not compared)')
+  for name, pair in compared.items():
+    say(f'compared {name} = {pair["value"]:.6g} (limit {pair["limit"]:g})')
+  say(f'correct = {result["correct"]}')
+  print(json.dumps(result), flush=True)
+
+
+if __name__ == '__main__':
+  main()
