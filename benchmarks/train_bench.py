@@ -168,7 +168,6 @@ def build_train_state(args, tokenizer):
       max_position_embeddings=max(args.max_seq_length, 512),
       attention_impl=args.attention,
       dropout_rate=args.dropout,
-      ablate=args.ablate,
       fused_qkv=args.fused_qkv,
       remat=args.remat)
   model = BertForPretraining(cfg)
@@ -542,10 +541,6 @@ def attach_args(parser):
                       help="jax PRNG impl; 'rbg' makes per-step dropout "
                       'draws ~free on TPU (weaker statistical guarantees '
                       'than threefry, fine for dropout)')
-  parser.add_argument('--ablate', default='',
-                      choices=['', 'attention-core', 'ffn', 'norms', 'gelu'],
-                      help='drop one model component (profiling aid; see '
-                      'BertConfig.ablate)')
   parser.add_argument('--dropout', type=float, default=0.1,
                       help='model dropout rate (0 disables the per-step '
                       'RNG draws entirely)')

@@ -1,0 +1,11 @@
+"""Share of the device's busy time inside step programs spent in
+remat's recomputation of the forward pass inside the backward
+pass; 0 where the cell runs without remat.
+From the program's capture summary (device operations billed by their
+``op_name``). Layer: train step. Moves ``tokens_per_s``."""
+
+from chipbench import capture_summary
+
+
+def read(ctx):
+  return capture_summary.busy_share_pct('passes', 'recompute')

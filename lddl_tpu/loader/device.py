@@ -132,7 +132,9 @@ def prefetch_to_device(iterator, mesh=None, data_axis=None, seq_axis=None,
         continue
     return False
 
-  tracer = get_tracer()
+  # Ring-buffer spans under LDDL_TRACE, spans of the profiler's own trace
+  # while a capture runs (telemetry/trace.py), else the shared no-op.
+  phase = get_tracer().phase
   tele = get_telemetry()
   # Histogram twin of the train.h2d trace span: the live overlap meter
   # needs h2d totals in the metrics registry (1 - data_wait/h2d), and
@@ -176,7 +178,14 @@ def prefetch_to_device(iterator, mesh=None, data_axis=None, seq_axis=None,
   def _producer():
     nonlocal feed_index
     try:
-      for item in iterator:
+      host_batches = iter(iterator)
+      while True:
+        # The pull of the host batch, on the producer's lane: what the
+        # loader costs when the feed is not hidden behind the step.
+        with phase('loader.next'):
+          item = next(host_batches, _SENTINEL)
+        if item is _SENTINEL:
+          break
         if ledger.enabled:
           # The device boundary: the last stop where the batch is still
           # host bytes. Hashed on the producer thread, so the cost
@@ -185,7 +194,7 @@ def prefetch_to_device(iterator, mesh=None, data_axis=None, seq_axis=None,
         feed_index += 1
         # The host-to-device transfer phase, on the producer thread's
         # own trace lane (overlaps the main thread's compute span).
-        with tracer.span('train.h2d'), h2d_hist.time():
+        with phase('train.h2d'), h2d_hist.time():
           placed = _put(item)
         if tele.enabled:
           _track(placed, +1)

@@ -50,11 +50,6 @@ class BertConfig:
   # MXU calls (opt-in: changes the param tree, so checkpoints are not
   # interchangeable with the unfused layout).
   fused_qkv: bool = False
-  # Profiling aid (benchmarks/train_bench.py --ablate): drop one component
-  # to attribute step time. '' (default) = the real model; 'attention-core'
-  # (ctx := v, q/k gemms DCE'd), 'ffn', 'norms', 'gelu'. Never set in
-  # training configs.
-  ablate: str = ''
 
   @property
   def head_dim(self):
@@ -90,10 +85,7 @@ class SelfAttention(nn.Module):
     q = q.reshape(b, s, heads, hd).transpose(0, 2, 1, 3)
     k = k.reshape(b, s, heads, hd).transpose(0, 2, 1, 3)
     v = v.reshape(b, s, heads, hd).transpose(0, 2, 1, 3)
-    if cfg.ablate == 'attention-core':
-      ctx = v
-    elif (cfg.attention_impl in ('ring', 'ring_flash') and
-          self.mesh is not None):
+    if cfg.attention_impl in ('ring', 'ring_flash') and self.mesh is not None:
       from ..parallel.ring import make_ring_attention
       block_impl = 'flash' if cfg.attention_impl == 'ring_flash' else 'dense'
       attend = make_ring_attention(self.mesh, block_impl=block_impl,
@@ -146,20 +138,19 @@ class Layer(nn.Module):
     cfg, deterministic = self.cfg, self.deterministic
     attn = SelfAttention(cfg, self.mesh, deterministic, name='attention')(
         x, attention_mask, segment_ids)
-    x = x + attn
-    if cfg.ablate != 'norms':
-      x = nn.LayerNorm(dtype=cfg.dtype, name='attention_norm')(x)
-    if cfg.ablate == 'ffn':
-      return x
+    # The scopes name what is no flax module, for the capture summary
+    # (telemetry/capture.py); they are metadata and change no arithmetic.
+    with jax.named_scope('residual'):
+      x = x + attn
+    x = nn.LayerNorm(dtype=cfg.dtype, name='attention_norm')(x)
     h = _dense(cfg.intermediate_size, cfg, 'intermediate')(x)
-    if cfg.ablate != 'gelu':
+    with jax.named_scope('gelu'):
       h = nn.gelu(h, approximate=True)
     h = _dense(cfg.hidden_size, cfg, 'output')(h)
     h = nn.Dropout(cfg.dropout_rate)(h, deterministic=deterministic)
-    x = x + h
-    if cfg.ablate != 'norms':
-      x = nn.LayerNorm(dtype=cfg.dtype, name='output_norm')(x)
-    return x
+    with jax.named_scope('residual'):
+      x = x + h
+    return nn.LayerNorm(dtype=cfg.dtype, name='output_norm')(x)
 
 
 class Encoder(nn.Module):
@@ -232,20 +223,28 @@ class BertForPretraining(nn.Module):
     cfg = self.cfg
     s = input_ids.shape[1]
     pos = jnp.arange(s, dtype=jnp.int32)[None, :]
-    x = (self.word_embeddings(input_ids) + self.position_embeddings(pos) +
-         self.token_type_embeddings(token_type_ids))
-    x = self.embed_dropout(self.embed_norm(x), deterministic=deterministic)
+    # embed / mlm_head / nsp_head name what happens between the modules
+    # (the sum, the gather, the tied decoder, tanh) for the capture
+    # summary; a named_scope is metadata and no flax scope, so parameter
+    # paths and dropout keys stay as they are.
+    with jax.named_scope('embed'):
+      x = (self.word_embeddings(input_ids) + self.position_embeddings(pos) +
+           self.token_type_embeddings(token_type_ids))
+      x = self.embed_norm(x)
+    x = self.embed_dropout(x, deterministic=deterministic)
     mask = attention_mask.astype(bool)
     x = self.encoder(x, mask, deterministic, segment_ids)
 
-    x_mlm = x
-    if mlm_positions is not None:
-      x_mlm = jnp.take_along_axis(x, mlm_positions[:, :, None], axis=1)
-    h = self.mlm_norm(nn.gelu(self.mlm_transform(x_mlm), approximate=True))
-    mlm_logits = (self.word_embeddings.attend(h).astype(jnp.float32) +
-                  self.mlm_bias)
-    pooled = jnp.tanh(self.pooler(x[:, 0]))
-    nsp_logits = self.nsp_classifier(pooled).astype(jnp.float32)
+    with jax.named_scope('mlm_head'):
+      x_mlm = x
+      if mlm_positions is not None:
+        x_mlm = jnp.take_along_axis(x, mlm_positions[:, :, None], axis=1)
+      h = self.mlm_norm(nn.gelu(self.mlm_transform(x_mlm), approximate=True))
+      mlm_logits = (self.word_embeddings.attend(h).astype(jnp.float32) +
+                    self.mlm_bias)
+    with jax.named_scope('nsp_head'):
+      pooled = jnp.tanh(self.pooler(x[:, 0]))
+      nsp_logits = self.nsp_classifier(pooled).astype(jnp.float32)
     return mlm_logits, nsp_logits
 
 

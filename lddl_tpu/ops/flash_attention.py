@@ -370,6 +370,7 @@ def _flash_fwd_impl(q, k, v, bias, q_seg, kv_seg, heads):
           pltpu.VMEM((block_q, d), jnp.float32),
       ],
       interpret=_interpret(),
+      name='flash_fwd',
   )(*inputs)
   return out, lse
 
@@ -418,6 +419,7 @@ def _flash_bwd(heads, res, cotangents):
       out_shape=jax.ShapeDtypeStruct((bh, s_q, d), q.dtype),
       scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
       interpret=_interpret(),
+      name='flash_dq',
   )(*dq_inputs)
 
   # dk/dv: grid (bh, kv-blocks, q-blocks), q innermost; the (k, v) block
@@ -451,6 +453,7 @@ def _flash_bwd(heads, res, cotangents):
           pltpu.VMEM((block_k, d), jnp.float32),
       ],
       interpret=_interpret(),
+      name='flash_dkv',
   )(*dkv_inputs)
   return (dq, dk[:, :s_kv, :], dv[:, :s_kv, :], jnp.zeros_like(bias),
           None if q_seg is None else jnp.zeros_like(q_seg),

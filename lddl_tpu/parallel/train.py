@@ -158,10 +158,13 @@ def pretrain_loss(model, params, batch, dropout_rng=None,
     # unmasked columns whose gathered labels are IGNORE_INDEX (stable
     # argsort of the ~masked bitmap = masked columns first, in order).
     p = min(max_predictions, labels.shape[1])
-    mlm_positions = jnp.argsort(
-        labels == IGNORE_INDEX, axis=1, stable=True,
-    )[:, :p].astype(jnp.int32)
-    labels = jnp.take_along_axis(labels, mlm_positions, axis=1)
+    # 'loss' names what is no flax module for the capture summary
+    # (telemetry/capture.py); a scope is metadata, not arithmetic.
+    with jax.named_scope('loss'):
+      mlm_positions = jnp.argsort(
+          labels == IGNORE_INDEX, axis=1, stable=True,
+      )[:, :p].astype(jnp.int32)
+      labels = jnp.take_along_axis(labels, mlm_positions, axis=1)
   segment_ids = batch.get('segment_ids')
   mlm_logits, nsp_logits = model.apply(
       {'params': params},
@@ -172,28 +175,29 @@ def pretrain_loss(model, params, batch, dropout_rng=None,
       mlm_positions=mlm_positions,
       segment_ids=segment_ids,
       rngs=rngs)
-  masked = labels != IGNORE_INDEX
-  safe_labels = jnp.where(masked, labels, 0)
-  mlm_ce = optax.softmax_cross_entropy_with_integer_labels(
-      mlm_logits, safe_labels)
-  denom = jnp.maximum(masked.sum(), 1)
-  if segment_ids is not None:
-    seg = segment_ids
-    if mlm_positions is not None:
-      seg = jnp.take_along_axis(segment_ids, mlm_positions, axis=1)
-    mlm_loss = per_doc_mlm_loss(mlm_ce, masked, seg,
-                                num_docs_cap=batch['input_ids'].shape[1])
-  else:
-    mlm_loss = jnp.where(masked, mlm_ce, 0.0).sum() / denom
-  nsp_loss = optax.softmax_cross_entropy_with_integer_labels(
-      nsp_logits, batch['next_sentence_labels']).mean()
-  mlm_acc = jnp.where(masked,
-                      jnp.argmax(mlm_logits, -1) == labels, False).sum() / denom
-  return mlm_loss + nsp_loss, {
-      'mlm_loss': mlm_loss,
-      'nsp_loss': nsp_loss,
-      'mlm_acc': mlm_acc,
-  }
+  with jax.named_scope('loss'):
+    masked = labels != IGNORE_INDEX
+    safe_labels = jnp.where(masked, labels, 0)
+    mlm_ce = optax.softmax_cross_entropy_with_integer_labels(
+        mlm_logits, safe_labels)
+    denom = jnp.maximum(masked.sum(), 1)
+    if segment_ids is not None:
+      seg = segment_ids
+      if mlm_positions is not None:
+        seg = jnp.take_along_axis(segment_ids, mlm_positions, axis=1)
+      mlm_loss = per_doc_mlm_loss(mlm_ce, masked, seg,
+                                  num_docs_cap=batch['input_ids'].shape[1])
+    else:
+      mlm_loss = jnp.where(masked, mlm_ce, 0.0).sum() / denom
+    nsp_loss = optax.softmax_cross_entropy_with_integer_labels(
+        nsp_logits, batch['next_sentence_labels']).mean()
+    mlm_acc = jnp.where(
+        masked, jnp.argmax(mlm_logits, -1) == labels, False).sum() / denom
+    return mlm_loss + nsp_loss, {
+        'mlm_loss': mlm_loss,
+        'nsp_loss': nsp_loss,
+        'mlm_acc': mlm_acc,
+    }
 
 
 def _train_step_body(model, tx, mesh, params, opt_state, rng, batch,
@@ -201,8 +205,9 @@ def _train_step_body(model, tx, mesh, params, opt_state, rng, batch,
   """One un-jitted train step — the single definition both
   :func:`make_train_step` and :func:`make_scan_train_step` compile, so the
   per-step and scan-window paths stay provably identical."""
-  rng = jax.random.fold_in(
-      rng, opt_state[0].count if hasattr(opt_state[0], 'count') else 0)
+  with jax.named_scope('dropout_key'):
+    rng = jax.random.fold_in(
+        rng, opt_state[0].count if hasattr(opt_state[0], 'count') else 0)
 
   def loss_fn(p):
     return pretrain_loss(model, p, batch, dropout_rng=rng,
@@ -213,9 +218,11 @@ def _train_step_body(model, tx, mesh, params, opt_state, rng, batch,
   # reduction inside the compiled step, read on the host for free once
   # the loss scalar has already forced the device sync. This is the
   # sentinel's grad_spike signal and the train.grad_norm gauge.
-  metrics['grad_norm'] = optax.global_norm(grads)
-  updates, opt_state = tx.update(grads, opt_state, params)
-  params = optax.apply_updates(params, updates)
+  with jax.named_scope('grad_norm'):
+    metrics['grad_norm'] = optax.global_norm(grads)
+  with jax.named_scope('optimizer'):
+    updates, opt_state = tx.update(grads, opt_state, params)
+    params = optax.apply_updates(params, updates)
   # The state leaves the step laid out as it came in. Left to itself the
   # partitioner hands replicated-by-rule leaves (biases, norms) back
   # split over fsdp, which the next call of an AOT-compiled step rejects

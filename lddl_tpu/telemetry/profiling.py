@@ -12,7 +12,11 @@ Two entry points over one code path:
     land under ``LDDL_TELEMETRY_DIR/profiles/`` (same layout the bench
     context manager uses), numbered per capture, so a long pretrain can
     be profiled without a restart and costs nothing while unarmed: the
-    unarmed ``on_step`` path is two attribute reads.
+    unarmed ``on_step`` path is two attribute reads. While a capture
+    runs, ``tracer.phase`` (:mod:`.trace`) writes the loop's phases into
+    the profiler's trace; when ``on_step()`` stops it, the capture is
+    summarized (:mod:`.capture`: idle gaps by phase, device time by
+    module class and pass) into ``last_summary`` and ``summary.json``.
 
 The profiler singleton is plain state, not a thread or a socket — with
 ``LDDL_MONITOR`` unset nothing ever arms it, preserving the PR 7 no-op
@@ -20,6 +24,7 @@ guarantees (pinned by tests/test_monitor.py and tests/test_roofline.py).
 """
 
 import contextlib
+import logging
 import os
 import threading
 
@@ -64,6 +69,9 @@ class StepProfiler:
     self._out_dir = None
     self._capture_index = 0
     self.last_trace_dir = None
+    # :func:`lddl_tpu.telemetry.capture.summarize` of the newest finished
+    # capture, or None (no capture yet, or its trace was not readable).
+    self.last_summary = None
 
   def arm(self, steps, out_dir=None):
     """Request a capture of the next ``steps`` train steps; returns the
@@ -80,6 +88,7 @@ class StepProfiler:
     directory when this call *finished* a capture, else None."""
     if not self._armed_steps and not self._active_steps:
       return None
+    finished = None
     with self._lock:
       if self._armed_steps and not self._active_steps:
         import jax
@@ -98,8 +107,11 @@ class StepProfiler:
         if self._active_steps == 0:
           import jax
           jax.profiler.stop_trace()
-          return self.last_trace_dir
-      return None
+          finished = self.last_trace_dir
+    if finished is not None:
+      # Outside the lock: reading the trace takes about a second.
+      self.last_summary = _summarize(finished)
+    return finished
 
   def close(self):
     """Stop any in-flight trace (train-loop teardown); idempotent."""
@@ -119,6 +131,19 @@ class StepProfiler:
   @property
   def armed(self):
     return bool(self._armed_steps or self._active_steps)
+
+
+def _summarize(trace_dir):
+  """The capture's summary, or None: a trace that is missing or cannot be
+  read costs the operator the table, never the training run."""
+  from .capture import summarize_capture
+  try:
+    return summarize_capture(trace_dir)
+  except Exception:  # the train loop's boundary to a diagnostics reader
+    logging.getLogger('lddl_tpu').warning(
+        'profiler: no summary of the capture under %s', trace_dir,
+        exc_info=True)
+    return None
 
 
 _profiler = None

@@ -34,6 +34,17 @@ matched collective events (``comm.allgather``/``comm.barrier`` carry a
 sequence number, and all ranks complete collective ``#seq`` within one
 collective latency of each other), so cross-host unix-clock skew does
 not smear the merged timeline.
+
+**Phases: one instrumentation site, two sinks.** ``tracer.phase(name,
+step)`` is what the train loop and the device feed wrap their phases
+in (``train.step`` and its children, ``train.h2d``, ``loader.next``).
+It feeds the ring buffer above when ``LDDL_TRACE`` is on, and, whenever
+the process's :class:`~.profiling.StepProfiler` has a capture running,
+a ``jax.profiler.TraceAnnotation``: the span then sits on a
+``/host:CPU`` thread line of the profiler's own trace, on the device
+trace's clock, where :mod:`.capture` lays it over the device's idle
+gaps. The two sinks are independent (a capture needs no ``LDDL_TRACE``);
+with neither on, ``phase`` returns the shared no-op span.
 """
 
 import collections
@@ -45,6 +56,8 @@ import sys
 import tempfile
 import threading
 import time
+
+from . import profiling
 
 
 class _NoopSpan:
@@ -62,6 +75,35 @@ class _NoopSpan:
 _NOOP_SPAN = _NoopSpan()
 
 
+def _annotation(name, step):
+  """The phase as a span of the running profiler capture (jax is loaded:
+  a capture runs)."""
+  from jax.profiler import TraceAnnotation
+  if step is None:
+    return TraceAnnotation(name)
+  return TraceAnnotation(name, step=step)
+
+
+class _BothSinks:
+  """One phase into the profiler's trace and the ring buffer."""
+
+  __slots__ = ('_annotation', '_span')
+
+  def __init__(self, annotation, span):
+    self._annotation = annotation
+    self._span = span
+
+  def __enter__(self):
+    self._annotation.__enter__()
+    self._span.__enter__()
+    return self
+
+  def __exit__(self, *exc):
+    self._span.__exit__(*exc)
+    self._annotation.__exit__(*exc)
+    return False
+
+
 class NoopTracer:
   """The disabled recorder: every method is empty, every handle shared."""
 
@@ -75,6 +117,14 @@ class NoopTracer:
     pass
 
   def span(self, name, args=None):
+    return _NOOP_SPAN
+
+  def phase(self, name, step=None):
+    # Off (no capture running) this is two attribute reads: no
+    # allocation, no clock read.
+    profiler = profiling._profiler
+    if profiler is not None and profiler._active_steps:
+      return _annotation(name, step)
     return _NOOP_SPAN
 
   def complete(self, name, start, duration, tid=None, args=None):
@@ -178,6 +228,16 @@ class Tracer:
   def span(self, name, args=None):
     """Context manager recording one complete event for its wall time."""
     return _Span(self, name, args)
+
+  def phase(self, name, step=None):
+    """A phase of the train loop or the device feed: a ring-buffer span
+    (``args={'step': step}``: the ``train.step`` it belongs to) and,
+    while a profiler capture runs, a span of the profiler's trace too."""
+    span = _Span(self, name, None if step is None else {'step': step})
+    profiler = profiling._profiler
+    if profiler is not None and profiler._active_steps:
+      return _BothSinks(_annotation(name, step), span)
+    return span
 
   def complete(self, name, start, duration, tid=None, args=None):
     """A 'X' event with explicit monotonic ``start`` and ``duration``."""

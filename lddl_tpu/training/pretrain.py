@@ -499,12 +499,19 @@ class TrainLoop:
     global_batch = self.loader.batch_size * max(self.dp_world, 1)
     tele = get_telemetry()
     tracer = get_tracer()
+    # One instrumentation site, two sinks (telemetry/trace.py): the ring
+    # buffer under LDDL_TRACE, and the profiler's own trace while a
+    # capture runs, where telemetry/capture.py lays these phases over the
+    # device's idle gaps. Off, each call returns the shared no-op span.
+    phase = tracer.phase
     data_wait_h = tele.histogram('train.data_wait_seconds')
     compute_h = tele.histogram('train.compute_seconds')
     step_h = tele.histogram('train.step_seconds')
+    epoch_turn_h = tele.histogram('train.epoch_turn_seconds')
     steps_c = tele.counter('train.steps')
     samples_c = tele.counter('train.samples')
     grad_norm_g = tele.gauge('train.grad_norm')
+    samples_per_sec_g = tele.gauge('train.samples_per_sec')
     tiles_total_c = tele.counter('train.attn_tiles_total')
     tiles_skipped_c = tele.counter('train.attn_tiles_skipped')
     peak_total = _peak_flops_total() if tele.enabled else None
@@ -521,162 +528,181 @@ class TrainLoop:
     poll_at = time.monotonic()
     rate_anchor = (self.step, time.monotonic())
     losses = []
+
+    def open_stream():
+      # The flight recorder tees the *host* iterator (device arrays
+      # can't be packed); ordinal0 = the global step the next batch
+      # feeds, so ring entries carry their ledger collate coordinate.
+      return prefetch_to_device(
+          flight.wrap_host_stream(iter(self.loader), self.loader,
+                                  ordinal0=self.step),
+          mesh=self.mesh, size=prefetch)
+
+    stream = None
     try:
+      if self.step < max_steps:
+        stream = open_stream()
+      steps_this_epoch = 0
+      t_log = time.perf_counter()
+      t_turn = None  # set while an epoch turn waits for its first batch
       while self.step < max_steps and self.stop_reason is None:
-        # The flight recorder tees the *host* iterator (device arrays
-        # can't be packed); ordinal0 = the global step the next batch
-        # feeds, so ring entries carry their ledger collate coordinate.
-        stream = prefetch_to_device(
-            flight.wrap_host_stream(iter(self.loader), self.loader,
-                                    ordinal0=self.step),
-            mesh=self.mesh, size=prefetch)
-        t0 = time.perf_counter()
-        steps_this_epoch = 0
-        while True:
+        step_no = self.step
+        with phase('train.step', step_no):
           # Pull the batch explicitly so the stall waiting on the input
           # pipeline (data wait) is timed separately from the step itself:
           # the split is the report's loader-vs-compute bottleneck signal.
+          # The pull also frees the previous batch's device buffers.
           t_wait = time.perf_counter()
-          tm_wait = time.monotonic() if tracer.enabled else 0.0
-          try:
-            batch = next(stream)
-          except StopIteration:
-            break
+          with phase('train.data_wait', step_no):
+            batch = next(stream, None)
+          if batch is None:
+            # The epoch is exhausted: close its feed (joins the prefetch
+            # thread) and open the next one's.
+            t_turn = time.perf_counter()
+            with phase('train.epoch_turn', step_no):
+              stream.close()
+              if steps_this_epoch == 0:
+                raise ValueError(
+                    'loader yielded zero batches for a full epoch (dataset '
+                    'smaller than one global batch?); refusing to spin — '
+                    'reduce --batch-size or provide more data')
+              stream = open_stream()
+            steps_this_epoch = 0
+            t_log = time.perf_counter()
+            continue
           t_step = time.perf_counter()
-          tm_step = time.monotonic() if tracer.enabled else 0.0
-          if tracer.enabled:
-            tracer.complete('train.data_wait', tm_wait, tm_step - tm_wait,
-                            args={'step': self.step})
-          data_wait_h.observe(t_step - t_wait)
+          data_wait = t_step - t_wait
+          data_wait_h.observe(data_wait)
+          if t_turn is not None:
+            epoch_turn_h.observe(t_step - t_turn)
+            t_turn = None
           # After the batch pull, before the step: a 'kill' here models a
           # rank dying mid-training, a 'term' models the preemption notice.
           faults.inject('train.step', rank=self.dp_rank)
           steps_this_epoch += 1
-          step_no = self.step
-          self.params, self.opt_state, metrics = self.step_fn(
-              self.params, self.opt_state, self.rng, batch)
-          # float() blocks until the device finishes the step, so the
-          # compute span covers real execution, not just dispatch.
-          loss = float(metrics['loss'])
-          # The loss read above already paid the device sync; this one
-          # is a host copy of an already-materialized scalar.
-          gn = metrics.get('grad_norm')
-          grad_norm = float(gn) if gn is not None else None
-          losses.append(loss)
-          self._last_loss = loss
-          self.step += 1
-          self.samples_seen += global_batch
-          if not math.isfinite(loss) and nonfinite_stop:
-            # Stop at the step boundary behind the trailing emergency
-            # checkpoint (the preemption stop path) instead of training
-            # on garbage. LDDL_NONFINITE=ignore opts out.
-            self.stop_reason = 'nonfinite_loss'
-          data_wait = t_step - t_wait
-          trigger = sentinel.observe_step(step_no, loss=loss,
-                                          grad_norm=grad_norm,
-                                          data_wait=data_wait)
-          flight.record_step(step_no, loss=loss, grad_norm=grad_norm,
-                             data_wait=data_wait)
-          if trigger is not None:
-            incident = flight.capture(trigger)
-            if incident:
-              print(f'sentinel: {trigger["detector"]} fired at step '
-                    f'{step_no} — incident captured to {incident}')
-            else:
-              print(f'sentinel: {trigger["detector"]} fired at step '
-                    f'{step_no} ({trigger["reason"]})')
-          finished_trace = profiler.on_step()
-          if finished_trace:
-            print(f'profiler: wrote trace for step {self.step} window to '
-                  f'{finished_trace}')
-          if tracer.enabled:
-            tm_now = time.monotonic()
-            tracer.complete('train.compute', tm_step, tm_now - tm_step,
-                            args={'step': step_no})
-            tracer.counter('train.samples_per_sec',
-                           self.loader.batch_size / max(tm_now - tm_wait,
-                                                        1e-9))
-          if tele.enabled:
-            now = time.perf_counter()
-            compute_h.observe(now - t_step)
-            step_h.observe(now - t_wait)
-            steps_c.add(1)
-            samples_c.add(self.loader.batch_size)
-            if grad_norm is not None:
-              grad_norm_g.set(grad_norm)
-            tele.gauge('train.samples_per_sec').set(
-                self.loader.batch_size / max(now - t_wait, 1e-9))
-            if peak_total:
-              # Prefer XLA's own cost model (captured at compile time by
-              # the step cache) over the analytic estimate: the measured
-              # numerator reflects fusion, remat, and the real partitioned
-              # program, so MFU stops drifting from what the chip ran.
-              measured = getattr(self.step_fn, 'last_costs', None)
-              if measured is not None:
-                numerator = measured[0]
-              elif self.flops_fn is not None:
-                b, s = batch['input_ids'].shape
-                numerator = self.flops_fn(b, s)
+          # train.compute: the ring buffer's parent of dispatch + the
+          # loss read (the Perfetto merge's compute lane).
+          with tracer.span('train.compute',
+                           {'step': step_no} if tracer.enabled else None):
+            with phase('train.dispatch', step_no):
+              self.params, self.opt_state, metrics = self.step_fn(
+                  self.params, self.opt_state, self.rng, batch)
+            with phase('train.loss_read', step_no):
+              # float() blocks until the device finishes the step: the
+              # device sync.
+              loss = float(metrics['loss'])
+          with phase('train.after_step', step_no):
+            # The loss read above already paid the device sync; this one
+            # is a host copy of an already-materialized scalar.
+            gn = metrics.get('grad_norm')
+            grad_norm = float(gn) if gn is not None else None
+            losses.append(loss)
+            self._last_loss = loss
+            self.step += 1
+            self.samples_seen += global_batch
+            if not math.isfinite(loss) and nonfinite_stop:
+              # Stop at the step boundary behind the trailing emergency
+              # checkpoint (the preemption stop path) instead of training
+              # on garbage. LDDL_NONFINITE=ignore opts out.
+              self.stop_reason = 'nonfinite_loss'
+            trigger = sentinel.observe_step(step_no, loss=loss,
+                                            grad_norm=grad_norm,
+                                            data_wait=data_wait)
+            flight.record_step(step_no, loss=loss, grad_norm=grad_norm,
+                               data_wait=data_wait)
+            if trigger is not None:
+              incident = flight.capture(trigger)
+              if incident:
+                print(f'sentinel: {trigger["detector"]} fired at step '
+                      f'{step_no} — incident captured to {incident}')
               else:
-                numerator = None
-              if numerator:
-                tele.gauge('train.mfu').set(
-                    numerator / (max(now - t_wait, 1e-9) * peak_total))
-            if 'segment_ids' in batch:
-              # Host-side mirror of the kernel's tile-skip rule: the
-              # goodput signal for how much attention work block-diagonal
-              # packing actually removed this step.
-              import numpy as np
+                print(f'sentinel: {trigger["detector"]} fired at step '
+                      f'{step_no} ({trigger["reason"]})')
+            finished_trace = profiler.on_step()
+            if finished_trace:
+              print(f'profiler: wrote trace for step {self.step} window to '
+                    f'{finished_trace}')
+              if profiler.last_summary is not None:
+                from ..telemetry.capture import format_table
+                print(format_table(profiler.last_summary))
+            if tracer.enabled or tele.enabled:
+              now = time.perf_counter()
+              samples_per_sec = (self.loader.batch_size /
+                                 max(now - t_wait, 1e-9))
+              tracer.counter('train.samples_per_sec', samples_per_sec)
+            if tele.enabled:
+              compute_h.observe(now - t_step)
+              step_h.observe(now - t_wait)
+              steps_c.add(1)
+              samples_c.add(self.loader.batch_size)
+              if grad_norm is not None:
+                grad_norm_g.set(grad_norm)
+              samples_per_sec_g.set(samples_per_sec)
+              if peak_total:
+                # Prefer XLA's own cost model (captured at compile time by
+                # the step cache) over the analytic estimate: the measured
+                # numerator reflects fusion, remat, and the real
+                # partitioned program, so MFU stops drifting from what the
+                # chip ran.
+                measured = getattr(self.step_fn, 'last_costs', None)
+                if measured is not None:
+                  numerator = measured[0]
+                elif self.flops_fn is not None:
+                  b, s = batch['input_ids'].shape
+                  numerator = self.flops_fn(b, s)
+                else:
+                  numerator = None
+                if numerator:
+                  tele.gauge('train.mfu').set(
+                      numerator / (max(now - t_wait, 1e-9) * peak_total))
+              if 'segment_ids' in batch:
+                # Host-side mirror of the kernel's tile-skip rule: the
+                # goodput signal for how much attention work block-diagonal
+                # packing actually removed this step.
+                import numpy as np
 
-              from ..ops.flash_attention import count_skippable_tiles
-              total, skipped = count_skippable_tiles(
-                  np.asarray(batch['segment_ids']))
-              tiles_total_c.add(total)
-              tiles_skipped_c.add(skipped)
-          if log_every and self.step % log_every == 0:
-            dt = time.perf_counter() - t0
-            t0 = time.perf_counter()
-            print(f'step={self.step} loss={loss:.4f} '
-                  f'samples_seen={self.samples_seen} '
-                  f'({log_every * global_batch / max(dt, 1e-9):.1f} '
-                  'samples/s)')
-          if writer is not None:
-            # First-error-wins: a checkpoint that died in the background
-            # fails the run at the next step, not at the final flush.
-            writer.raise_pending()
-          if guard.requested:
-            self.stop_reason = 'preempted'
-          elif membership is not None:
-            now_m = time.monotonic()
-            # lddl: noqa[LDA003] membership poll cadence: the clock only
-            # rate-limits lease-store sweeps to one per heartbeat interval;
-            # a late poll delays noticing an already-recorded fleet event,
-            # it never changes any rank's verdict.
-            if now_m >= poll_at:
-              poll_at = now_m + membership.interval
-              w_step, w_t = rate_anchor
-              membership.publish_signals(
-                  {'steps_per_sec':
-                   (self.step - w_step) / max(now_m - w_t, 1e-9)})
-              rate_anchor = (self.step, now_m)
-              # Conditional assign: a quiet poll (None) must not wipe a
-              # stop reason an earlier check set (e.g. nonfinite_loss).
-              reason = membership.poll()
-              if reason is not None:
-                self.stop_reason = reason
-          if self.stop_reason is not None:
-            break
-          if ckpt_dir and ckpt_every and self.step % ckpt_every == 0:
-            self.save(ckpt_dir, writer=writer)
-            flight.note_checkpoint(ckpt_dir, self.step)
-          if self.step >= max_steps:
-            break
+                from ..ops.flash_attention import count_skippable_tiles
+                total, skipped = count_skippable_tiles(
+                    np.asarray(batch['segment_ids']))
+                tiles_total_c.add(total)
+                tiles_skipped_c.add(skipped)
+            if log_every and self.step % log_every == 0:
+              dt = time.perf_counter() - t_log
+              t_log = time.perf_counter()
+              print(f'step={self.step} loss={loss:.4f} '
+                    f'samples_seen={self.samples_seen} '
+                    f'({log_every * global_batch / max(dt, 1e-9):.1f} '
+                    'samples/s)')
+            if writer is not None:
+              # First-error-wins: a checkpoint that died in the background
+              # fails the run at the next step, not at the final flush.
+              writer.raise_pending()
+            if guard.requested:
+              self.stop_reason = 'preempted'
+            elif membership is not None:
+              now_m = time.monotonic()
+              # lddl: noqa[LDA003] membership poll cadence: the clock only
+              # rate-limits lease-store sweeps to one per heartbeat
+              # interval; a late poll delays noticing an already-recorded
+              # fleet event, it never changes any rank's verdict.
+              if now_m >= poll_at:
+                poll_at = now_m + membership.interval
+                w_step, w_t = rate_anchor
+                membership.publish_signals(
+                    {'steps_per_sec':
+                     (self.step - w_step) / max(now_m - w_t, 1e-9)})
+                rate_anchor = (self.step, now_m)
+                # Conditional assign: a quiet poll (None) must not wipe a
+                # stop reason an earlier check set (e.g. nonfinite_loss).
+                reason = membership.poll()
+                if reason is not None:
+                  self.stop_reason = reason
+            if (self.stop_reason is None and ckpt_dir and ckpt_every and
+                self.step % ckpt_every == 0):
+              self.save(ckpt_dir, writer=writer)
+              flight.note_checkpoint(ckpt_dir, self.step)
+      if stream is not None:
         stream.close()
-        if steps_this_epoch == 0 and self.stop_reason is None:
-          raise ValueError(
-              'loader yielded zero batches for a full epoch (dataset smaller '
-              'than one global batch?); refusing to spin — reduce '
-              '--batch-size or provide more data')
       # A capture armed near the end of the run may still be tracing; jax
       # allows one trace per process, so close it before returning.
       profiler.close()

@@ -53,3 +53,22 @@ def test_default_applies_to_accelerators_only(monkeypatch):
         compile_cache.DEFAULT_CACHE_DIR
   finally:
     jax.config.update('jax_compilation_cache_dir', before)
+
+
+def test_op_metadata_is_part_of_the_cache_key(monkeypatch, tmp_path):
+  """Scopes renamed over the same arithmetic must not come back from the
+  cache under their old names (the capture summary bills by them):
+  wherever the cache lives, the key covers the metadata."""
+  flag = 'jax_compilation_cache_include_metadata_in_key'
+  before = getattr(jax.config, flag)
+  try:
+    for placed in (str(tmp_path), None):
+      jax.config.update(flag, False)
+      if placed:
+        monkeypatch.setenv('JAX_COMPILATION_CACHE_DIR', placed)
+      else:
+        monkeypatch.delenv('JAX_COMPILATION_CACHE_DIR', raising=False)
+      compile_cache.use_compile_cache()
+      assert getattr(jax.config, flag) is True
+  finally:
+    jax.config.update(flag, before)

@@ -458,6 +458,63 @@ class TestStepProfiler:
     prof.on_step()
     assert prof.on_step() == str(tmp_path / 'capture0001')
 
+  def test_finished_capture_leaves_summary_and_file(self, tmp_path):
+    # Real jax.profiler on the CPU backend, through the singleton the
+    # phase helper looks at: the phases land in the trace with their step,
+    # the summary is kept and written beside the trace. (A CPU trace has
+    # no TPU device plane, so the summary's device list is empty.)
+    import jax
+    import jax.numpy as jnp
+
+    from lddl_tpu.telemetry import capture
+    from lddl_tpu.telemetry.trace import disable_trace, get_tracer
+    disable_trace()  # the annotations need no LDDL_TRACE
+    prof = profiling.get_step_profiler()
+    assert prof.last_summary is None
+    prof.arm(2, out_dir=str(tmp_path))
+    assert prof.on_step() is None  # starts the trace
+    phase = get_tracer().phase
+    done = None
+    for step in (5, 6):
+      with phase('train.step', step):
+        with phase('train.dispatch', step):
+          jax.jit(lambda x: x @ x)(jnp.ones((8, 8))).block_until_ready()
+        done = prof.on_step()
+    assert done == str(tmp_path / 'capture0000')
+    summary = prof.last_summary
+    assert summary['devices'] == []
+    assert summary['phases_seen'] == ['train.dispatch', 'train.step']
+    assert summary['seconds'] > 0 and summary['trace_bytes'] > 0
+    with open(os.path.join(done, 'summary.json')) as f:
+      assert json.load(f) == summary
+    # Stage one on the same file: the spans, their steps, one thread.
+    (line,) = capture.extract(summary['trace'])['host']
+    rows = {(name, step) for name, _, _, step in line['events']}
+    assert ('train.dispatch', 5) in rows and ('train.dispatch', 6) in rows
+    assert ('train.step', 5) in rows
+    assert 'capture summary' in capture.format_table(summary)
+
+  @pytest.mark.parametrize('leave', ['nothing', 'garbage', 'truncated'])
+  def test_missing_or_unreadable_trace_leaves_none(self, monkeypatch,
+                                                   tmp_path, caplog, leave):
+    _FakeJaxProfiler(monkeypatch)
+    prof = profiling.StepProfiler()
+    prof.last_summary = {'stale': True}
+    prof.arm(1, out_dir=str(tmp_path))
+    prof.on_step()
+    run_dir = tmp_path / 'capture0000' / 'plugins' / 'profile' / 'run'
+    if leave != 'nothing':
+      run_dir.mkdir(parents=True)
+      # A length-delimited field that claims more bytes than there are,
+      # or one whose length never ends.
+      (run_dir / 'host.xplane.pb').write_bytes(
+          b'\x0a\x7fnot a profile' if leave == 'garbage' else b'\x0a\xff')
+    with caplog.at_level('WARNING', logger='lddl_tpu'):
+      assert prof.on_step() == str(tmp_path / 'capture0000')  # no raise
+    assert prof.last_summary is None
+    assert not os.path.exists(tmp_path / 'capture0000' / 'summary.json')
+    assert 'no summary of the capture' in caplog.text
+
   def test_close_stops_inflight_trace(self, monkeypatch, tmp_path):
     fake = _FakeJaxProfiler(monkeypatch)
     prof = profiling.StepProfiler()

@@ -151,6 +151,98 @@ def _skewed_files(skew=3.7):
   return [(meta0, ev0), (meta1, ev1)]
 
 
+class _RunningCapture:
+  """A StepProfiler with a capture "running": the profiler calls faked,
+  so the state is real and no trace is written."""
+
+  def __init__(self, monkeypatch, tmp_path):
+    import jax
+
+    import lddl_tpu.telemetry.profiling as profiling
+    monkeypatch.setattr(jax.profiler, 'start_trace', lambda d: None)
+    monkeypatch.setattr(jax.profiler, 'stop_trace', lambda: None)
+    self.profiler = profiling.get_step_profiler()
+    self.profiler.arm(2, out_dir=str(tmp_path))
+    self.profiler.on_step()  # starts the capture
+    assert self.profiler._active_steps == 2
+
+
+class TestPhaseSinks:
+  """``tracer.phase``: one instrumentation site, two sinks (the ring
+  buffer under LDDL_TRACE, the profiler's trace while a capture runs)."""
+
+  def test_neither_sink_is_the_shared_noop(self):
+    import sys
+    disable_trace()
+    tracer = get_tracer()
+    assert tracer is NOOP_TRACER
+    span = tracer.phase('train.step', 7)
+    assert span is tracer.phase('train.dispatch') is tracer.span('x')
+    assert type(span).__slots__ == ()
+
+    def hot(n):
+      for step in range(n):
+        with tracer.phase('train.step', step):
+          with tracer.phase('train.dispatch', step):
+            pass
+
+    hot(100)
+    before = sys.getallocatedblocks()
+    hot(10_000)
+    delta = sys.getallocatedblocks() - before
+    assert abs(delta) < 20, f'no-op phase path allocated {delta} blocks'
+
+  def test_an_armed_profiler_that_has_not_started_is_still_off(
+      self, tmp_path):
+    import lddl_tpu.telemetry.profiling as profiling
+    disable_trace()
+    profiling.get_step_profiler().arm(3, out_dir=str(tmp_path))
+    assert get_tracer().phase('train.step', 1) is get_tracer().span('x')
+
+  @pytest.mark.parametrize('step', [None, 12])
+  def test_running_capture_gives_a_trace_annotation(self, monkeypatch,
+                                                    tmp_path, step):
+    import jax
+    disable_trace()
+    _RunningCapture(monkeypatch, tmp_path)
+    span = get_tracer().phase('train.loss_read', step)
+    assert isinstance(span, jax.profiler.TraceAnnotation)
+    with span:  # needs no LDDL_TRACE, records nothing in the ring
+      pass
+    assert get_tracer().event_dicts() == []
+
+  def test_capture_over_stops_the_annotations(self, monkeypatch, tmp_path):
+    disable_trace()
+    capture = _RunningCapture(monkeypatch, tmp_path)
+    capture.profiler.on_step()
+    capture.profiler.on_step()  # 2 of 2: the capture stops
+    assert get_tracer().phase('train.step', 3) is get_tracer().span('x')
+
+  @pytest.mark.parametrize('capturing', [False, True])
+  def test_ring_span_carries_its_parents_step(self, monkeypatch, tmp_path,
+                                              capturing):
+    t = enable_trace(max_events=100, flush_interval=1e9)
+    assert get_tracer() is t
+    if capturing:  # both sinks at once
+      _RunningCapture(monkeypatch, tmp_path)
+    with t.phase('train.step', 41):
+      with t.phase('train.dispatch', 41):
+        time.sleep(0.002)
+      with t.phase('loader.next'):
+        pass
+    by_name = {e['name']: e for e in t.event_dicts()}
+    assert sorted(by_name) == ['loader.next', 'train.dispatch', 'train.step']
+    assert by_name['train.step']['args'] == {'step': 41}
+    assert by_name['train.dispatch']['args'] == {'step': 41}
+    assert 'args' not in by_name['loader.next']
+    parent, child = by_name['train.step'], by_name['train.dispatch']
+    assert parent['ts'] <= child['ts']
+    assert child['ts'] + child['dur'] <= parent['ts'] + parent['dur']
+    assert child['dur'] >= 0.002
+    assert type(t.phase('x')).__name__ == (
+        '_BothSinks' if capturing else '_Span')
+
+
 class TestMergeAndClockAlignment:
 
   def test_offsets_recover_deliberate_skew(self):
