@@ -96,9 +96,15 @@ def prefetch_to_device(iterator, mesh=None, data_axis=None, seq_axis=None,
   in-flight transfer plus the batch being consumed — the same
   valid-until-the-next-pull lifetime the zero-copy slot views have on
   the host side. Keep a batch alive across pulls (or pass
-  ``donate=False``) only if you re-read it after stepping; the train
-  loop blocks on the step's output before pulling, so in-flight
-  executions are never affected (deletion waits on XLA usage holds).
+  ``donate=False``) only if you re-read it after stepping. The train
+  loop does *not* wait for step ``k`` before it pulls batch ``k+1``
+  (``TrainLoop.run`` keeps one step in flight), so the deletion routinely
+  lands while the step that reads the batch still runs, or is still
+  queued behind the one before it: ``Array.delete()`` only drops the
+  array's reference, and the runtime frees the memory once every
+  execution already enqueued on it has finished (its usage holds). The
+  loop rests on that; whatever an observer wants of a batch it takes
+  before the next pull.
   """
 
   def _put(item):
@@ -218,8 +224,9 @@ def prefetch_to_device(iterator, mesh=None, data_axis=None, seq_axis=None,
       if donate:
         # The consumer just asked for the next batch: the previous one's
         # device buffers are dead by contract (see docstring). Deletion
-        # defers to XLA usage holds, so a still-executing step that read
-        # this batch finishes before the memory is actually freed.
+        # defers to XLA usage holds, so the step that reads this batch,
+        # which TrainLoop.run has launched and not waited for, finishes
+        # before the memory is actually freed.
         _delete_device_batch(item)
         if tele.enabled:
           _track(item, -1)

@@ -58,6 +58,7 @@ PARENT_PHASE = 'train.step'
 # The TPU runtime's own host span around handing a program to the device
 # queue: no program starts on the device before it (see _device_clock_shift).
 ENQUEUE = 'DoEnqueueProgram'
+DISPATCH = 'train.dispatch'
 EPOCH_TURN = 'train.epoch_turn'
 UNATTRIBUTED = 'unattributed'
 
@@ -389,27 +390,30 @@ def _threads(host):
   return sorted(main, key=lambda r: r[1]), sorted(feed, key=lambda r: r[1])
 
 
-def _device_clock_shift(steps, enqueues):
+def _device_clock_shift(steps, enqueues, main):
   """Nanoseconds to add to the device plane's times to put them on the
   host planes' clock, or None when the trace has no enqueue span.
 
   The profiler lays the two clocks over each other itself, but in the
   traces of this repo's chip runs (PERF.md section 3) every step program
   "starts" 0.4-1.6 ms before the host runtime's ``DoEnqueueProgram`` for
-  it has begun, throughout a capture by the same amount. A program cannot run
-  before it is enqueued, and once enqueued an idle chip starts it within
-  microseconds, so the device's timeline is placed where the promptest
-  step program of the capture starts as its enqueue begins."""
+  it has begun, throughout a capture by the same amount. A program cannot
+  run before it is enqueued, and an idle chip starts one within
+  microseconds of its enqueue. ``TrainLoop.run`` keeps a step in flight, so
+  most programs of a capture were enqueued a whole step before they start
+  and the enqueue nearest to a program's start is the next step's; but
+  ``StepProfiler`` starts a trace only once the loop has drained
+  (:attr:`~.profiling.StepProfiler.at_edge`), so the capture's first step
+  program is launched into an idle chip. The device's timeline is placed
+  where that program starts as its own enqueue begins: the first enqueue
+  from the start of the capture's first ``train.dispatch`` on."""
   if not enqueues:
     return None
-  starts = sorted(enqueues)
-  shifts = []
-  for program_start, _ in steps:
-    i = bisect.bisect_left(starts, program_start)
-    nearest = min(starts[max(i - 1, 0):i + 1],
-                  key=lambda t: abs(t - program_start))
-    shifts.append(nearest - program_start)
-  return max(shifts)
+  dispatched = next((r[1] for r in main if r[0] == DISPATCH), None)
+  own = [t for t in enqueues if dispatched is None or t >= dispatched]
+  if not own:
+    return None
+  return min(own) - steps[0][0]
 
 
 def _summarize_device(device, main, feed, enqueues):
@@ -419,7 +423,7 @@ def _summarize_device(device, main, feed, enqueues):
                  if n.startswith(STEP_MODULE_PREFIX))
   if not steps:
     return None
-  shift = _device_clock_shift(steps, enqueues)
+  shift = _device_clock_shift(steps, enqueues, main)
   to_host = shift or 0
   step_starts = [s for s, _ in steps]
   by_class = dict.fromkeys(CLASSES, 0)
@@ -452,9 +456,9 @@ def _summarize_device(device, main, feed, enqueues):
   feed_starts = [r[1] for r in feed]
   gaps = []
   for (_, lo), (hi, _) in zip(steps, steps[1:]):
-    if hi <= lo:
-      continue
-    lo, hi = lo + to_host, hi + to_host  # on the host's clock
+    # Back-to-back programs (the next one was queued before this one
+    # ended) still give a gap record, of length nought.
+    lo, hi = lo + to_host, max(hi, lo) + to_host  # on the host's clock
     phases = collections.Counter()
     for name, over in _overlaps(leaves, leaf_starts, lo, hi):
       phases[name] += over
@@ -534,7 +538,7 @@ def format_table(summary):
         '  device clock: no enqueue span of the runtime in the trace, times '
         'as the profiler gives them' if shift is None else
         f'  device clock: moved by {shift / 1e6:+.3f} ms onto the host\'s '
-        '(no step program starts before its enqueue)')
+        '(the first step program starts as its enqueue begins)')
     gaps = d['gaps']
     if gaps:
       total = sum(g['ns'] for g in gaps)

@@ -8,7 +8,12 @@ Two entry points over one code path:
   - :class:`StepProfiler` — the live-run half: ``GET /profile?steps=N``
     on the monitor endpoint (or ``lddl-monitor --profile N``) *arms* the
     profiler, and the train loop's per-step ``on_step()`` hook starts a
-    trace at the next step boundary and stops it N steps later. Traces
+    trace at the next step boundary and stops it N steps later. The loop
+    keeps one step in flight, so it asks :attr:`StepProfiler.at_edge`
+    before each launch and drains first where a capture is about to start
+    or stop: a capture holds N whole step programs, the first of them
+    launched into an idle chip (:mod:`.capture` places the device's clock
+    by it). Traces
     land under ``LDDL_TELEMETRY_DIR/profiles/`` (same layout the bench
     context manager uses), numbered per capture, so a long pretrain can
     be profiled without a restart and costs nothing while unarmed: the
@@ -83,10 +88,23 @@ class StepProfiler:
       self._armed_steps = steps
       return self._out_dir
 
-  def on_step(self):
-    """Call once per train step, at the step boundary. Returns the trace
-    directory when this call *finished* a capture, else None."""
+  @property
+  def at_edge(self):
+    """Whether the next :meth:`on_step` starts or stops a trace. The
+    train loop reads this before it launches a step and, if so, first
+    waits for the step in flight. Unarmed: two attribute reads."""
+    return (self._active_steps == 1 or
+            bool(self._armed_steps and not self._active_steps))
+
+  def on_step(self, in_flight=False):
+    """Call once per observed train step. Returns the trace directory
+    when this call *finished* a capture, else None. ``in_flight`` says
+    that a later step already runs on the device: a trace neither starts
+    nor stops then (an ``arm()`` that raced the launch waits one step for
+    the loop's drain), so that a capture holds whole step programs."""
     if not self._armed_steps and not self._active_steps:
+      return None
+    if in_flight and self.at_edge:
       return None
     finished = None
     with self._lock:

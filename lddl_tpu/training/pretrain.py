@@ -459,15 +459,30 @@ class TrainLoop:
           prefetch=2, membership=None, async_ckpt=None):
     """Train until ``max_steps`` (global); returns per-step loss list.
 
+    One step is always in flight. An iteration pulls batch k, launches
+    step k (the call returns at once; the step's inputs are the previous
+    step's outputs, still on the device) and only then reads step k-1's
+    loss and runs step k-1's observers (non-finite check, sentinel,
+    flight recorder, profiler hook, telemetry, log line, writer, guard,
+    membership), so the device works on step k while the host looks at
+    step k-1. The loop waits for the step in flight (a *drain*: its loss
+    read, its observers) before a checkpoint, before the profiler starts
+    or stops a capture, and before it returns: every launched step has
+    its loss in the returned list, and ``step``, ``samples_seen`` and the
+    state a checkpoint holds always describe the same step.
+
     Preemption-tolerant: a SIGTERM (or ``LDDL_PREEMPTION_FILE`` notice)
-    stops the loop at the next step boundary behind one final
-    synchronous checkpoint; a :class:`~lddl_tpu.training.elastic.
-    RankMembership` passed as ``membership`` is polled at its heartbeat
-    cadence and any fleet event (dead peer, shed verdict) likewise
-    stops the loop checkpointed, with :attr:`stop_reason` telling the
-    supervisor why. ``async_ckpt`` overrides ``LDDL_ASYNC_CKPT``:
-    in-loop checkpoints ride the background writer, overlapping orbax
-    IO with compute.
+    is looked for before each launch, so it launches nothing more; the
+    step in flight is drained and the loop stops behind one final
+    synchronous checkpoint. What an observer finds (a non-finite loss, a
+    membership event of a :class:`~lddl_tpu.training.elastic.
+    RankMembership` passed as ``membership``, polled at its heartbeat
+    cadence) **stops the loop at most one step after the step that caused
+    it**: the step already in flight is drained and counted, then the
+    loop stops behind the same checkpoint path, with :attr:`stop_reason`
+    telling the supervisor why. ``async_ckpt`` overrides
+    ``LDDL_ASYNC_CKPT``: in-loop checkpoints ride the background writer,
+    overlapping orbax IO with compute.
     """
     import jax
 
@@ -484,8 +499,9 @@ class TrainLoop:
 
     # Live metrics endpoint (LDDL_MONITOR): no-op singleton when unset.
     maybe_start_monitor(rank=max(jax.process_index(), 0))
-    # GET /profile?steps=N arms this; unarmed on_step() is two attribute
-    # reads, so the hook costs nothing on unwatched runs.
+    # GET /profile?steps=N arms this; unarmed, at_edge and on_step() are
+    # two attribute reads each, so the hook costs nothing on unwatched
+    # runs.
     profiler = get_step_profiler()
     # Streaming anomaly sentinels + black-box recorder (LDDL_SENTINEL):
     # both resolve to shared no-op singletons when the gate is off.
@@ -507,6 +523,7 @@ class TrainLoop:
     data_wait_h = tele.histogram('train.data_wait_seconds')
     compute_h = tele.histogram('train.compute_seconds')
     step_h = tele.histogram('train.step_seconds')
+    loss_read_h = tele.histogram('train.loss_read_seconds')
     epoch_turn_h = tele.histogram('train.epoch_turn_seconds')
     steps_c = tele.counter('train.steps')
     samples_c = tele.counter('train.samples')
@@ -528,6 +545,11 @@ class TrainLoop:
     poll_at = time.monotonic()
     rate_anchor = (self.step, time.monotonic())
     losses = []
+    t_log = time.perf_counter()
+    # The number of the next step to launch: self.step counts observed
+    # steps and trails it by one while a step is in flight.
+    next_step = self.step
+    in_flight = None
 
     def open_stream():
       # The flight recorder tees the *host* iterator (device arrays
@@ -535,31 +557,163 @@ class TrainLoop:
       # feeds, so ring entries carry their ledger collate coordinate.
       return prefetch_to_device(
           flight.wrap_host_stream(iter(self.loader), self.loader,
-                                  ordinal0=self.step),
+                                  ordinal0=next_step),
           mesh=self.mesh, size=prefetch)
+
+    def read_loss(launched):
+      """Blocks until the device has finished ``launched``: the device
+      sync, with the next step (if one was launched) already queued."""
+      t_read = time.perf_counter()
+      with phase('train.loss_read', launched.step):
+        loss = float(launched.metrics['loss'])
+      loss_read_h.observe(time.perf_counter() - t_read)
+      return loss
+
+    def observe(launched, loss, drained=False):
+      """``launched``'s observers, given its loss; ``drained`` says that
+      no later step is on the device."""
+      nonlocal t_log, poll_at, rate_anchor
+      step_no = launched.step
+      metrics = launched.metrics
+      with phase('train.after_step', step_no):
+        # The loss read above already paid the device sync; this one
+        # is a host copy of an already-materialized scalar.
+        gn = metrics.get('grad_norm')
+        grad_norm = float(gn) if gn is not None else None
+        losses.append(loss)
+        self._last_loss = loss
+        self.step += 1
+        self.samples_seen += global_batch
+        if not math.isfinite(loss) and nonfinite_stop:
+          # Stop behind the trailing emergency checkpoint (the
+          # preemption stop path) instead of training on garbage; the
+          # step in flight, if any, is drained first.
+          # LDDL_NONFINITE=ignore opts out.
+          self.stop_reason = 'nonfinite_loss'
+        trigger = sentinel.observe_step(step_no, loss=loss,
+                                        grad_norm=grad_norm,
+                                        data_wait=launched.data_wait)
+        flight.record_step(step_no, loss=loss, grad_norm=grad_norm,
+                           data_wait=launched.data_wait)
+        if trigger is not None:
+          incident = flight.capture(trigger)
+          if incident:
+            print(f'sentinel: {trigger["detector"]} fired at step '
+                  f'{step_no} — incident captured to {incident}')
+          else:
+            print(f'sentinel: {trigger["detector"]} fired at step '
+                  f'{step_no} ({trigger["reason"]})')
+        finished_trace = profiler.on_step(in_flight=not drained)
+        if finished_trace:
+          print(f'profiler: wrote trace for step {self.step} window to '
+                f'{finished_trace}')
+          if profiler.last_summary is not None:
+            from ..telemetry.capture import format_table
+            print(format_table(profiler.last_summary))
+        if tracer.enabled or tele.enabled:
+          # The step's own interval, pull to pull (in a drain with no
+          # pull behind it: pull to the loss in hand).
+          step_seconds = max(launched.t_end - launched.t_wait, 1e-9)
+          samples_per_sec = self.loader.batch_size / step_seconds
+          tracer.counter('train.samples_per_sec', samples_per_sec)
+        if tele.enabled:
+          compute_h.observe(launched.t_end - launched.t_step)
+          step_h.observe(launched.t_end - launched.t_wait)
+          steps_c.add(1)
+          samples_c.add(self.loader.batch_size)
+          if grad_norm is not None:
+            grad_norm_g.set(grad_norm)
+          samples_per_sec_g.set(samples_per_sec)
+          if launched.flops:
+            tele.gauge('train.mfu').set(
+                launched.flops / (step_seconds * peak_total))
+          if launched.tiles is not None:
+            tiles_total_c.add(launched.tiles[0])
+            tiles_skipped_c.add(launched.tiles[1])
+        if log_every and self.step % log_every == 0:
+          dt = time.perf_counter() - t_log
+          t_log = time.perf_counter()
+          print(f'step={self.step} loss={loss:.4f} '
+                f'samples_seen={self.samples_seen} '
+                f'({log_every * global_batch / max(dt, 1e-9):.1f} '
+                'samples/s)')
+        if writer is not None:
+          # First-error-wins: a checkpoint that died in the background
+          # fails the run at the next step, not at the final flush.
+          writer.raise_pending()
+        if guard.requested:
+          self.stop_reason = 'preempted'
+        elif membership is not None:
+          now_m = time.monotonic()
+          # lddl: noqa[LDA003] membership poll cadence: the clock only
+          # rate-limits lease-store sweeps to one per heartbeat
+          # interval; a late poll delays noticing an already-recorded
+          # fleet event, it never changes any rank's verdict.
+          if now_m >= poll_at:
+            poll_at = now_m + membership.interval
+            w_step, w_t = rate_anchor
+            membership.publish_signals(
+                {'steps_per_sec':
+                 (self.step - w_step) / max(now_m - w_t, 1e-9)})
+            rate_anchor = (self.step, now_m)
+            # Conditional assign: a quiet poll (None) must not wipe a
+            # stop reason an earlier check set (e.g. nonfinite_loss).
+            reason = membership.poll()
+            if reason is not None:
+              self.stop_reason = reason
+
+    def drain():
+      """Wait for the step in flight and observe it: afterwards the
+      device is idle and ``self.step`` describes ``self.params``, so this
+      is where the in-loop checkpoint is written."""
+      nonlocal in_flight
+      launched, in_flight = in_flight, None
+      loss = read_loss(launched)
+      if launched.t_end is None:
+        launched.t_end = time.perf_counter()
+      observe(launched, loss, drained=True)
+      if (self.stop_reason is None and ckpt_dir and ckpt_every and
+          self.step % ckpt_every == 0):
+        self.save(ckpt_dir, writer=writer)
+        flight.note_checkpoint(ckpt_dir, self.step)
 
     stream = None
     try:
-      if self.step < max_steps:
+      if next_step < max_steps:
         stream = open_stream()
       steps_this_epoch = 0
-      t_log = time.perf_counter()
       t_turn = None  # set while an epoch turn waits for its first batch
-      while self.step < max_steps and self.stop_reason is None:
-        step_no = self.step
-        with phase('train.step', step_no):
+      while next_step < max_steps and self.stop_reason is None:
+        if guard.requested:
+          # A preemption launches nothing more.
+          self.stop_reason = 'preempted'
+          break
+        if in_flight is not None and (
+            profiler.at_edge or
+            (ckpt_dir and ckpt_every and next_step % ckpt_every == 0)):
+          # A checkpoint boundary and the two ends of a profiler capture
+          # are syncs: the state to save is the drained step's, and a
+          # capture holds whole step programs.
+          drain()
+          continue
+        with phase('train.step', next_step):
           # Pull the batch explicitly so the stall waiting on the input
           # pipeline (data wait) is timed separately from the step itself:
           # the split is the report's loader-vs-compute bottleneck signal.
-          # The pull also frees the previous batch's device buffers.
+          # The pull also deletes the previous batch's device buffers,
+          # while the step that reads them may still run (loader/device.py:
+          # the deletion waits for it).
           t_wait = time.perf_counter()
-          with phase('train.data_wait', step_no):
+          if in_flight is not None and in_flight.t_end is None:
+            in_flight.t_end = t_wait
+          with phase('train.data_wait', next_step):
             batch = next(stream, None)
           if batch is None:
             # The epoch is exhausted: close its feed (joins the prefetch
-            # thread) and open the next one's.
+            # thread) and open the next one's, with the epoch's last step
+            # still in flight.
             t_turn = time.perf_counter()
-            with phase('train.epoch_turn', step_no):
+            with phase('train.epoch_turn', next_step):
               stream.close()
               if steps_this_epoch == 0:
                 raise ValueError(
@@ -580,127 +734,29 @@ class TrainLoop:
           # rank dying mid-training, a 'term' models the preemption notice.
           faults.inject('train.step', rank=self.dp_rank)
           steps_this_epoch += 1
-          # train.compute: the ring buffer's parent of dispatch + the
-          # loss read (the Perfetto merge's compute lane).
+          # train.compute: the ring buffer's parent of this step's
+          # dispatch + the previous step's loss read (the Perfetto merge's
+          # compute lane).
           with tracer.span('train.compute',
-                           {'step': step_no} if tracer.enabled else None):
-            with phase('train.dispatch', step_no):
+                           {'step': next_step} if tracer.enabled else None):
+            with phase('train.dispatch', next_step):
               self.params, self.opt_state, metrics = self.step_fn(
                   self.params, self.opt_state, self.rng, batch)
-            with phase('train.loss_read', step_no):
-              # float() blocks until the device finishes the step: the
-              # device sync.
-              loss = float(metrics['loss'])
-          with phase('train.after_step', step_no):
-            # The loss read above already paid the device sync; this one
-            # is a host copy of an already-materialized scalar.
-            gn = metrics.get('grad_norm')
-            grad_norm = float(gn) if gn is not None else None
-            losses.append(loss)
-            self._last_loss = loss
-            self.step += 1
-            self.samples_seen += global_batch
-            if not math.isfinite(loss) and nonfinite_stop:
-              # Stop at the step boundary behind the trailing emergency
-              # checkpoint (the preemption stop path) instead of training
-              # on garbage. LDDL_NONFINITE=ignore opts out.
-              self.stop_reason = 'nonfinite_loss'
-            trigger = sentinel.observe_step(step_no, loss=loss,
-                                            grad_norm=grad_norm,
-                                            data_wait=data_wait)
-            flight.record_step(step_no, loss=loss, grad_norm=grad_norm,
-                               data_wait=data_wait)
-            if trigger is not None:
-              incident = flight.capture(trigger)
-              if incident:
-                print(f'sentinel: {trigger["detector"]} fired at step '
-                      f'{step_no} — incident captured to {incident}')
-              else:
-                print(f'sentinel: {trigger["detector"]} fired at step '
-                      f'{step_no} ({trigger["reason"]})')
-            finished_trace = profiler.on_step()
-            if finished_trace:
-              print(f'profiler: wrote trace for step {self.step} window to '
-                    f'{finished_trace}')
-              if profiler.last_summary is not None:
-                from ..telemetry.capture import format_table
-                print(format_table(profiler.last_summary))
-            if tracer.enabled or tele.enabled:
-              now = time.perf_counter()
-              samples_per_sec = (self.loader.batch_size /
-                                 max(now - t_wait, 1e-9))
-              tracer.counter('train.samples_per_sec', samples_per_sec)
+            launched = _Launched(next_step, metrics, t_wait, t_step,
+                                 data_wait)
             if tele.enabled:
-              compute_h.observe(now - t_step)
-              step_h.observe(now - t_wait)
-              steps_c.add(1)
-              samples_c.add(self.loader.batch_size)
-              if grad_norm is not None:
-                grad_norm_g.set(grad_norm)
-              samples_per_sec_g.set(samples_per_sec)
-              if peak_total:
-                # Prefer XLA's own cost model (captured at compile time by
-                # the step cache) over the analytic estimate: the measured
-                # numerator reflects fusion, remat, and the real
-                # partitioned program, so MFU stops drifting from what the
-                # chip ran.
-                measured = getattr(self.step_fn, 'last_costs', None)
-                if measured is not None:
-                  numerator = measured[0]
-                elif self.flops_fn is not None:
-                  b, s = batch['input_ids'].shape
-                  numerator = self.flops_fn(b, s)
-                else:
-                  numerator = None
-                if numerator:
-                  tele.gauge('train.mfu').set(
-                      numerator / (max(now - t_wait, 1e-9) * peak_total))
-              if 'segment_ids' in batch:
-                # Host-side mirror of the kernel's tile-skip rule: the
-                # goodput signal for how much attention work block-diagonal
-                # packing actually removed this step.
-                import numpy as np
-
-                from ..ops.flash_attention import count_skippable_tiles
-                total, skipped = count_skippable_tiles(
-                    np.asarray(batch['segment_ids']))
-                tiles_total_c.add(total)
-                tiles_skipped_c.add(skipped)
-            if log_every and self.step % log_every == 0:
-              dt = time.perf_counter() - t_log
-              t_log = time.perf_counter()
-              print(f'step={self.step} loss={loss:.4f} '
-                    f'samples_seen={self.samples_seen} '
-                    f'({log_every * global_batch / max(dt, 1e-9):.1f} '
-                    'samples/s)')
-            if writer is not None:
-              # First-error-wins: a checkpoint that died in the background
-              # fails the run at the next step, not at the final flush.
-              writer.raise_pending()
-            if guard.requested:
-              self.stop_reason = 'preempted'
-            elif membership is not None:
-              now_m = time.monotonic()
-              # lddl: noqa[LDA003] membership poll cadence: the clock only
-              # rate-limits lease-store sweeps to one per heartbeat
-              # interval; a late poll delays noticing an already-recorded
-              # fleet event, it never changes any rank's verdict.
-              if now_m >= poll_at:
-                poll_at = now_m + membership.interval
-                w_step, w_t = rate_anchor
-                membership.publish_signals(
-                    {'steps_per_sec':
-                     (self.step - w_step) / max(now_m - w_t, 1e-9)})
-                rate_anchor = (self.step, now_m)
-                # Conditional assign: a quiet poll (None) must not wipe a
-                # stop reason an earlier check set (e.g. nonfinite_loss).
-                reason = membership.poll()
-                if reason is not None:
-                  self.stop_reason = reason
-            if (self.stop_reason is None and ckpt_dir and ckpt_every and
-                self.step % ckpt_every == 0):
-              self.save(ckpt_dir, writer=writer)
-              flight.note_checkpoint(ckpt_dir, self.step)
+              # What the observers report of this step's batch is taken
+              # now: the next pull deletes the batch.
+              launched.note_batch(batch, self.step_fn, self.flops_fn,
+                                  peak_total)
+            next_step += 1
+            previous, in_flight = in_flight, launched
+            if previous is not None:
+              loss = read_loss(previous)
+          if previous is not None:
+            observe(previous, loss)
+      if in_flight is not None:
+        drain()
       if stream is not None:
         stream.close()
       # A capture armed near the end of the run may still be tracing; jax
@@ -727,6 +783,40 @@ class TrainLoop:
       print(f'stopping early: {self.stop_reason} '
             f'(step={self.step} samples_seen={self.samples_seen})')
     return losses
+
+
+@dataclasses.dataclass
+class _Launched:
+  """A step on the device, as its observers need it one step later."""
+
+  step: int
+  metrics: object    # the step's outputs: device scalars, not yet read
+  t_wait: float      # perf_counter at its batch pull
+  t_step: float      # perf_counter at its launch
+  data_wait: float
+  t_end: object = None   # at the first pull after its launch: pull to pull
+  flops: object = None   # the train.mfu numerator, or None
+  tiles: object = None   # (total, skipped) attention tiles of a packed batch
+
+  def note_batch(self, batch, step_fn, flops_fn, peak_total):
+    if peak_total:
+      # Prefer XLA's own cost model (captured at compile time by the
+      # step cache) over the analytic estimate: the measured numerator
+      # reflects fusion, remat, and the real partitioned program, so MFU
+      # stops drifting from what the chip ran.
+      measured = getattr(step_fn, 'last_costs', None)
+      if measured is not None:
+        self.flops = measured[0]
+      elif flops_fn is not None:
+        self.flops = flops_fn(*batch['input_ids'].shape)
+    if 'segment_ids' in batch:
+      # Host-side mirror of the kernel's tile-skip rule: the goodput
+      # signal for how much attention work block-diagonal packing
+      # actually removed this step.
+      import numpy as np
+
+      from ..ops.flash_attention import count_skippable_tiles
+      self.tiles = count_skippable_tiles(np.asarray(batch['segment_ids']))
 
 
 def _peak_flops_total():

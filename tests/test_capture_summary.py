@@ -111,26 +111,104 @@ def test_hand_made_gaps_by_phase():
 
 
 def test_device_clock_is_moved_onto_the_hosts():
-  # The runtime's enqueue spans begin 2 before, 5 after and 1 before the
-  # three step programs "start": no program runs before it is enqueued, so
-  # the device's times move by +5 and the gaps with them.
+  # The capture's first step program is launched into an idle chip
+  # (StepProfiler starts a trace only once the loop has drained), so it
+  # starts as its own enqueue begins: the first enqueue from the start of
+  # the first train.dispatch (95) on, at 98, against a program that
+  # "starts" at 100. The device's times move by -2 and the gaps with them;
+  # the later enqueues, 5 after and 1 before their programs, decide
+  # nothing.
   events = hand_made()
   events['host'].append({'line': 'tfrt-non-blocking-queue', 'events': [
+      ['DoEnqueueProgram', 60, 3, None],  # before the loop's first dispatch
       ['DoEnqueueProgram', 98, 3, None], ['DoEnqueueProgram', 305, 3, None],
       ['DoEnqueueProgram', 699, 3, None]]})
   (d,) = capture.summarize(events)['devices']
-  assert d['device_clock_shift_ns'] == 5
+  assert d['device_clock_shift_ns'] == -2
   first, second = d['gaps']
   assert first['ns'] == 100 and second['ns'] == 300
-  assert first['phases'] == {       # the gap is 205..305 on the host's clock
-      'train.loss_read': 5, 'train.after_step': 30, 'train.data_wait': 20,
-      'train.dispatch': 20, 'unattributed': 25}
-  assert second['phases'] == {      # 405..705
-      'train.loss_read': 5, 'train.after_step': 20, 'train.data_wait': 70,
-      'train.epoch_turn': 150, 'train.dispatch': 45, 'unattributed': 10}
+  assert first['phases'] == {       # the gap is 198..298 on the host's clock
+      'train.loss_read': 12, 'train.after_step': 30, 'train.data_wait': 20,
+      'train.dispatch': 13, 'unattributed': 25}
+  assert second['phases'] == {      # 398..698
+      'train.loss_read': 12, 'train.after_step': 20, 'train.data_wait': 70,
+      'train.epoch_turn': 150, 'train.dispatch': 38, 'unattributed': 10}
   assert d['busy_ns'] == 275        # the device's own sums do not move
-  assert 'moved by +0.000 ms' in capture.format_table(
+  assert 'moved by -0.000 ms' in capture.format_table(
       dict(capture.summarize(events), seconds=0.0, trace_bytes=0))
+
+
+def one_step_in_flight(lead=20):
+  """A capture of ``TrainLoop.run`` as it is since PR 26: four step
+  programs (steps 11-14), the first launched into an idle chip, each of
+  the others queued before the one before it ends. Host times below; the
+  device's raw clock reads ``lead`` less."""
+  programs = [(1050, 1150), (1150, 1250), (1250, 1350), (1355, 1450)]
+  modules = [['jit_step(1)', lo - lead, hi - lo] for lo, hi in programs]
+  names = [['a', 'convolution fusion', ATT]]
+  ops = [[0, lo - lead, hi - lo] for lo, hi in programs]
+  main = {'line': 'python', 'events': [
+      ['train.step', 1030, 31, 11],
+      ['train.data_wait', 1030, 5, 11],
+      ['train.dispatch', 1040, 20, 11],     # nothing in flight: no loss read
+      ['train.step', 1062, 98, 12],
+      ['train.data_wait', 1062, 3, 12],
+      ['train.dispatch', 1066, 14, 12],
+      ['train.loss_read', 1082, 70, 11],    # program 11 ends at 1150
+      ['train.after_step', 1153, 5, 11],
+      ['train.step', 1160, 102, 13],
+      ['train.data_wait', 1160, 3, 13],
+      ['train.dispatch', 1164, 11, 13],
+      ['train.loss_read', 1176, 77, 12],    # program 12 ends at 1250
+      ['train.after_step', 1254, 6, 12],
+      ['train.step', 1262, 100, 14],
+      ['train.data_wait', 1262, 3, 14],
+      ['train.dispatch', 1266, 14, 14],
+      ['train.loss_read', 1282, 70, 13],    # program 13 ends at 1350
+      ['train.after_step', 1353, 7, 13],
+  ]}
+  queue = {'line': 'tfrt-non-blocking-queue', 'events': [
+      ['DoEnqueueProgram', t, 2, None] for t in (1050, 1070, 1168, 1270)]}
+  return {'devices': [{'plane': '/device:TPU:0', 'names': names, 'ops': ops,
+                       'modules': modules}], 'host': [main, queue]}
+
+
+@pytest.mark.parametrize('lead', [20, 0, -7, 1500])
+def test_clock_shift_with_a_step_in_flight_is_the_anchored_one(lead):
+  # With lead 20, the enqueue nearest to the third program's raw start
+  # (1230) is the *next* step's (1270): the old rule, the largest lead
+  # over the nearest enqueue, read 40. The anchor reads the lead itself.
+  (d,) = capture.summarize(one_step_in_flight(lead))['devices']
+  assert d['device_clock_shift_ns'] == lead
+  assert d['steps'] == 4 and d['busy_ns'] == 395
+
+
+def test_back_to_back_programs_still_give_one_gap_each():
+  (d,) = capture.summarize(one_step_in_flight())['devices']
+  gaps = d['gaps']
+  assert [g['ns'] for g in gaps] == [0, 0, 5]
+  assert [g['step'] for g in gaps] == [12, 13, 14]
+  assert gaps[0]['phases'] == gaps[1]['phases'] == {'unattributed': 0}
+  # 1350..1355 on the host's clock: the wake-up from the loss read, then
+  # the observers of step 13.
+  assert gaps[2]['phases'] == {'train.loss_read': 2, 'train.after_step': 2,
+                               'unattributed': 1}
+  for g in gaps:
+    assert sum(g['phases'].values()) == g['ns']
+    assert not g['epoch_turn']
+  assert [g['first_step'] for g in gaps] == [False, False, False]
+  table = capture.format_table(
+      dict(capture.summarize(one_step_in_flight()), seconds=0.0,
+           trace_bytes=0))
+  assert 'gaps between step programs: 3, mean 0.000 ms' in table
+
+
+def test_programs_that_overlap_by_rounding_give_a_gap_of_nought():
+  events = one_step_in_flight()
+  events['devices'][0]['modules'][1][1] -= 1  # starts (and ends) 1 ns early
+  (d,) = capture.summarize(events)['devices']
+  assert [g['ns'] for g in d['gaps']] == [0, 1, 5]
+  assert all(sum(g['phases'].values()) == g['ns'] for g in d['gaps'])
 
 
 def test_no_step_program_or_no_phases():
@@ -168,7 +246,9 @@ def test_recorded_steps_totals(recorded, recorded_device):
   assert [g['ns'] for g in d['gaps']] == want['gap_ns']
   assert [g['step'] for g in d['gaps']] == want['gap_steps']
   # Every step program of the capture "started" ~1.5 ms before the host
-  # had enqueued it: the device's clock is moved onto the host's.
+  # had enqueued it: the device's clock is moved onto the host's, by the
+  # lead of the first of the three (recorded under the serial loop, where
+  # every program was launched into an idle chip).
   assert d['device_clock_shift_ns'] == want['device_clock_shift_ns']
   assert 1_000_000 < d['device_clock_shift_ns'] < 2_000_000
 
