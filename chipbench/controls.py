@@ -10,7 +10,8 @@ follow the same steps with the plain reference. That gives the *lower*
 reading of every number compared (the program against the reference). On
 the first ``--control-seeds`` seeds it also puts in the program's place
 
-  - the control: the reference computed in fp8 (``precision='fp8'``),
+  - the control: the reference computed in the family's
+    ``CONTROL_PRECISION`` (BERT's: fp8; the side is named after it),
   - the fault "half of the batch left out, the mean taken over the rest"
     (the reference on the first half of the rows),
 
@@ -91,9 +92,9 @@ def main(argv=None):
                                                     'controls'))
   parser.add_argument('--rejudge')
   args = parser.parse_args(argv)
-  from chipbench import adapter, compare, reference, run
-  stream = adapter.DROPOUT_STREAM
+  from chipbench import compare, run
   cell = run.find_cell(args.workload)
+  family = cell['family']
   if args.rejudge:
     rows = run.load_json(args.rejudge)
     for row in rows:
@@ -101,7 +102,7 @@ def main(argv=None):
       print(json.dumps({k: row[k] for k in ('seed', 'side', 'verdict')}))
     return summary(rows)
   run.require_device(cell)
-  shards = run.prepare_data(cell['traffic_data'])
+  shards = run.prepare_data(cell['traffic_data'], family.VOCAB_FILE)
   train = cell['traffic_data']['train']
   config = cell['config_data']
   rows = []
@@ -123,18 +124,18 @@ def main(argv=None):
     t0 = time.perf_counter()
     program, batches = program_side(run, cell, shards, seed)
     t1 = time.perf_counter()
-    ref = reference.follow(config, train, seed, batches, stream=stream)
+    ref = family.follow(config, train, seed, batches)
     emit(seed, 'program', compare.numbers(program, ref), t1 - t0)
     run.say(f'seed {seed}: reference in {time.perf_counter() - t1:.1f}s')
     if n < args.control_seeds:
       t2 = time.perf_counter()
-      control = reference.follow(config, train, seed, batches,
-                                 precision='fp8', stream=stream)
-      emit(seed, 'control_fp8', compare.numbers(control, ref),
-           time.perf_counter() - t2)
+      control = family.follow(config, train, seed, batches,
+                              precision=family.CONTROL_PRECISION)
+      emit(seed, f'control_{family.CONTROL_PRECISION}',
+           compare.numbers(control, ref), time.perf_counter() - t2)
       t3 = time.perf_counter()
-      half = reference.follow(config, train, seed, batches, stream=stream,
-                              keep=slice(0, train['batch_size'] // 2))
+      half = family.follow(config, train, seed, batches,
+                           keep=slice(0, train['batch_size'] // 2))
       emit(seed, 'fault_half_batch', compare.numbers(half, ref),
            time.perf_counter() - t3)
       unchanged = dict(ref, change_norms={k: 0.0

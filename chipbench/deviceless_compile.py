@@ -26,16 +26,13 @@ def main(argv=None):
                       help='bin length to compile (default: the longest)')
   args = parser.parse_args(argv)
   import jax
-  import jax.numpy as jnp
   import numpy as np
-  import optax
   from jax.experimental import topologies
   from jax.sharding import NamedSharding
 
   from chipbench import run
-  from lddl_tpu.models import BertForPretraining
   from lddl_tpu.ops import flash_attention
-  from lddl_tpu.parallel import make_mesh, make_train_step
+  from lddl_tpu.parallel import make_mesh
   from lddl_tpu.parallel.mesh import canonical_batch_spec
   from lddl_tpu.parallel.train import state_shardings
 
@@ -48,16 +45,8 @@ def main(argv=None):
   topo = topologies.get_topology_desc(platform='tpu', topology_name='v5e:2x2')
   mesh = make_mesh(**train['mesh'],
                    devices=np.asarray(topo.devices[:cell['chips']]))
-  cfg = run.bert_config(cell, train)
-  model = BertForPretraining(cfg, mesh=mesh)
-  tx = optax.adamw(train['learning_rate'],
-                   weight_decay=train['weight_decay'])
+  step, params, opt_state = cell['family'].abstract_step(cell, mesh)
   b, s = train['batch_size'], args.seq or train['max_seq_length']
-  dummy = jnp.zeros((2, 128), jnp.int32)
-  params = jax.eval_shape(
-      lambda: model.init(jax.random.key(0), dummy, dummy,
-                         jnp.ones_like(dummy))['params'])
-  opt_state = jax.eval_shape(tx.init, params)
   p_sh, o_sh = state_shardings(mesh, params, opt_state)
 
   def with_sharding(tree, shardings):
@@ -68,10 +57,8 @@ def main(argv=None):
   batch = {k: jax.ShapeDtypeStruct(
       v.shape, v.dtype,
       sharding=NamedSharding(mesh, canonical_batch_spec(mesh, v.shape)))
-           for k, v in run.fake_batch(b, s, train['block_diagonal']).items()}
+           for k, v in cell['family'].fake_batch(train, s).items()}
   key = jax.eval_shape(lambda: jax.random.key(0))
-  step = make_train_step(model, tx, mesh,
-                         max_predictions=train['max_predictions'])
   t0 = time.perf_counter()
   compiled = step.lower(with_sharding(params, p_sh),
                         with_sharding(opt_state, o_sh), key, batch).compile()

@@ -3,8 +3,9 @@
     python3 chipbench/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
 The timed window is one uninterrupted call of ``TrainLoop.run``, built as
-``lddl_tpu.training.pretrain.main`` builds it (compile cache, tokenizer,
-``BertConfig``, mesh, ``TrainLoop.build``), with the real loader,
+``lddl_tpu.training.pretrain.main`` builds it (compile cache, mesh, then
+tokenizer, model configuration and ``TrainLoop.build`` by the model's
+family), with the real loader,
 ``prefetch_to_device`` and the ``CompiledStepCache``. The benchmark adds
 two taps of its own and nothing else: one around the host loader (what
 each batch held) and one around ``loop.step_fn`` (when each step was
@@ -15,7 +16,9 @@ loop's own stop path (the preemption notice).
 Everything that belongs to one cell is data: the configuration file, the
 traffic file, the limits file and one reader per per-layer metric, all
 found by the names in ``BENCHMARK.json`` (or, for rehearsal cells that
-are not part of the benchmark, in ``chipbench/rehearsal/``).
+are not part of the benchmark, in ``chipbench/rehearsal/``). Everything
+that knows a model is its family's (``chipbench/families/``), found by
+the configuration file's key ``family``.
 
 The last line of standard output is the result. Exit codes: 0 a result
 was printed; 2 bad arguments or files; 3 no TPU, or fewer chips than the
@@ -46,10 +49,8 @@ REPO = os.path.dirname(HERE)
 if REPO not in sys.path:
   sys.path.insert(0, REPO)
 
-VOCAB = os.path.join(REPO, 'benchmarks', 'assets', 'bench_vocab_30522.txt')
 WORK = os.path.join(REPO, '.chipbench_work')
 COMPARED_STEPS = 3
-ADAM_B1 = 0.9
 
 
 def say(*parts):
@@ -72,7 +73,9 @@ def load_json(path):
 
 
 def find_cell(name):
-  """The cell's entry, configuration, traffic and limits, by name."""
+  """The cell's entry, configuration (with its family), traffic and
+  limits, by name."""
+  from chipbench import families
   bench_path = os.path.join(REPO, 'BENCHMARK.json')
   if not os.path.exists(bench_path):
     fail(2, 'no BENCHMARK.json at the root of the checkout')
@@ -91,6 +94,10 @@ def find_cell(name):
   cell['traffic_data'] = load_json(traffic_path)
   cell['limits'] = load_json(os.path.join(HERE, 'limits', name + '.json'))
   cell['bench'] = bench
+  try:
+    cell['family'] = families.load(cell['config_data'])
+  except families.Refused as e:
+    fail(2, str(e))
   return cell
 
 
@@ -126,12 +133,13 @@ def _cli(*args):
   subprocess.run(cmd, check=True, cwd=REPO, env=env, stdout=sys.stderr)
 
 
-def prepare_data(traffic):
+def prepare_data(traffic, vocab_file):
   """Balanced shards for this traffic file's corpus; made once per
   checkout (users pay preprocessing once per corpus), found again by the
   hash of what decides their bytes."""
   from chipbench.corpus import write_corpus
   recipe = {k: traffic[k] for k in ('corpus', 'preprocess', 'balance')}
+  recipe['vocab_file'] = os.path.relpath(vocab_file, REPO)
   digest = hashlib.sha256(
       json.dumps(recipe, sort_keys=True).encode()).hexdigest()[:16]
   final = os.path.join(WORK, 'data', digest)
@@ -152,7 +160,7 @@ def prepare_data(traffic):
   say(f'corpus: {mb:.1f} MB from corpus_seed {corpus["corpus_seed"]}')
   pre = traffic['preprocess']
   _cli(pre['cli'], '--source', os.path.join(tmp, 'source'), '--sink',
-       os.path.join(tmp, 'shards'), '--vocab-file', VOCAB, *pre['args'])
+       os.path.join(tmp, 'shards'), '--vocab-file', vocab_file, *pre['args'])
   _cli('balance_shards', '--indir', os.path.join(tmp, 'shards'), '--outdir',
        os.path.join(tmp, 'balanced'), '--num-shards',
        traffic['balance']['num_shards'])
@@ -168,49 +176,19 @@ def prepare_data(traffic):
   return os.path.join(final, 'balanced')
 
 
-def bin_lengths(shards, train):
-  """The sequence length of every batch shape the loader can yield."""
-  if not train.get('bin_size'):
-    return [train['max_seq_length']]
-  ids = sorted({int(name.rsplit('_', 1)[1]) for name in os.listdir(shards)
-                if '.parquet_' in name})
-  align = 8 if train['data_format'] == 'pairs' else 128
-  return sorted({
-      min(-(-train['bin_size'] * (i + 1) // align) * align,
-          train['max_seq_length']) for i in ids})
-
-
 # ----------------------------------------------------------------------------
 # the two taps
 
 
-def batch_facts(batch):
-  """What the arithmetic of required work needs to know of one batch."""
-  import numpy as np
-  mask = np.asarray(batch['attention_mask'])
-  rows = mask.sum(axis=1)
-  seg = batch.get('segment_ids')
-  if seg is None:
-    units = rows
-  else:
-    seg = np.asarray(seg)
-    units = np.concatenate([np.bincount(r[r >= 0]) for r in seg])
-    units = units[units > 0]
-  return {
-      'rows': [int(n) for n in rows],
-      'units': [int(n) for n in units],
-      'masked': [int(n) for n in
-                 (np.asarray(batch['labels']) != -100).sum(axis=1)],
-  }
-
-
 class LoaderTap:
-  """The host loader, with what each batch held written down. Iteration
-  runs on the prefetch thread, as the loader's own would."""
+  """The host loader, with what each batch held (the family's
+  ``batch_facts``) written down. Iteration runs on the prefetch thread,
+  as the loader's own would."""
 
-  def __init__(self, inner, keep_first):
+  def __init__(self, inner, keep_first, batch_facts):
     self._inner = inner
     self._keep_first = keep_first
+    self._batch_facts = batch_facts
     self.facts = []
     self.first = []
 
@@ -229,7 +207,7 @@ class LoaderTap:
           return
       if len(self.first) < self._keep_first:
         self.first.append({k: np.array(v) for k, v in batch.items()})
-      self.facts.append(batch_facts(batch))
+      self.facts.append(self._batch_facts(batch))
       yield batch
 
 
@@ -290,16 +268,13 @@ class Window:
     i = len(self.calls)
     self.calls.append(now)
     if i == 1:
-      # The state after one step: Adam's first moment is (1 - b1) times
-      # the first gradient, as the optimizer got it.
-      from chipbench import adapter
-      self.grad_norms = adapter.leaf_norms(opt_state[0].mu,
-                                           scale=1.0 / (1.0 - ADAM_B1))
+      # The state after one step holds the first gradient, as the
+      # optimizer got it.
+      self.grad_norms = self.cell['family'].first_gradient_norms(opt_state)
     elif i == COMPARED_STEPS:
       # The parameters after the compared steps, before this call
       # donates them.
-      from chipbench import adapter
-      self.change_norms = adapter.change_norms(
+      self.change_norms = self.cell['family'].change_norms(
           self.cell['config_data'], self.seed, params)
     elif i == self.warmup and self.trace_dir:
       # Armed now, the loop's own profiler hook starts the trace once this
@@ -324,95 +299,37 @@ class Window:
 # building the loop as pretrain.main does
 
 
-def fake_batch(batch, seq, block_diagonal):
-  import numpy as np
-  out = {
-      'input_ids': np.ones((batch, seq), np.int32),
-      'token_type_ids': np.zeros((batch, seq), np.int32),
-      'attention_mask': np.ones((batch, seq), np.int32),
-      'labels': np.full((batch, seq), -100, np.int32),
-      'next_sentence_labels': np.zeros((batch,), np.int32),
-  }
-  out['labels'][:, 1::7] = 5
-  if block_diagonal:
-    out['segment_ids'] = np.zeros((batch, seq), np.int32)
-  return out
-
-
-def bert_config(cell, train):
-  """``BertConfig`` as ``pretrain.main`` makes it, from the configuration
-  file; where the file names a preset of the program, its sizes have to
-  be that preset's."""
-  from lddl_tpu.models import BertConfig
-  from lddl_tpu.training.pretrain import MODEL_SIZES
-  c = cell['config_data']
-  sizes = dict(hidden_size=c['hidden_size'],
-               num_layers=c['num_hidden_layers'],
-               num_heads=c['num_attention_heads'],
-               intermediate_size=c['intermediate_size'])
-  preset = c.get('program_preset')
-  if preset and MODEL_SIZES[preset] != sizes:
-    fail(2, f'configuration {cell["config"]!r} says preset {preset!r} but '
-         f'its sizes {sizes} are not MODEL_SIZES[{preset!r}]')
-  if c['max_position_embeddings'] != max(train['max_seq_length'], 512):
-    fail(2, 'max_position_embeddings of the configuration file is not '
-         'max(max_seq_length, 512), which is what pretrain.main builds')
-  if c['attention_probs_dropout_prob'] != 0:
-    fail(2, 'the program has no dropout on attention probabilities; the '
-         'configuration file has to say 0 and list the key as changed')
-  return BertConfig(
-      vocab_size=c['vocab_size'],
-      max_position_embeddings=c['max_position_embeddings'],
-      type_vocab_size=c['type_vocab_size'],
-      dropout_rate=c['hidden_dropout_prob'],
-      attention_impl=train['attention'], remat=train['remat'], **sizes)
-
-
 def build_loop(cell, shards, seed, window):
   import jax
   import jax.numpy as jnp
 
-  from chipbench import adapter
+  from chipbench import families
   from lddl_tpu.core.compile_cache import use_compile_cache
   from lddl_tpu.loader.device import make_global_batch
   from lddl_tpu.parallel import make_mesh, mesh_summary
-  from lddl_tpu.tokenization.wordpiece import load_bert_tokenizer
-  from lddl_tpu.training.pretrain import TrainLoop
 
+  family = cell['family']
   train = cell['traffic_data']['train']
   if train['prng'] != 'threefry':
     jax.config.update('jax_default_prng_impl', train['prng'])
   say(f'compile cache: {use_compile_cache()}')
   jax.config.update('jax_persistent_cache_min_compile_time_secs', 0)
-  tokenizer = load_bert_tokenizer(vocab_file=VOCAB, backend='hf')
-  vocab = ((tokenizer.vocab_size + 63) // 64) * 64
-  if vocab != cell['config_data']['vocab_size']:
-    fail(2, f'the tokenizer gives a padded vocabulary of {vocab}, the '
-         f'configuration file says {cell["config_data"]["vocab_size"]}')
-  cfg = bert_config(cell, train)
   mesh = make_mesh(**train['mesh'])
   say(f'mesh: {mesh_summary(mesh)}')
-  loop = TrainLoop.build(
-      shards, tokenizer, model_cfg=cfg, mesh=mesh,
-      learning_rate=train['learning_rate'],
-      warmup_steps=train['warmup_steps'], total_steps=train['total_steps'],
-      weight_decay=train['weight_decay'],
-      batch_size_per_rank=train['batch_size'], bin_size=train['bin_size'],
-      max_seq_length=train['max_seq_length'], masking=train['masking'],
-      seed=seed, max_predictions=train['max_predictions'],
-      data_format=train['data_format'],
-      block_diagonal=train['block_diagonal'])
+  try:
+    loop = family.build_loop(cell, shards, seed, mesh)
+  except families.Refused as e:
+    fail(2, str(e))
   say('TrainLoop.build done')
-  loop.loader = LoaderTap(loop.loader, COMPARED_STEPS)
+  loop.loader = LoaderTap(loop.loader, COMPARED_STEPS, family.batch_facts)
   tap = make_step_tap(loop.step_fn, window)
   loop.step_fn = tap
 
   # Every shape the loader can yield is compiled now, through the loop's
   # own step object and the program's own placement, on a batch of
   # nothing; the state these steps leave is thrown away.
-  for seq in bin_lengths(shards, train):
-    placed = make_global_batch(
-        fake_batch(train['batch_size'], seq, train['block_diagonal']), mesh)
+  for seq in family.bin_lengths(shards, train):
+    placed = make_global_batch(family.fake_batch(train, seq), mesh)
     t0 = time.perf_counter()
     loop.params, loop.opt_state, metrics = tap(
         loop.params, loop.opt_state, loop.rng, placed)
@@ -422,7 +339,7 @@ def build_loop(cell, shards, seed, window):
   for key, executable in getattr(tap, '_compiled', {}).items():
     analysis = getattr(executable, 'memory_analysis', lambda: None)()
     if analysis is not None:
-      shape = [k for k in key if k[0] == 'input_ids'][0][1]
+      shape = max((k[1] for k in key), key=len)  # [batch_size, seq]
       total = (analysis.argument_size_in_bytes +
                analysis.output_size_in_bytes +
                analysis.temp_size_in_bytes - analysis.alias_size_in_bytes)
@@ -440,8 +357,7 @@ def build_loop(cell, shards, seed, window):
       lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding),
       loop.params)
   jax.tree.map(lambda x: x.delete(), loop.params)
-  loop.params = adapter.seeded_program_params(cell['config_data'], seed,
-                                              like)
+  loop.params = family.seeded_params(cell['config_data'], seed, like)
   loop.opt_state = jax.jit(
       lambda t: jax.tree.map(jnp.zeros_like, t), donate_argnums=0)(
           loop.opt_state)
@@ -508,11 +424,11 @@ def main(argv=None):
 
   import jax
 
-  from chipbench import compare, reference, required_work, trace_reduce
+  from chipbench import compare, required_work, trace_reduce
   peaks = required_work.load_peaks(device['kind']) if on_tpu else None
 
   say('imports done')
-  shards = prepare_data(traffic)
+  shards = prepare_data(traffic, cell['family'].VOCAB_FILE)
   trace_dir = None
   if args.trace:
     from lddl_tpu.telemetry import enable
@@ -559,9 +475,8 @@ def main(argv=None):
   del loop, tap
   gc.collect()
   t0 = time.perf_counter()
-  from chipbench import adapter
-  ref = reference.follow(cell['config_data'], train, seed, first_batches,
-                         stream=adapter.DROPOUT_STREAM)
+  ref = cell['family'].follow(cell['config_data'], train, seed,
+                              first_batches)
   values = compare.numbers(program, ref)
   values['compiles_in_window'] = compiles
   correct, compared, observed = compare.judge(values, cell['limits'])
@@ -574,7 +489,8 @@ def main(argv=None):
 
   bench = cell['bench']
   ctx = {
-      'cell': cell, 'config': cell['config_data'], 'train': train,
+      'cell': cell, 'family': cell['family'],
+      'config': cell['config_data'], 'train': train,
       'chips': cell['chips'], 'peaks': peaks, 'wall_s': wall,
       'steps': facts, 'intervals_ms': intervals_ms,
       'compiles_in_window': compiles, 'memory': memory,

@@ -54,13 +54,14 @@ def test_hand_made_trace():
 
 
 def test_mfu_is_read_from_the_traced_steps_on_the_device_clock():
-  from chipbench import required_work, run
+  from chipbench import families, required_work, run
   config = run.load_json(os.path.join(os.path.dirname(HERE), 'configs',
                                       'bert-tiny.json'))
   steps = [{'rows': [40, 64], 'units': [40, 64], 'masked': [6, 9]},
            {'rows': [128, 100], 'units': [128, 100], 'masked': [19, 15]}]
   ctx = {'trace': tr.reduce(hand_made()), 'peaks': {'flops_per_s': 1e15},
          'chips': 1, 'config': config, 'train': {'max_predictions': 20},
+         'family': families.load(config),
          'traced_steps': steps,
          # a slow loop around the same steps changes nothing:
          'wall_s': 1e9, 'steps': steps * 50}
