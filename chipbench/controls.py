@@ -5,7 +5,8 @@
 
 Not part of a benchmark run. In one process, for every seed: build the
 loop as ``run.py`` does, let it take its first steps through
-``TrainLoop.run`` (no measured window is needed for a training cell), and
+``TrainLoop.run`` (the compared steps and the one after them: no measured
+window is needed for a training cell), and
 follow the same steps with the plain reference. That gives the *lower*
 reading of every number compared (the program against the reference). On
 the first ``--control-seeds`` seeds it also puts in the program's place
@@ -49,7 +50,8 @@ def program_side(run, cell, shards, seed):
   window = run.Window(cell, seed, seconds=0.0, trace_dir=None)
   loop, tap = run.build_loop(cell, shards, seed, window)
   window.recording = True
-  losses = loop.run(cell['traffic_data']['window']['max_steps'], log_every=0)
+  # The parameters' change is read at the call after the compared steps.
+  losses = loop.run(run.COMPARED_STEPS + 1, log_every=0)
   window.recording = False
   batches = loop.loader.first
   out = {'losses': losses[:run.COMPARED_STEPS],

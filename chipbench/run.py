@@ -9,9 +9,14 @@ family), with the real loader,
 ``prefetch_to_device`` and the ``CompiledStepCache``. The benchmark adds
 two taps of its own and nothing else: one around the host loader (what
 each batch held) and one around ``loop.step_fn`` (when each step was
-called). The window opens at the call of the first step after warm-up
-and closes at the first call ``--seconds`` later; it is ended through the
-loop's own stop path (the preemption notice).
+called, and at which call it was first found finished). The window holds
+whole epochs, so that every seed fills it with the same work in another
+order: it opens when the last step before the first epoch start at or
+after warm-up is found finished, and closes when the last step of an
+epoch is, ``--seconds`` later or more (one epoch where an epoch is
+longer); it is ended through the loop's own stop path (the preemption
+notice). ``setup_s`` ends at the first step call after warm-up, wherever
+the epoch stands.
 
 Everything that belongs to one cell is data: the configuration file, the
 traffic file, the limits file and one reader per per-layer metric, all
@@ -30,6 +35,7 @@ import time
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
+import collections  # noqa: E402
 import gc  # noqa: E402
 import glob  # noqa: E402
 import hashlib  # noqa: E402
@@ -182,14 +188,17 @@ def prepare_data(traffic, vocab_file):
 
 class LoaderTap:
   """The host loader, with what each batch held (the family's
-  ``batch_facts``) written down. Iteration runs on the prefetch thread,
-  as the loader's own would."""
+  ``batch_facts``), its padded shape and the index at which each epoch
+  starts written down. Iteration runs on the prefetch thread, as the
+  loader's own would; ``TrainLoop.run`` enters it once an epoch."""
 
   def __init__(self, inner, keep_first, batch_facts):
     self._inner = inner
     self._keep_first = keep_first
     self._batch_facts = batch_facts
     self.facts = []
+    self.shapes = []
+    self.epoch_starts = []
     self.first = []
 
   def __getattr__(self, name):
@@ -199,6 +208,7 @@ class LoaderTap:
     import jax
     import numpy as np
     it = iter(self._inner)
+    self.epoch_starts.append(len(self.facts))
     while True:
       with jax.profiler.TraceAnnotation('chipbench.loader_next'):
         try:
@@ -208,12 +218,15 @@ class LoaderTap:
       if len(self.first) < self._keep_first:
         self.first.append({k: np.array(v) for k, v in batch.items()})
       self.facts.append(self._batch_facts(batch))
+      self.shapes.append(max((np.shape(v) for v in batch.values()), key=len))
       yield batch
 
 
 def make_step_tap(step_fn, window):
   """``loop.step_fn`` as the program's own ``CompiledStepCache``, with the
-  time of every call written down."""
+  time of every call written down, and each step's loss kept until a
+  later call finds it ready (the loop runs ahead of the device: a call
+  returns before its step has run)."""
   import jax
 
   from lddl_tpu.training.pretrain import CompiledStepCache
@@ -225,14 +238,29 @@ def make_step_tap(step_fn, window):
       if window.recording:
         window.on_call(now, params, opt_state, self)
       with jax.profiler.TraceAnnotation('chipbench.step_fn'):
-        return super().__call__(params, opt_state, rng, batch)
+        out = super().__call__(params, opt_state, rng, batch)
+      if window.recording:
+        window.pending.append(out[2]['loss'])
+      return out
 
   return StepTap(step_fn)
 
 
 class Window:
-  """Opens after ``warmup`` steps, closes ``seconds`` later; between the
-  two, nothing but reading the clock."""
+  """Whole epochs of finished steps. ``done[j]`` is the time of the first
+  step call that found step ``j`` finished: with one step in flight that
+  is call ``j + 2``, made as soon as the host has read step ``j``'s loss,
+  while step ``j + 1`` runs. The window opens at ``done[lo - 1]``, ``lo``
+  the first epoch start at or after ``open_at`` (warm-up, in a traced run
+  the traced steps too), and closes at ``done[hi - 1]``, ``hi`` the first
+  epoch start with ``seconds`` or more between the two: the device ran
+  steps ``lo`` to ``hi - 1`` in that time, whole epochs, and nothing else.
+  (Between the *calls* ``lo`` and ``hi`` it ran steps ``lo - 1`` to
+  ``hi - 2``: another epoch's last step for this one's, which in a cell
+  of long unlike steps moved ``tokens_per_s`` by 1.9 % with the seed.)
+  Between the two, nothing but reading the clock. Set-up ends at
+  ``calls[open_at]``. ``epoch_starts`` is the loader tap's list: the index
+  of each epoch's first step, there before that step is called."""
 
   HISTOGRAMS = ('train.data_wait_seconds', 'train.compute_seconds',
                 'train.step_seconds', 'train.h2d_seconds')
@@ -251,6 +279,9 @@ class Window:
     self.open_at = self.warmup + (self.trace_steps + 2 if trace_dir else 0)
     self.recording = False
     self.calls = []
+    self.pending = collections.deque()  # losses of steps not yet found ready
+    self.done = []
+    self.epoch_starts = []
     self.open_index = self.close_index = None
     self.misses = {}
     self.telemetry = {}
@@ -267,6 +298,9 @@ class Window:
   def on_call(self, now, params, opt_state, tap):
     i = len(self.calls)
     self.calls.append(now)
+    while self.pending and self.pending[0].is_ready():
+      self.pending.popleft()
+      self.on_done(now, tap)
     if i == 1:
       # The state after one step holds the first gradient, as the
       # optimizer got it.
@@ -281,12 +315,20 @@ class Window:
       # step is done and stops it `trace_steps` steps later.
       from lddl_tpu.telemetry.profiling import get_step_profiler
       get_step_profiler().arm(self.trace_steps, out_dir=self.trace_dir)
-    if i == self.open_at:
+
+  def on_done(self, now, tap):
+    """The next step was found finished at ``now``; where the step after
+    it starts an epoch, the window may open or close."""
+    self.done.append(now)
+    i = len(self.done)
+    if (i < self.open_at or self.close_index is not None or
+        i not in self.epoch_starts):
+      return
+    if self.open_index is None:
       self.open_index = i
       self.misses['open'] = tap.misses
       self.telemetry['open'] = self._telemetry()
-    elif (self.open_index is not None and self.close_index is None and
-          now - self.calls[self.open_index] >= self.seconds):
+    elif now - self.done[self.open_index - 1] >= self.seconds:
       self.close_index = i
       self.misses['close'] = tap.misses
       self.telemetry['close'] = self._telemetry()
@@ -322,6 +364,7 @@ def build_loop(cell, shards, seed, window):
     fail(2, str(e))
   say('TrainLoop.build done')
   loop.loader = LoaderTap(loop.loader, COMPARED_STEPS, family.batch_facts)
+  window.epoch_starts = loop.loader.epoch_starts
   tap = make_step_tap(loop.step_fn, window)
   loop.step_fn = tap
 
@@ -443,19 +486,23 @@ def main(argv=None):
   losses = loop.run(traffic['window']['max_steps'], log_every=0)
   window.recording = False
   if window.close_index is None:
-    fail(2, f'the loop ended after {len(window.calls)} steps before the '
-         'window closed: raise window.max_steps in the traffic file')
+    fail(2, f'the loop ended after {len(window.calls)} steps before an '
+         'epoch start closed the window: raise window.max_steps in the '
+         'traffic file')
   if args.trace:
     from lddl_tpu.telemetry.profiling import get_step_profiler
     get_step_profiler().close()
 
   lo, hi = window.open_index, window.close_index
-  t_open, t_close = window.calls[lo], window.calls[hi]
+  t_open, t_close = window.done[lo - 1], window.done[hi - 1]
   wall = t_close - t_open
   loader_facts = loop.loader.facts
   facts = loader_facts[lo:hi]
+  epochs = sum(lo <= start < hi for start in window.epoch_starts)
+  steps_per_shape = dict(collections.Counter(
+      'x'.join(map(str, shape)) for shape in loop.loader.shapes[lo:hi]))
   intervals_ms = [1e3 * (b - a) for a, b in
-                  zip(window.calls[lo:hi], window.calls[lo + 1:hi + 1])]
+                  zip(window.done[lo - 1:hi - 1], window.done[lo:hi])]
   tokens = sum(sum(f['rows']) for f in facts)
   compiles = window.misses['close'] - window.misses['open']
   finite = all(math.isfinite(x) for x in losses)
@@ -465,11 +512,16 @@ def main(argv=None):
              'change_norms': window.change_norms}
   memory = max((device_memory(d) for d in jax.local_devices()),
                key=lambda m: m['peak_bytes'])
-  say(f'window: {hi - lo} steps, {tokens} real tokens in {wall:.3f}s; '
+  say(f'window: {epochs} whole epochs, {hi - lo} steps '
+      f'{steps_per_shape}, {tokens} real tokens in {wall:.3f}s; '
       f'loss {losses[lo]:.4f} -> {losses[hi - 1]:.4f}; compiles inside '
       f'{compiles}; peak_bytes_in_use {memory["peak_bytes_in_use"]} + '
       f'peak_bytes_reserved {memory["peak_bytes_reserved"]} = '
       f'{memory["peak_bytes"]} of {memory["bytes_limit"]}')
+
+  if hi - lo <= 64:  # a cell of few long steps: every one of them
+    say('ms between steps found finished: ' +
+        ' '.join(f'{x:.1f}' for x in intervals_ms))
 
   # --- free the program's state, then follow its first steps ---
   del loop, tap
@@ -502,11 +554,16 @@ def main(argv=None):
       'tokens_per_s': (tokens / wall, 'tokens/s'),
       'step_ms_p90': (statistics.quantiles(intervals_ms, n=10)[8]
                       if len(intervals_ms) >= 2 else None, 'ms'),
-      'setup_s': (t_open - T_START, 's'),
+      'setup_s': (window.calls[window.open_at] - T_START, 's'),
   }
   device_out = dict(device, memory_peak_bytes=memory['peak_bytes'])
   result = {'correct': bool(correct), 'attempted': hi - lo,
-            'failed': sum(not math.isfinite(x) for x in losses[lo:hi])}
+            'failed': sum(not math.isfinite(x) for x in losses[lo:hi]),
+            'window': {'epochs': epochs, 'steps': hi - lo, 'seconds': wall,
+                       'step_ms_median': statistics.median(intervals_ms),
+                       'step_ms_max': max(intervals_ms),
+                       'real_tokens': tokens,
+                       'steps_per_shape': steps_per_shape}}
   metrics = {}
   if args.trace:
     opened, closed = window.telemetry['open'], window.telemetry['close']
@@ -541,7 +598,6 @@ def main(argv=None):
     # Not a chip: counts only, and never under the name of a device metric.
     result['rehearsal'] = {
         'note': f'platform {device["platform"]}: no device metric',
-        'steps': hi - lo, 'real_tokens': tokens,
         'per_layer_readers': sorted(read_per_layer(
             [m['name'] for m in bench['per_layer']], ctx)),
     }
