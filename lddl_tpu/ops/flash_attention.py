@@ -14,14 +14,28 @@ q-blocks, k-blocks) for forward/dq and (batch*heads, k-blocks,
 q-blocks) for dk/dv — the contracted sequence axis is the *innermost*
 (sequential) grid dimension, with the running state (max/sum/acc or
 gradient accumulators) in VMEM scratch that persists across those
-steps. VMEM residency per grid step is one 128-row q/output tile plus
-one kv block of up to ``_BLOCK_KV_FWD``/``_BLOCK_KV_BWD`` (4096/2048)
-keys — a few MB total, independent of sequence length (an earlier
-revision held full per-head K/V in VMEM, capping single-chip sequences
-at ~8k; the grid-blocked form runs 32k+). K/V lengths that don't divide
-into whole blocks are padded up to the next block boundary with
--inf-biased columns (``_kv_blocking``), never dropped to slow 128-wide
-blocks.
+steps. One grid step works on a ``[block_q, block_k]`` tile of up to
+1024 x 1024 (``_tile_blocks``; a grid step costs ~0.45 us before it
+computes anything, so the tile must be large enough to hide that):
+VMEM per step is the q/output tile, one k and one v block (128 KB each
+in bfloat16, double-buffered) and the float32 ``[block_q, block_k]``
+intermediates (4 MB each, which Mosaic works through in pieces) —
+inside the default scoped limit, independent of sequence length (an
+earlier revision held full per-head K/V in VMEM, capping single-chip
+sequences at ~8k; the grid-blocked form runs 32k+). Lengths that don't
+divide into whole blocks are padded up to the next block boundary —
+keys with -inf-biased columns, queries with zero rows that are sliced
+away (``_blocking``) — never dropped to slow 128-wide blocks.
+
+Arithmetic: the MXU takes q, k, v and dO tiles in the dtype they arrive
+in and sums in float32; the four products with a computed operand
+(p.v, p^T.dO, dS.k, dS^T.q) round that operand to the inputs' dtype just
+before the product, where the dense path rounds its probabilities
+(``models/bert.py``: ``probs.astype(cfg.dtype)``). Scores, the running
+max and sum, ``exp``, ``lse``, ``delta``, dS and the accumulators are
+float32. A model built in float32 therefore gets float32 operands
+throughout. No product transposes a tile: q.k^T contracts the minor
+dimension of both operands, and dk/dv keep their tile key-major.
 
 Masking: a key-side additive bias ``[batch, seq]`` (0 = attend, -1e9 =
 padding) — the same semantics as the dense path and the ring
@@ -41,10 +55,12 @@ intervals are disjoint provably contains only masked pairs — the
 kernels *skip* such tiles entirely (``pl.when`` around the whole tile
 body: no MXU issue, no accumulator update), and only boundary-straddling
 tiles pay the elementwise ``q_seg == kv_seg`` additive -1e9 bias on top
-of the key-side padding bias. A row packing k documents therefore runs
-~1/k of its attention tiles instead of computing and masking all of
-them — the "no cross-contamination" masking of arXiv:2107.02027 as a
-speedup rather than a cost.
+of the key-side padding bias: a tile whose two intervals are the same
+single document runs the body without it (a segment id of -1 must come
+with a masked key, as the packed loader gives them). A row packing k
+documents therefore runs ~1/k of its attention tiles instead of
+computing and masking all of them — the "no cross-contamination"
+masking of arXiv:2107.02027 as a speedup rather than a cost.
 
 Differentiation is a ``jax.custom_vjp``: forward saves (out, lse); the
 backward runs two Pallas kernels — dq over q-blocks, (dk, dv) over
@@ -61,6 +77,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -91,51 +108,69 @@ def _padded_len(s):
   return ((s + 127) // 128) * 128
 
 
-# Tuned on v5e: the q block sets the output tile (128 = one MXU tile of
-# rows); the kv block is the unit streamed through the innermost grid
-# dimension — larger blocks amortize per-grid-step overhead (128-wide kv
-# blocks measured 3-4x slower than 2048-wide at s>=2048) while VMEM use
-# stays modest (2 x block_k x 64 x 2B double-buffered ~= 1 MB at 2048).
-# Env overrides (LDDL_FLASH_BLOCK_{Q,KV_FWD,KV_BWD}) support per-shape
-# retuning without code edits — short sequences want smaller kv blocks,
-# and block-diagonal packed rows skip at tile granularity, so many small
-# documents per row skip more with smaller kv blocks.
-_BLOCK_Q = int(os.environ.get('LDDL_FLASH_BLOCK_Q', 128))
-_BLOCK_KV_FWD = int(os.environ.get('LDDL_FLASH_BLOCK_KV_FWD', 4096))
-_BLOCK_KV_BWD = int(os.environ.get('LDDL_FLASH_BLOCK_KV_BWD', 2048))
-# Segmented (block-diagonal) runs cap kv blocks finer: a tile can only
-# skip whole, so the skip granularity IS the kv block — a 4096-wide
-# block over a row packing 16 x ~512-token docs straddles ~8 documents
-# and never skips, while 512-wide blocks skip ~7/8 of the grid. The
-# extra per-block overhead is repaid as soon as rows pack >~2 docs.
-_BLOCK_KV_SEG = int(os.environ.get('LDDL_FLASH_BLOCK_KV_SEG', 512))
+# Tuned on v5e (PERF.md section 5, PR 30's tables: the kernels alone at
+# [24, 8192, 64] bfloat16). A grid step costs ~0.45 us before it computes
+# anything (its DMAs' issue and wait, the pipeline's bookkeeping), and a
+# [128 x 512] tile's own work takes no longer than that: the tile, not the
+# operands' dtype, sets the pace (Mosaic's default float32 product is one
+# bfloat16 pass already). Forward + backward of one layer's call, one
+# document a row: 69.4 ms at [128 x 512], 33.2 at [512 x 512], 26.1 at
+# [512 x 1024], 23.8 at [1024 x 1024]; rows of 30 short documents, where
+# a larger tile skips more coarsely, 39.8 / 15.9 / 13.1 / 12.4: the
+# largest tile wins there too. The float32 [block_q, block_k]
+# intermediates (scores, p, dp, ds: 4 MB each at [1024 x 1024]) compile
+# inside Mosaic's default scoped VMEM limit, so no ``vmem_limit_bytes`` is
+# set; kv blocks of 2048 bought nothing more.
+# The four values are *caps*: a sequence shorter than a cap takes one
+# block of its own (padded) length. Env overrides
+# (LDDL_FLASH_BLOCK_{Q,KV_FWD,KV_BWD,KV_SEG}) retune a shape without a
+# code edit.
+_BLOCK_Q = int(os.environ.get('LDDL_FLASH_BLOCK_Q', 1024))
+_BLOCK_KV_FWD = int(os.environ.get('LDDL_FLASH_BLOCK_KV_FWD', 1024))
+_BLOCK_KV_BWD = int(os.environ.get('LDDL_FLASH_BLOCK_KV_BWD', 1024))
+# Segmented (block-diagonal) runs: a tile can only skip whole, so the
+# tile IS the skip granularity, and this is the cap that traffic of very
+# many very short documents a row would lower.
+_BLOCK_KV_SEG = int(os.environ.get('LDDL_FLASH_BLOCK_KV_SEG', 1024))
 
 
-def _kv_blocking(s_kv_pad, cap):
-  """(block, padded_kv): a kv block <= cap (multiple of 128, or the whole
-  length when it fits in one block) and the kv length rounded up to a
+def _blocking(s_pad, cap):
+  """(block, padded): a block <= cap (multiple of 128, or the whole
+  length when it fits in one block) and the length rounded up to a
   whole number of blocks. Rather than requiring the block to divide the
   incoming length (which collapses to slow 128-wide blocks whenever the
-  length has no large divisor), the caller pads K/V/bias up to
-  ``padded_kv`` — masked padding columns cost at most one extra
+  length has no large divisor), the caller pads up to ``padded`` —
+  masked padding columns (and zero query rows) cost at most one extra
   fractional block of compute (<= ~6% at s >= 2k)."""
-  if s_kv_pad <= cap:
-    return s_kv_pad, s_kv_pad
-  n_steps = -(-s_kv_pad // cap)
-  block = -(-s_kv_pad // (n_steps * 128)) * 128
+  if s_pad <= cap:
+    return s_pad, s_pad
+  n_steps = -(-s_pad // cap)
+  unit = min(cap, 128)  # a cap under one lane tile: interpreter-sized tests
+  block = -(-s_pad // (n_steps * unit)) * unit
   return block, block * n_steps
 
 
-def _pad_kv(k, v, bias, kv_seg, padded_kv):
-  s_kv = k.shape[1]
-  if padded_kv == s_kv:
-    return k, v, bias, kv_seg
-  grow = ((0, 0), (0, padded_kv - s_kv), (0, 0))
-  seg_grow = ((0, 0), (0, 0), (0, padded_kv - s_kv))
-  return (jnp.pad(k, grow), jnp.pad(v, grow),
-          jnp.pad(bias, seg_grow, constant_values=NEG_INF),
-          None if kv_seg is None else jnp.pad(kv_seg, seg_grow,
-                                              constant_values=-1.0))
+def _tile_blocks(s_q, s_kv, segmented, backward=False):
+  """((block_q, padded_q), (block_k, padded_kv)) of one kernel launch,
+  from static facts alone: the two (already ``_padded_len``-ed) sequence
+  lengths, whether segment ids came along, and which pass it is. The one
+  place a tile shape is chosen: the forward and backward launches and the
+  host's ``count_skippable_tiles`` all ask here, so the count of tiles
+  the host reports cannot drift from the grid the chip runs."""
+  kv_cap = _BLOCK_KV_BWD if backward else _BLOCK_KV_FWD
+  if segmented:
+    kv_cap = min(kv_cap, _BLOCK_KV_SEG)
+  return _blocking(s_q, _BLOCK_Q), _blocking(s_kv, kv_cap)
+
+
+def _pad_to(x, axis, length, value=0.0):
+  """``x`` grown along ``axis`` to ``length`` with ``value``; None and an
+  array already that long pass through."""
+  if x is None or x.shape[axis] == length:
+    return x
+  widths = [(0, 0)] * x.ndim
+  widths[axis] = (0, length - x.shape[axis])
+  return jnp.pad(x, widths, constant_values=value)
 
 
 def _seg_interval(seg):
@@ -151,20 +186,46 @@ def _seg_interval(seg):
   return lo, hi
 
 
-def _tile_live(qseg_ref, kseg_ref):
-  """Scalar: does this (q-block, kv-block) tile contain any same-doc
-  pair? Doc ids are monotone within a packed row, so each block spans a
-  contiguous id interval and interval overlap is exact."""
-  qlo, qhi = _seg_interval(qseg_ref[0, 0, :])
-  klo, khi = _seg_interval(kseg_ref[0, 0, :])
-  return (qlo <= khi) & (klo <= qhi)
+def _for_live_tile(tile, qseg_ref, kseg_ref):
+  """Run ``tile(seg_bias)`` unless the (q-block, kv-block) tile holds no
+  same-document pair. Doc ids are monotone within a packed row, so each
+  block spans a contiguous id interval and interval overlap is exact.
+  A live tile is *interior* when both intervals are the same single
+  document: every real pair is a same-document pair, padding keys carry
+  the key-side bias already (an id of -1 comes with a masked key), so
+  the elementwise segment bias would add zeros and is left out.
+  The refs hold the two blocks' ids (row or column), or are None for
+  full attention: one tile body, no skip machinery in the trace."""
+  if qseg_ref is None:
+    tile(False)
+    return
+  qlo, qhi = _seg_interval(qseg_ref[...])
+  klo, khi = _seg_interval(kseg_ref[...])
+  live = (qlo <= khi) & (klo <= qhi)
+  interior = (qlo == qhi) & (klo == khi) & (qlo == klo)
+  pl.when(interior)(lambda: tile(False))
+  pl.when(live & jnp.logical_not(interior))(lambda: tile(True))
 
 
-def _seg_bias(qseg_ref, kseg_ref):
-  """Elementwise cross-document mask for boundary-straddling tiles."""
-  qseg = qseg_ref[0, 0, :]
-  kseg = kseg_ref[0, 0, :]
-  return jnp.where(qseg[:, None] == kseg[None, :], 0.0, NEG_INF)
+def _mxu(a, b, contract):
+  """One MXU product summed in float32. ``contract`` names the dimension
+  of ``a`` and of ``b`` that is summed over, so a transposed operand is
+  a pair of dimension numbers and never a materialised tile. Operands go
+  in as they are: bfloat16 inputs give the MXU its native single pass."""
+  return lax.dot_general(a, b, (((contract[0],), (contract[1],)), ((), ())),
+                         preferred_element_type=jnp.float32)
+
+
+def _scores(a, b, bias, scale, a_seg=None, b_seg=None):
+  """float32 ``[rows of a, rows of b]`` scores of one tile: a.b^T scaled,
+  plus the key-side padding bias (a row ``[1, n]`` where ``b`` holds the
+  keys, a column ``[m, 1]`` where ``a`` does) and, given the two blocks'
+  segment ids (a column ``[m, 1]`` and a row ``[1, n]``) on a tile that
+  straddles a document boundary, the elementwise cross-document mask."""
+  s = _mxu(a, b, (1, 1)) * scale + bias
+  if a_seg is not None:
+    s = s + jnp.where(a_seg == b_seg, 0.0, NEG_INF)
+  return s
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, qseg_ref, kseg_ref,
@@ -182,27 +243,22 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, qseg_ref, kseg_ref,
     l_ref[...] = jnp.zeros_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
 
-  def _tile():
-    q = q_ref[0].astype(jnp.float32)  # [bq, d]
-    k_blk = k_ref[0].astype(jnp.float32)  # [bk, d]
-    v_blk = v_ref[0].astype(jnp.float32)
-    scores = jnp.dot(q, k_blk.T, preferred_element_type=jnp.float32) * scale
-    scores = scores + bias_ref[0, 0, :].astype(jnp.float32)[None, :]
-    if qseg_ref is not None:
-      scores = scores + _seg_bias(qseg_ref, kseg_ref)
+  def _tile(seg_bias):
+    v_blk = v_ref[0]  # [bk, d], the input's dtype
+    segs = (qseg_ref[0, 0, :][:, None], kseg_ref[0]) if seg_bias else ()
+    scores = _scores(q_ref[0], k_ref[0], bias_ref[0], scale, *segs)
     m = m_ref[...]
     m_new = jnp.maximum(m, jnp.max(scores, axis=-1, keepdims=True))
     p = jnp.exp(scores - m_new)
     alpha = jnp.exp(m - m_new)
     m_ref[...] = m_new
     l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
-        p, v_blk, preferred_element_type=jnp.float32)
+    # p is rounded to the input's dtype where the dense path rounds its
+    # probabilities (models/bert.py); float32 inputs keep it float32.
+    acc_ref[...] = acc_ref[...] * alpha + _mxu(p.astype(v_blk.dtype), v_blk,
+                                               (1, 0))
 
-  if qseg_ref is None:
-    _tile()
-  else:
-    pl.when(_tile_live(qseg_ref, kseg_ref))(_tile)
+  _for_live_tile(_tile, qseg_ref, kseg_ref)
 
   @pl.when(j == pl.num_programs(2) - 1)
   def _finalize():
@@ -222,27 +278,17 @@ def _dq_kernel(q_ref, k_ref, v_ref, bias_ref, qseg_ref, kseg_ref, do_ref,
   def _init():
     dq_acc_ref[...] = jnp.zeros_like(dq_acc_ref)
 
-  def _tile():
-    q = q_ref[0].astype(jnp.float32)
-    do = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0]      # [bq, 1]
-    delta = delta_ref[0]  # [bq, 1]
-    k_blk = k_ref[0].astype(jnp.float32)
-    v_blk = v_ref[0].astype(jnp.float32)
-    scores = jnp.dot(q, k_blk.T, preferred_element_type=jnp.float32) * scale
-    scores = scores + bias_ref[0, 0, :].astype(jnp.float32)[None, :]
-    if qseg_ref is not None:
-      scores = scores + _seg_bias(qseg_ref, kseg_ref)
-    p = jnp.exp(scores - lse)
-    dp = jnp.dot(do, v_blk.T, preferred_element_type=jnp.float32)
-    ds = p * (dp - delta)
-    dq_acc_ref[...] = dq_acc_ref[...] + jnp.dot(
-        ds, k_blk, preferred_element_type=jnp.float32)
+  def _tile(seg_bias):
+    k_blk = k_ref[0]
+    segs = (qseg_ref[0, 0, :][:, None], kseg_ref[0]) if seg_bias else ()
+    scores = _scores(q_ref[0], k_blk, bias_ref[0], scale, *segs)
+    p = jnp.exp(scores - lse_ref[0])            # lse, delta: [bq, 1]
+    dp = _mxu(do_ref[0], v_ref[0], (1, 1))      # do.v^T
+    ds = p * (dp - delta_ref[0])
+    dq_acc_ref[...] = dq_acc_ref[...] + _mxu(ds.astype(k_blk.dtype), k_blk,
+                                             (1, 0))
 
-  if qseg_ref is None:
-    _tile()
-  else:
-    pl.when(_tile_live(qseg_ref, kseg_ref))(_tile)
+  _for_live_tile(_tile, qseg_ref, kseg_ref)
 
   @pl.when(j == pl.num_programs(2) - 1)
   def _finalize():
@@ -253,7 +299,15 @@ def _dkv_kernel(q_ref, k_ref, v_ref, bias_ref, qseg_ref, kseg_ref, do_ref,
                 lse_ref, delta_ref, dk_ref, dv_ref, dk_acc_ref, dv_acc_ref,
                 *, scale):
   """Grid (bh, kv-blocks, q-blocks), q innermost; dk/dv accumulate in
-  scratch across the q sweep while the (k, v) block stays resident."""
+  scratch across the q sweep while the (k, v) block stays resident.
+
+  The tile is kept key-major, ``[block_k, block_q]``: dv = P^T.dO and
+  dk = dS^T.q are then plain products of the tile, where a query-major
+  tile would have to be transposed twice a step. So the per-key rows
+  (bias, kv segment ids) arrive as columns ``[block_k, 1]`` and the
+  per-query ones (lse, delta, q segment ids) as rows ``[1, block_q]`` —
+  which also keeps what the innermost axis fetches every step small (a
+  ``[block_q, 1]`` float32 column moves a whole 128-lane tile a row)."""
   i = pl.program_id(2)
 
   @pl.when(i == 0)
@@ -261,33 +315,21 @@ def _dkv_kernel(q_ref, k_ref, v_ref, bias_ref, qseg_ref, kseg_ref, do_ref,
     dk_acc_ref[...] = jnp.zeros_like(dk_acc_ref)
     dv_acc_ref[...] = jnp.zeros_like(dv_acc_ref)
 
-  def _tile():
-    k_blk = k_ref[0].astype(jnp.float32)  # [bk, d]
-    v_blk = v_ref[0].astype(jnp.float32)
-    bias = bias_ref[0, 0, :].astype(jnp.float32)[None, :]
-    q = q_ref[0].astype(jnp.float32)
-    do = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0]
-    delta = delta_ref[0]
+  def _tile(seg_bias):
+    q = q_ref[0]    # [bq, d]
+    do = do_ref[0]
+    segs = (kseg_ref[0], qseg_ref[0]) if seg_bias else ()
+    scores_t = _scores(k_ref[0], q, bias_ref[0], scale, *segs)  # k.q^T
     # Rows beyond the real sequence carry lse from padded-q garbage; their
     # dO is zero (cotangents of padding outputs are never produced by the
     # loss) so they contribute nothing — but guard exp() overflow anyway.
-    scores = jnp.dot(q, k_blk.T, preferred_element_type=jnp.float32) * scale
-    scores = scores + bias
-    if qseg_ref is not None:
-      scores = scores + _seg_bias(qseg_ref, kseg_ref)
-    p = jnp.exp(jnp.minimum(scores - lse, 30.0))
-    dv_acc_ref[...] = dv_acc_ref[...] + jnp.dot(
-        p.T, do, preferred_element_type=jnp.float32)
-    dp = jnp.dot(do, v_blk.T, preferred_element_type=jnp.float32)
-    ds = p * (dp - delta)
-    dk_acc_ref[...] = dk_acc_ref[...] + jnp.dot(
-        ds.T, q, preferred_element_type=jnp.float32)
+    p_t = jnp.exp(jnp.minimum(scores_t - lse_ref[0], 30.0))
+    dv_acc_ref[...] = dv_acc_ref[...] + _mxu(p_t.astype(do.dtype), do, (1, 0))
+    dp_t = _mxu(v_ref[0], do, (1, 1))  # v.do^T
+    ds_t = p_t * (dp_t - delta_ref[0])
+    dk_acc_ref[...] = dk_acc_ref[...] + _mxu(ds_t.astype(q.dtype), q, (1, 0))
 
-  if qseg_ref is None:
-    _tile()
-  else:
-    pl.when(_tile_live(qseg_ref, kseg_ref))(_tile)
+  _for_live_tile(_tile, qseg_ref, kseg_ref)
 
   @pl.when(i == pl.num_programs(2) - 1)
   def _finalize():
@@ -337,12 +379,17 @@ def _flash_pair(q, k, v, bias, q_seg, kv_seg, heads):
 
 def _flash_fwd_impl(q, k, v, bias, q_seg, kv_seg, heads):
   bh, s_q, d = q.shape
-  block_q = min(_BLOCK_Q, s_q)
-  cap = _BLOCK_KV_FWD if q_seg is None else min(_BLOCK_KV_FWD, _BLOCK_KV_SEG)
-  block_k, padded_kv = _kv_blocking(k.shape[1], cap)
-  k, v, bias, kv_seg = _pad_kv(k, v, bias, kv_seg, padded_kv)
-  grid = (bh, pl.cdiv(s_q, block_q), pl.cdiv(padded_kv, block_k))
-  q_spec, kv_spec, bias_spec, qseg_spec, _ = _qkv_specs(
+  (block_q, padded_q), (block_k, padded_kv) = _tile_blocks(
+      s_q, k.shape[1], q_seg is not None)
+  # Whole blocks on both axes: zero query rows (segment id -1) are
+  # sliced away below, padded keys are masked by their bias.
+  q = _pad_to(q, 1, padded_q)
+  q_seg = _pad_to(q_seg, 2, padded_q, -1.0)
+  k, v = _pad_to(k, 1, padded_kv), _pad_to(v, 1, padded_kv)
+  bias = _pad_to(bias, 2, padded_kv, NEG_INF)
+  kv_seg = _pad_to(kv_seg, 2, padded_kv, -1.0)
+  grid = (bh, padded_q // block_q, padded_kv // block_k)
+  q_spec, kv_spec, bias_spec, qseg_spec, row_spec = _qkv_specs(
       block_q, block_k, d, heads)
   if q_seg is None:
     kernel, in_specs = _plain(_fwd_kernel), [q_spec, kv_spec, kv_spec,
@@ -356,13 +403,10 @@ def _flash_fwd_impl(q, k, v, bias, q_seg, kv_seg, heads):
       functools.partial(kernel, scale=1.0 / d**0.5),
       grid=grid,
       in_specs=in_specs,
-      out_specs=[
-          pl.BlockSpec((1, block_q, d), lambda i, b, j: (i, b, 0)),
-          pl.BlockSpec((1, block_q, 1), lambda i, b, j: (i, b, 0)),
-      ],
+      out_specs=[q_spec, row_spec],
       out_shape=[
-          jax.ShapeDtypeStruct((bh, s_q, d), q.dtype),
-          jax.ShapeDtypeStruct((bh, s_q, 1), jnp.float32),
+          jax.ShapeDtypeStruct((bh, padded_q, d), q.dtype),
+          jax.ShapeDtypeStruct((bh, padded_q, 1), jnp.float32),
       ],
       scratch_shapes=[
           pltpu.VMEM((block_q, 1), jnp.float32),
@@ -372,7 +416,7 @@ def _flash_fwd_impl(q, k, v, bias, q_seg, kv_seg, heads):
       interpret=_interpret(),
       name='flash_fwd',
   )(*inputs)
-  return out, lse
+  return out[:, :s_q, :], lse[:, :s_q, :]
 
 
 def _flash_fwd(q, k, v, bias, q_seg, kv_seg, heads):
@@ -385,18 +429,24 @@ def _flash_bwd(heads, res, cotangents):
   g, g_lse = cotangents
   bh, s_q, d = q.shape
   s_kv = k.shape[1]
-  block_q = min(_BLOCK_Q, s_q)
-  cap = _BLOCK_KV_BWD if q_seg is None else min(_BLOCK_KV_BWD, _BLOCK_KV_SEG)
-  block_k, padded_kv = _kv_blocking(s_kv, cap)
-  k, v, bias_padded, kv_seg_padded = _pad_kv(k, v, bias, kv_seg, padded_kv)
+  segmented = q_seg is not None
+  (block_q, padded_q), (block_k, padded_kv) = _tile_blocks(
+      s_q, s_kv, segmented, backward=True)
   g = g.astype(q.dtype)
   # d(out)/dS = P(delta-terms); d(lse)/dS = P — so an lse cotangent folds
   # into the shared (dp - delta) factor as delta -= g_lse.
   delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
                   axis=-1, keepdims=True)  # [bh, s, 1]
   delta = delta - g_lse.astype(jnp.float32)
+  # Whole blocks on both axes. A padded query row has q = dO = delta = 0
+  # and lse = 0: its P is finite and its dS and P^T.dO terms are zeros.
+  q_padded, g = _pad_to(q, 1, padded_q), _pad_to(g, 1, padded_q)
+  lse, delta = _pad_to(lse, 1, padded_q), _pad_to(delta, 1, padded_q)
+  q_seg_padded = _pad_to(q_seg, 2, padded_q, -1.0)
+  k, v = _pad_to(k, 1, padded_kv), _pad_to(v, 1, padded_kv)
+  bias_padded = _pad_to(bias, 2, padded_kv, NEG_INF)
+  kv_seg_padded = _pad_to(kv_seg, 2, padded_kv, -1.0)
   scale = 1.0 / d**0.5
-  segmented = q_seg is not None
 
   # dq: grid (bh, q-blocks, kv-blocks), kv innermost.
   q_spec, kv_spec, bias_spec, qseg_spec, row_blocked = _qkv_specs(
@@ -405,43 +455,49 @@ def _flash_bwd(heads, res, cotangents):
     dq_kernel = _dq_kernel
     dq_specs = [q_spec, kv_spec, kv_spec, bias_spec, qseg_spec, bias_spec,
                 q_spec, row_blocked, row_blocked]
-    dq_inputs = (q, k, v, bias_padded, q_seg, kv_seg_padded, g, lse, delta)
+    dq_inputs = (q_padded, k, v, bias_padded, q_seg_padded, kv_seg_padded, g,
+                 lse, delta)
   else:
     dq_kernel = _plain(_dq_kernel)
     dq_specs = [q_spec, kv_spec, kv_spec, bias_spec, q_spec,
                 row_blocked, row_blocked]
-    dq_inputs = (q, k, v, bias_padded, g, lse, delta)
+    dq_inputs = (q_padded, k, v, bias_padded, g, lse, delta)
   dq = pl.pallas_call(
       functools.partial(dq_kernel, scale=scale),
-      grid=(bh, pl.cdiv(s_q, block_q), pl.cdiv(padded_kv, block_k)),
+      grid=(bh, padded_q // block_q, padded_kv // block_k),
       in_specs=dq_specs,
-      out_specs=pl.BlockSpec((1, block_q, d), lambda i, b, j: (i, b, 0)),
-      out_shape=jax.ShapeDtypeStruct((bh, s_q, d), q.dtype),
+      out_specs=q_spec,
+      out_shape=jax.ShapeDtypeStruct((bh, padded_q, d), q.dtype),
       scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
       interpret=_interpret(),
       name='flash_dq',
   )(*dq_inputs)
 
   # dk/dv: grid (bh, kv-blocks, q-blocks), q innermost; the (k, v) block
-  # stays resident across the q sweep.
+  # stays resident across the q sweep. The tile is key-major (see
+  # ``_dkv_kernel``): per-key rows go in as columns, per-query columns as
+  # rows (a singleton axis swapped: one small copy outside the kernel).
+  turned = lambda x: jnp.swapaxes(x, 1, 2)  # row <-> column
   q_by_i = pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0))
   kv_by_j = pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0))
-  bias_by_j = pl.BlockSpec((1, 1, block_k), lambda b, j, i: (b // heads, 0, j))
+  kcol_by_j = pl.BlockSpec((1, block_k, 1), lambda b, j, i: (b // heads, j, 0))
   qseg_by_i = pl.BlockSpec((1, 1, block_q), lambda b, j, i: (b // heads, 0, i))
-  row_by_i = pl.BlockSpec((1, block_q, 1), lambda b, j, i: (b, i, 0))
+  qrow_by_i = pl.BlockSpec((1, 1, block_q), lambda b, j, i: (b, 0, i))
   if segmented:
     dkv_kernel = _dkv_kernel
-    dkv_specs = [q_by_i, kv_by_j, kv_by_j, bias_by_j, qseg_by_i, bias_by_j,
-                 q_by_i, row_by_i, row_by_i]
-    dkv_inputs = (q, k, v, bias_padded, q_seg, kv_seg_padded, g, lse, delta)
+    dkv_specs = [q_by_i, kv_by_j, kv_by_j, kcol_by_j, qseg_by_i, kcol_by_j,
+                 q_by_i, qrow_by_i, qrow_by_i]
+    dkv_inputs = (q_padded, k, v, turned(bias_padded), q_seg_padded,
+                  turned(kv_seg_padded), g, turned(lse), turned(delta))
   else:
     dkv_kernel = _plain(_dkv_kernel)
-    dkv_specs = [q_by_i, kv_by_j, kv_by_j, bias_by_j, q_by_i,
-                 row_by_i, row_by_i]
-    dkv_inputs = (q, k, v, bias_padded, g, lse, delta)
+    dkv_specs = [q_by_i, kv_by_j, kv_by_j, kcol_by_j, q_by_i,
+                 qrow_by_i, qrow_by_i]
+    dkv_inputs = (q_padded, k, v, turned(bias_padded), g, turned(lse),
+                  turned(delta))
   dk, dv = pl.pallas_call(
       functools.partial(dkv_kernel, scale=scale),
-      grid=(bh, pl.cdiv(padded_kv, block_k), pl.cdiv(s_q, block_q)),
+      grid=(bh, padded_kv // block_k, padded_q // block_q),
       in_specs=dkv_specs,
       out_specs=[kv_by_j, kv_by_j],
       out_shape=[
@@ -455,7 +511,8 @@ def _flash_bwd(heads, res, cotangents):
       interpret=_interpret(),
       name='flash_dkv',
   )(*dkv_inputs)
-  return (dq, dk[:, :s_kv, :], dv[:, :s_kv, :], jnp.zeros_like(bias),
+  return (dq[:, :s_q, :], dk[:, :s_kv, :], dv[:, :s_kv, :],
+          jnp.zeros_like(bias),
           None if q_seg is None else jnp.zeros_like(q_seg),
           None if kv_seg is None else jnp.zeros_like(kv_seg))
 
@@ -554,16 +611,12 @@ def count_skippable_tiles(segment_ids, block_q=None, block_k=None):
   (batch, q-block, kv-block); multiply by heads for per-head counts;
   the fraction is heads-invariant). Feeds the ``train.attn_tiles_*``
   telemetry counters and the benchmark skip-fraction columns."""
-  s = int(segment_ids.shape[1])
-  s_pad = _padded_len(s)
-  if block_q is None:
-    block_q = min(_BLOCK_Q, s_pad)
-  if block_k is None:
-    block_k, s_pad = _kv_blocking(s_pad, min(_BLOCK_KV_FWD, _BLOCK_KV_SEG))
+  if block_q is None or block_k is None:
+    s_pad = _padded_len(int(segment_ids.shape[1]))
+    (grid_q, _), (grid_k, _) = _tile_blocks(s_pad, s_pad, segmented=True)
+    block_q, block_k = block_q or grid_q, block_k or grid_k
   import numpy as np
   seg = np.asarray(segment_ids)
-  if s_pad != s:
-    seg = np.pad(seg, ((0, 0), (0, s_pad - s)), constant_values=-1)
   qlo, qhi = segment_block_intervals(seg, block_q)
   klo, khi = segment_block_intervals(seg, block_k)
   live = ((qlo[:, :, None] <= khi[:, None, :]) &

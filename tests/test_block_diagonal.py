@@ -98,16 +98,21 @@ def test_gradients_match_dense_block_diagonal(s, k):
                                atol=2e-4, err_msg=f'd{name}')
 
 
-def test_multiblock_skip_grid_parity(monkeypatch):
+@pytest.mark.parametrize('block_q', [64, 128, 256])
+def test_multiblock_skip_grid_parity(monkeypatch, block_q):
   """Force tiny blocks so the grid really has skippable cross-doc tiles
   in forward AND both backward kernels, and verify the skipped result
   still matches the dense reference exactly — the tile-skip predicate
-  must be conservative, never lossy."""
-  monkeypatch.setattr(fa, '_BLOCK_Q', 64)
+  must be conservative, never lossy. With four documents of ~128 tokens
+  the three q blocks give interior tiles (64: whole tiles inside one
+  document, no segment bias), boundary tiles and skipped ones."""
+  monkeypatch.setattr(fa, '_BLOCK_Q', block_q)
   monkeypatch.setattr(fa, '_BLOCK_KV_SEG', 128)
   b, h, s, d = 2, 2, 512, 32
   seg, mask = _ragged_segments(b, s, 4, seed=11)
-  total, skipped = count_skippable_tiles(seg, block_q=64, block_k=128)
+  total, skipped = count_skippable_tiles(seg)
+  assert (total, skipped) == count_skippable_tiles(seg, block_q=block_q,
+                                                    block_k=128)
   assert skipped > 0  # the point of the test: skips actually happen
   q, kk, v = _inputs(b, h, s, d, seed=13)
   segj, maskj = jnp.asarray(seg), jnp.asarray(mask)
@@ -160,6 +165,37 @@ def test_bf16_segmented():
                              rtol=2e-2, atol=2e-2)
 
 
+def test_interior_tiles_leave_out_the_segment_bias(monkeypatch):
+  """A row of two documents cut on a block edge has only interior and
+  skipped tiles: no tile runs the elementwise bias, and the result is
+  still the dense block-diagonal one (forward and gradients); with a
+  padded tail inside the last interior tile, too."""
+  monkeypatch.setattr(fa, '_BLOCK_Q', 128)
+  monkeypatch.setattr(fa, '_BLOCK_KV_SEG', 128)
+  b, h, s, d = 1, 2, 512, 32
+  seg = np.repeat(np.arange(s)[None, :] // 256, b, 0).astype(np.int32)
+  mask = np.ones((b, s), np.int32)
+  seg[:, 490:], mask[:, 490:] = -1, 0
+  total, skipped = count_skippable_tiles(seg)
+  assert (total, skipped) == (16, 8)
+  q, kk, v = _inputs(b, h, s, d, seed=31)
+  segj, maskj = jnp.asarray(seg), jnp.asarray(mask)
+  keep = _real_mask(mask, h, d)
+  cot = jnp.asarray(np.random.default_rng(32).standard_normal(
+      (b, h, s, d), dtype=np.float32)) * jnp.asarray(keep)
+  out = flash_attention(q, kk, v, maskj, segj, segj)
+  ref = _dense_block_diagonal(q, kk, v, maskj, segj)
+  np.testing.assert_allclose(np.asarray(out) * keep, np.asarray(ref) * keep,
+                             rtol=2e-5, atol=2e-5)
+  gf = jax.grad(lambda *a: jnp.sum(flash_attention(*a, maskj, segj, segj) *
+                                   cot), argnums=(0, 1, 2))(q, kk, v)
+  gd = jax.grad(lambda *a: jnp.sum(_dense_block_diagonal(*a, maskj, segj) *
+                                   cot), argnums=(0, 1, 2))(q, kk, v)
+  for a, b_, name in zip(gf, gd, 'qkv'):
+    np.testing.assert_allclose(np.asarray(a), np.asarray(b_), rtol=2e-4,
+                               atol=2e-4, err_msg=f'd{name}')
+
+
 def test_segment_ids_require_pairing():
   q, kk, v = _inputs(1, 1, 64, 32)
   seg = jnp.zeros((1, 64), jnp.int32)
@@ -167,20 +203,57 @@ def test_segment_ids_require_pairing():
     flash_attention(q, kk, v, None, seg, None)
 
 
-def test_count_skippable_tiles():
-  # One doc per row: every tile overlaps itself -> nothing skips.
-  one = np.zeros((2, 2048), np.int32)
-  total, skipped = count_skippable_tiles(one)
-  assert total > 0 and skipped == 0
-  # 16 docs per row at the segmented default blocking: most of the grid
-  # is provably cross-document (the acceptance bar for the packed path).
-  seg, _ = _ragged_segments(2, 2048, 16, seed=3, pad_tail=False)
+def _two_documents(b, s):
+  """Two documents a row, the boundary on no block edge."""
+  return np.repeat(np.where(np.arange(s)[None, :] < int(s * 0.574), 0, 1),
+                   b, 0).astype(np.int32)
+
+
+def _tiles_by_pairs(seg, block_q, block_k):
+  """(total, skipped) from the definition: a tile is live if it holds
+  one same-document pair of real tokens. No intervals, no kernel code."""
+  b, s = seg.shape
+  assert s % block_q == 0 and s % block_k == 0
+  q = seg.reshape(b, s // block_q, 1, block_q, 1)
+  k = seg.reshape(b, 1, s // block_k, 1, block_k)
+  live = ((q == k) & (q >= 0)).any(axis=(3, 4))
+  return live.size, int(live.size - live.sum())
+
+
+@pytest.mark.parametrize('case', [
+    'one-document-s2048', 'sixteen-documents-s8192', 'all-padding',
+    'one-document-s8192', 'two-documents-s8192'])
+def test_count_skippable_tiles(case):
+  """The host's count is the grid's: blocks from the kernels' own
+  ``_tile_blocks``, and on whole rows equal to the count by pairs."""
+  if case == 'all-padding':
+    # All-padding rows skip everything.
+    total, skipped = count_skippable_tiles(np.full((1, 512), -1, np.int32))
+    assert total > 0 and skipped == total
+    return
+  s = 2048 if case.endswith('s2048') else 8192
+  if case.startswith('one-document'):
+    # One doc per row: every tile overlaps itself -> nothing skips.
+    seg = np.zeros((2, s), np.int32)
+  elif case.startswith('two-documents'):
+    seg = _two_documents(2, s)
+  else:
+    seg, _ = _ragged_segments(2, s, 16, seed=3, pad_tail=False)
   total, skipped = count_skippable_tiles(seg)
-  assert skipped / total > 0.5
-  # All-padding rows skip everything.
-  pad = np.full((1, 512), -1, np.int32)
-  total, skipped = count_skippable_tiles(pad)
-  assert skipped == total
+  (block_q, padded_q), (block_k, padded_k) = fa._tile_blocks(s, s, True)
+  assert padded_q == padded_k == s
+  assert total == 2 * (s // block_q) * (s // block_k)
+  assert (total, skipped) == _tiles_by_pairs(seg, block_q, block_k)
+  if case.startswith('one-document'):
+    assert skipped == 0
+  elif case.startswith('two-documents'):
+    # Each document's q blocks skip the other document's kv blocks; the
+    # one q block and the one kv block on the boundary skip nothing.
+    assert 0.25 < skipped / total < 0.5
+  else:
+    # 16 docs per row at the segmented default blocking: most of the grid
+    # is provably cross-document (the acceptance bar for the packed path).
+    assert skipped / total > 0.5
 
 
 def test_ring_flash_matches_dense_block_diagonal():

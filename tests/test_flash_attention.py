@@ -85,6 +85,138 @@ def test_bf16_inputs():
       atol=3e-2)
 
 
+def _dense_as_the_model_rounds(q, k, v, mask, seg=None):
+  """The dense path of ``models/bert.py`` at ``cfg.dtype`` = the inputs'
+  dtype: float32 scores and softmax, probabilities rounded to the
+  inputs' dtype before the second product, context in that dtype."""
+  scale = 1.0 / (q.shape[-1] ** 0.5)
+  s = jnp.einsum('bhqd,bhkd->bhqk', q, k,
+                 preferred_element_type=jnp.float32) * scale
+  bias = jnp.where(mask, 0.0, -1e9)[:, None, None, :]
+  if seg is not None:
+    same = seg[:, None, :, None] == seg[:, None, None, :]
+    bias = bias + jnp.where(same, 0.0, -1e9)
+  p = jax.nn.softmax(s + bias.astype(jnp.float32), axis=-1)
+  return jnp.einsum('bhqk,bhkd->bhqd', p.astype(q.dtype), v)
+
+
+def _two_documents(b, s):
+  """Ids of two documents a row, the boundary on no block edge, and the
+  mask that goes with them (no padding)."""
+  seg = np.where(np.arange(s)[None, :] < int(s * 0.574), 0, 1)
+  return (jnp.asarray(np.repeat(seg, b, 0), jnp.int32),
+          jnp.ones((b, s), jnp.int32))
+
+
+@pytest.mark.parametrize('segmented', [False, True],
+                         ids=['plain', 'two-documents'])
+def test_bf16_gradients_match_dense(monkeypatch, segmented):
+  """bfloat16 operands with float32 sums, the computed operands (p, ds)
+  rounded where the dense path rounds its probabilities: the gradients
+  stay as close to the float32 truth as the dense path's own bfloat16
+  gradients do, over several blocks on both axes."""
+  from lddl_tpu.ops import flash_attention as fa
+  monkeypatch.setattr(fa, '_BLOCK_Q', 128)
+  for cap in ('_BLOCK_KV_FWD', '_BLOCK_KV_BWD', '_BLOCK_KV_SEG'):
+    monkeypatch.setattr(fa, cap, 256)
+  b, h, s, d = 1, 2, 512, 64
+  q, k, v, mask = _inputs(b, h, s, d, seed=21)
+  seg = None
+  if segmented:
+    seg, mask = _two_documents(b, s)
+  cot = jnp.asarray(
+      np.random.default_rng(22).standard_normal(q.shape, dtype=np.float32))
+  cot = cot * jnp.asarray(mask, jnp.float32)[:, None, :, None]
+  qb, kb, vb = (x.astype(jnp.bfloat16) for x in (q, k, v))
+
+  def grads(attend, *operands):
+    return jax.grad(lambda *a: jnp.sum(attend(*a).astype(jnp.float32) * cot),
+                    argnums=(0, 1, 2))(*operands)
+
+  flash = grads(lambda *a: fa.flash_attention(*a, mask, seg, seg), qb, kb, vb)
+  rounded = grads(lambda *a: _dense_as_the_model_rounds(*a, mask, seg),
+                  qb, kb, vb)
+  # The truth: the same bfloat16 values, every step in float32.
+  truth = grads(lambda *a: _dense_as_the_model_rounds(*a, mask, seg),
+                *(x.astype(jnp.float32) for x in (qb, kb, vb)))
+  for got, dense, want, name in zip(flash, rounded, truth, 'qkv'):
+    assert got.dtype == jnp.bfloat16
+    got, dense, want = (np.asarray(x, np.float32) for x in (got, dense, want))
+    norm = np.linalg.norm(want)
+    flash_err = np.linalg.norm(got - want) / norm
+    dense_err = np.linalg.norm(dense - want) / norm
+    assert flash_err < 5e-3, f'd{name}: {flash_err}'
+    assert flash_err < 1.25 * dense_err + 2e-4, (
+        f'd{name}: flash {flash_err} against dense {dense_err}')
+    np.testing.assert_allclose(got, dense, rtol=2e-2, atol=1e-2,
+                               err_msg=f'd{name}')
+
+
+def _kernel_products(dtype, segmented):
+  """{kernel name: [(operand dtypes, dimension numbers), ...]} and the
+  set of all primitives inside the three traced kernels, for a forward
+  and backward pass at ``dtype``."""
+  b, h, s, d = 1, 2, 256, 64
+  x = jnp.ones((b, h, s, d), dtype)
+  seg = jnp.zeros((b, s), jnp.int32) if segmented else None
+
+  def loss(q, k, v):
+    return jnp.sum(flash_attention(q, k, v, None, seg, seg)
+                   .astype(jnp.float32))
+
+  def nested(eqn):
+    for value in eqn.params.values():
+      for item in value if isinstance(value, (list, tuple)) else [value]:
+        item = getattr(item, 'jaxpr', item)
+        if hasattr(item, 'eqns'):
+          yield item
+
+  products, primitives = {}, set()
+
+  def inside(jaxpr, name):
+    for eqn in jaxpr.eqns:
+      primitives.add(eqn.primitive.name)
+      if eqn.primitive.name == 'dot_general':
+        products.setdefault(name, []).append(
+            (tuple(var.aval.dtype.name for var in eqn.invars),
+             eqn.params['dimension_numbers']))
+      for sub in nested(eqn):
+        inside(sub, name)
+
+  def outside(jaxpr):
+    for eqn in jaxpr.eqns:
+      if eqn.primitive.name == 'pallas_call':
+        inside(eqn.params['jaxpr'], eqn.params['name'])
+      else:
+        for sub in nested(eqn):
+          outside(sub)
+
+  outside(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(x, x, x).jaxpr)
+  return products, primitives
+
+
+@pytest.mark.parametrize('segmented', [False, True],
+                         ids=['plain', 'segmented'])
+@pytest.mark.parametrize('dtype', ['bfloat16', 'float32'])
+def test_kernels_feed_the_mxu_the_input_dtype(dtype, segmented):
+  """The mechanism engages: with bfloat16 inputs no product inside
+  flash_fwd / flash_dq / flash_dkv has a float32 operand; with float32
+  inputs every operand is float32 (today's arithmetic); either way every
+  product sums in float32 and no tile is transposed."""
+  products, primitives = _kernel_products(jnp.dtype(dtype), segmented)
+  assert set(products) == {'flash_fwd', 'flash_dq', 'flash_dkv'}
+  per_tile = {'flash_fwd': 2, 'flash_dq': 3, 'flash_dkv': 4}
+  bodies = 2 if segmented else 1  # interior and boundary tiles
+  for name, found in products.items():
+    assert len(found) == per_tile[name] * bodies, (name, found)
+    for operands, (contract, batch) in found:
+      assert operands == (dtype, dtype), (name, operands)
+      assert batch == ((), ())
+      # q.k^T-like (both minor dimensions) or a plain product.
+      assert contract in (((1,), (1,)), ((1,), (0,))), (name, contract)
+  assert 'transpose' not in primitives
+
+
 def test_model_flash_impl_matches_dense():
   from lddl_tpu.models import BertConfig, BertForPretraining
   mk = lambda impl: BertForPretraining(
@@ -196,16 +328,20 @@ def test_block_env_overrides():
   assert out.stdout.split() == ['256', '512', '512']
 
 
+@pytest.mark.parametrize('block_q', [128, 256, 512])
 @pytest.mark.parametrize('caps', [(128, 128), (256, 256)])
-def test_multiblock_kv_grid(monkeypatch, caps):
+def test_multiblock_kv_grid(monkeypatch, caps, block_q):
   """Force the innermost kv grid dimension to take multiple steps (the
-  default caps of 4096/2048 make every CPU-sized test a single step, so
+  default caps of 1024 make every CPU-sized test a single step, so
   the cross-step scratch accumulation — init/rescale/finalize — would
   otherwise go untested). The (256, 256) case also exercises the
   non-divisor overshoot: s=600 pads to 640, which blocks as 256 x 3 =
-  768 with -inf-biased padding columns."""
+  768 with -inf-biased padding columns; the q block does the same on
+  its axis (256: 3 x 256 = 768 with zero query rows sliced away; 512:
+  2 x 384)."""
   from lddl_tpu.ops import flash_attention as fa
   cap_fwd, cap_bwd = caps
+  monkeypatch.setattr(fa, '_BLOCK_Q', block_q)
   monkeypatch.setattr(fa, '_BLOCK_KV_FWD', cap_fwd)
   monkeypatch.setattr(fa, '_BLOCK_KV_BWD', cap_bwd)
   q, k, v, mask = _inputs(1, 2, 600, 64, seed=11)
