@@ -47,8 +47,7 @@ def _sync(out):
 
 def _make_scanned_fwd(fn, n):
   """Chain n applications (each output feeds the next query) inside one
-  jit program, so dispatch and the host sync are paid once per n calls
-  — the same methodology as train_bench --scan-steps.
+  jit program, so dispatch and the host sync are paid once per n calls.
   The data dependency between iterations prevents XLA from removing or
   parallelizing the repeats."""
   import jax

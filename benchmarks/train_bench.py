@@ -1,26 +1,23 @@
-"""Mock-training benchmark harness: consume the loader, measure, verify.
+"""Mock-training harness: consume the loader, measure, verify.
 
-Capability parity with the reference's de-facto integration test
-(``/root/reference/benchmarks/torch_train.py:97-252``) plus the TPU-native
-additions the reference could not have:
+What the reference's mock trainer is for
+(``/root/reference/benchmarks/torch_train.py:97-252``), without a model:
 
-  - ``--mode loader``: pure data-pipeline consumption — per-step latency
-    (avg/min/max after ``--warmup``), samples/s, shape/dtype asserts every
-    step, ``--debug`` raw-batch eyeballing with id→token decoding;
-  - ``--mode train``: the same loader feeding the real
-    :func:`lddl_tpu.parallel.make_train_step` over a device mesh — step
-    latency, samples/s, tokens/s, and **MFU** (analytic model FLOPs from
-    :mod:`lddl_tpu.models.flops` / measured step time / chip peak);
+  - pure data-pipeline consumption — per-step latency (avg/min/max after
+    ``--warmup``), samples/s, shape/dtype asserts every step, ``--debug``
+    raw-batch eyeballing with id→token decoding;
   - per-rank sequence-length stats dumped to ``<seq-len-dir>/lens_<rank>.npz``
     (min/max/batch-size/padded-len per iteration + seq-len and padded-zero
     histograms), the input contract of ``benchmarks/validate_binning.py``
     (reference ``make_training_seqlen_plots.py``).
 
+The train step is the product's: ``python -m lddl_tpu.training.pretrain``
+runs the same shards through ``TrainLoop.run``.
+
 Run from the repo root, e.g.::
 
   python benchmarks/train_bench.py --path balanced/ --vocab-file vocab.txt \
-      --bin-size 64 --batch-size 16 --mode train --model tiny --epochs 1 \
-      --seq-len-dir seqlens/
+      --bin-size 64 --batch-size 16 --epochs 1 --seq-len-dir seqlens/
 """
 
 import argparse
@@ -136,154 +133,6 @@ def debug_print(batch, tokenizer):
         ' '.join(tokenizer.convert_ids_to_tokens(restored.tolist())))
 
 
-MODEL_PRESETS = {
-    # hidden, layers, heads, intermediate
-    'tiny': (128, 2, 2, 512),      # CI / smoke
-    'base': (768, 12, 12, 3072),
-    'large': (1024, 24, 16, 4096),
-}
-
-
-def build_train_state(args, tokenizer):
-  """Model + optimizer + sharded params + jitted step over the mesh."""
-  import jax
-  import optax
-  if getattr(args, 'prng', 'threefry') != 'threefry':
-    jax.config.update('jax_default_prng_impl', args.prng)
-
-  from lddl_tpu.core.compile_cache import use_compile_cache
-  from lddl_tpu.models import BertConfig, BertForPretraining
-  from lddl_tpu.parallel import make_mesh, make_train_step, mesh_summary
-  from lddl_tpu.parallel.train import init_params
-
-  use_compile_cache()
-  hidden, layers, heads, inter = MODEL_PRESETS[args.model]
-  vocab = ((tokenizer.vocab_size + 63) // 64) * 64  # pad for the MXU
-  cfg = BertConfig(
-      vocab_size=vocab,
-      hidden_size=hidden,
-      num_layers=layers,
-      num_heads=heads,
-      intermediate_size=inter,
-      max_position_embeddings=max(args.max_seq_length, 512),
-      attention_impl=args.attention,
-      dropout_rate=args.dropout,
-      fused_qkv=args.fused_qkv,
-      remat=args.remat)
-  model = BertForPretraining(cfg)
-  mesh = make_mesh(data=args.dp, fsdp=args.fsdp, tensor=args.tp,
-                   seq=args.sp)
-  print(f'mesh: {mesh_summary(mesh)}; devices={len(jax.devices())} '
-        f'({jax.devices()[0].device_kind})')
-  if args.max_predictions is not None:
-    from lddl_tpu.parallel.train import check_max_predictions
-    check_max_predictions(args.max_predictions, args.max_seq_length,
-                          args.masking,
-                          mlm_probability=args.mlm_probability)
-  tx = optax.adamw(1e-4)
-  params = init_params(model, mesh, jax.random.key(args.seed),
-                       seq_len=min(128, args.max_seq_length))
-  opt_state = jax.jit(
-      tx.init, out_shardings=None)(params)
-  step = make_train_step(model, tx, mesh,
-                         max_predictions=args.max_predictions)
-  return cfg, mesh, model, tx, step, params, opt_state
-
-
-def run_scan(args, loader, tokenizer):
-  """``--scan-steps K``: jit K train steps into ONE program (``lax.scan``
-  over a device-resident batch window) so per-step dispatch and host
-  sync cost is paid once per window. Collects K same-shape batches from
-  the real loader, stacks them on device, then times ``--scan-windows``
-  window executions.
-  """
-  import jax
-
-  from lddl_tpu.models.flops import (bert_pretrain_flops_per_step,
-                                     peak_flops_per_device)
-  from lddl_tpu.parallel import make_scan_train_step, stack_batch_window
-
-  cfg, mesh, model, tx, _, params, opt_state = build_train_state(
-      args, tokenizer)
-  k = args.scan_steps
-  # K batches of one static shape (whichever bin shape fills first wins,
-  # unless --scan-seq-len pins a specific bin's padded length — e.g. 512
-  # for a phase-2 datapoint, which short-pair bins would otherwise
-  # outrace).
-  by_shape = {}
-  batches = None
-  for batch in loader:
-    check_batch(batch)
-    if (args.scan_seq_len and
-        batch['input_ids'].shape[1] != args.scan_seq_len):
-      continue
-    group = by_shape.setdefault(batch['input_ids'].shape, [])
-    group.append(batch)
-    if len(group) == k:
-      batches = group
-      break
-  if batches is None:
-    best = max(by_shape.values(), key=len, default=[])
-    hint = ('no batch matched --scan-seq-len '
-            f'{args.scan_seq_len} (check the dataset has that bin); '
-            if args.scan_seq_len and not by_shape else '')
-    raise SystemExit(
-        f'no bin yielded {k} batches (best: {len(best)}); {hint}lower '
-        '--scan-steps or use a bigger dataset')
-  shape = batches[0]['input_ids'].shape
-  window = stack_batch_window(batches, mesh)
-  b, s = shape
-  scan = make_scan_train_step(model, tx, mesh,
-                              max_predictions=args.max_predictions)
-  rng = jax.random.key(args.seed + 1)
-
-  t0 = time.perf_counter()
-  params, opt_state, metrics = scan(params, opt_state, rng, window)
-  loss = float(metrics['loss'])  # device->host read: waits for the window
-  compile_s = time.perf_counter() - t0
-
-  n_dev = len(jax.devices())
-  peak = (args.peak_tflops * 1e12 if args.peak_tflops else
-          peak_flops_per_device())
-  flops_per_step = bert_pretrain_flops_per_step(
-      cfg, b, s, max_predictions=args.max_predictions)
-  times = []
-  # Shared capture path with the live /profile endpoint (same output
-  # layout); no-op when --profile-dir is unset.
-  from lddl_tpu.telemetry.profiling import trace_capture
-  with trace_capture(args.profile_dir):
-    for _ in range(args.scan_windows):
-      t0 = time.perf_counter()
-      params, opt_state, metrics = scan(params, opt_state, rng, window)
-      loss = float(metrics['loss'])
-      times.append(time.perf_counter() - t0)
-  # Median window: robust against an outlier window in either direction.
-  med_step = sorted(times)[len(times) // 2] / k
-  avg_step = sum(times) / len(times) / k
-  summary = {
-      'mode': 'train-scan',
-      'model': args.model,
-      'batch': b,
-      'seq_len': s,
-      'scan_steps': k,
-      'windows': args.scan_windows,
-      'compile_seconds': round(compile_s, 2),
-      'avg_latency_ms': round(avg_step * 1e3, 3),
-      'median_latency_ms': round(med_step * 1e3, 3),
-      'min_latency_ms': round(min(times) / k * 1e3, 3),
-      'samples_per_sec': round(b / med_step, 2),
-      'tokens_per_sec': round(b * s / med_step, 1),
-      'model_tflops_per_sec': round(flops_per_step / med_step / 1e12, 3),
-      'remat': bool(args.remat),
-      'devices': n_dev,
-      'loss': round(loss, 4),
-  }
-  if peak:  # None only on the CPU backend without --peak-tflops
-    summary['mfu'] = round(flops_per_step / med_step / (peak * n_dev), 6)
-  print(json.dumps(summary))
-  return summary
-
-
 def run(args):
   import lddl_tpu  # noqa: F401  (PYTHONPATH check before heavy imports)
   from lddl_tpu.loader import get_bert_pretrain_data_loader
@@ -292,8 +141,8 @@ def run(args):
   if args.dp_world_size == 1:
     # Multi-host pod run with defaults: each process feeds its own dp
     # shard and dumps its own lens_<rank>.npz (the reference derives the
-    # same from the launcher env; torch_train.py:98-104). Applies to both
-    # modes — a loader-mode pod run otherwise duplicates data per host.
+    # same from the launcher env; torch_train.py:98-104): a pod run
+    # otherwise duplicates data per host.
     import jax
     if jax.process_count() > 1:
       args.dp_rank = jax.process_index()
@@ -324,112 +173,60 @@ def run(args):
       log_dir=args.log_dir,
       log_level=getattr(logging, args.log_level))
 
-  if args.mode == 'train' and args.scan_steps:
-    return run_scan(args, loader, tokenizer)
-
   iters_per_epoch = min(len(loader), args.iters_per_epoch)
   stats = SeqlenStats(args.epochs, iters_per_epoch)
   meter = StepMeter(warmup=args.warmup)
   data_meter = StepMeter(warmup=args.warmup)
 
-  train = args.mode == 'train'
-  if train:
-    import jax
-
-    from lddl_tpu.loader.device import prefetch_to_device
-    from lddl_tpu.models.flops import (bert_pretrain_flops_per_step,
-                                       peak_flops_per_device)
-    cfg, mesh, _, _, step, params, opt_state = build_train_state(
-        args, tokenizer)
-    rng = jax.random.key(args.seed + 1)
-    peak = (args.peak_tflops * 1e12 if args.peak_tflops else
-            peak_flops_per_device())
-    n_dev = len(jax.devices())
-
   summary = {}
   for epoch in range(args.epochs):
     total_samples = 0
     total_tokens = 0
-    total_model_flops = 0.0
     epoch_start = time.perf_counter()
     epoch_before = loader.epoch
-    it = iter(loader)
-    stream = enumerate(it)
-    if train:
-      # Overlap host collate with device compute; stats/checks run on the
-      # host copy before transfer.
-      def _tee(src):
-        for i, b in src:
-          check_batch(b)
-          if i < iters_per_epoch:  # prefetch may read past the cutoff
-            stats.record(epoch, i, b)
-          yield b
-
-      device_stream = prefetch_to_device(
-          _tee(stream), mesh=mesh, size=args.prefetch)
+    stream = iter(loader)
 
     t0 = time.perf_counter()
     for i in range(iters_per_epoch):
-      if train:
-        t_data = time.perf_counter()
-        try:
-          batch = next(device_stream)
-        except StopIteration:
-          break
-        data_meter.update(time.perf_counter() - t_data)
-        params, opt_state, metrics = step(params, opt_state, rng, batch)
-        jax.block_until_ready(metrics['loss'])
-        b, s = batch['input_ids'].shape
-        total_model_flops += bert_pretrain_flops_per_step(
-            cfg, b, s, max_predictions=args.max_predictions)
-      else:
-        t_data = time.perf_counter()
-        try:
-          _, batch = next(stream)
-        except StopIteration:
-          break
-        data_meter.update(time.perf_counter() - t_data)
-        check_batch(batch)
-        stats.record(epoch, i, batch)
-        b, s = batch['input_ids'].shape
+      t_data = time.perf_counter()
+      try:
+        batch = next(stream)
+      except StopIteration:
+        break
+      data_meter.update(time.perf_counter() - t_data)
+      check_batch(batch)
+      stats.record(epoch, i, batch)
+      b, s = batch['input_ids'].shape
       elapsed = time.perf_counter() - t0
       t0 = time.perf_counter()
       meter.update(elapsed)
       if meter.iters <= args.warmup:
         # Keep the rate numerators aligned with the measured denominator
-        # (meter.total excludes warmup/compile steps).
-        if train:
-          total_model_flops = 0.0
+        # (meter.total excludes warmup steps).
         total_samples = 0
         total_tokens = 0
       else:
         total_samples += b
         total_tokens += b * s
       if (i + 1) % args.log_freq == 0:
-        line = (f'epoch={epoch} iter={i + 1}/{iters_per_epoch} '
-                f'latency(ms) last={elapsed * 1e3:.1f} '
-                f'avg={meter.avg * 1e3:.1f} min={meter.min * 1e3:.1f} '
-                f'max={meter.max * 1e3:.1f} '
-                f'samples/s={total_samples / max(meter.total, 1e-9):.1f}')
-        if train:
-          line += f" loss={float(metrics['loss']):.4f}"
-        print(line)
+        print(f'epoch={epoch} iter={i + 1}/{iters_per_epoch} '
+              f'latency(ms) last={elapsed * 1e3:.1f} '
+              f'avg={meter.avg * 1e3:.1f} min={meter.min * 1e3:.1f} '
+              f'max={meter.max * 1e3:.1f} '
+              f'samples/s={total_samples / max(meter.total, 1e-9):.1f}')
         if args.debug:
           debug_print(batch, tokenizer)
 
     # An --iters-per-epoch cutoff can leave the loader generator short of
-    # its final yield, where it advances its epoch counter. Quiesce the
-    # prefetch producer (close() joins it), then pin the epoch to exactly
-    # before+1 — an unconditional assignment, so it is correct whether or
-    # not the generator got to its own increment.
-    if train:
-      device_stream.close()
+    # its final yield, where it advances its epoch counter. Pin the epoch
+    # to exactly before+1 — an unconditional assignment, so it is correct
+    # whether or not the generator got to its own increment.
     loader.epoch = epoch_before + 1
 
     epoch_elapsed = time.perf_counter() - epoch_start
     measured = max(meter.total, 1e-9)
     summary = {
-        'mode': args.mode,
+        'mode': 'loader',
         'epoch': epoch,
         'iters': meter.iters,
         'epoch_seconds': round(epoch_elapsed, 3),
@@ -440,13 +237,6 @@ def run(args):
         'samples_per_sec': round(total_samples / measured, 2),
         'tokens_per_sec': round(total_tokens / measured, 1),
     }
-    if train:
-      summary['model_tflops_per_sec'] = round(
-          total_model_flops / measured / 1e12, 6)
-      if peak:
-        summary['mfu'] = round(total_model_flops / measured / (peak * n_dev),
-                               6)
-      summary['devices'] = n_dev
     print(json.dumps(summary))
     meter.reset()
     data_meter.reset()
@@ -462,8 +252,6 @@ def run(args):
 def attach_args(parser):
   parser.add_argument('--path', required=True,
                       help='balanced shard directory')
-  parser.add_argument('--mode', choices=['loader', 'train'],
-                      default='loader')
   parser.add_argument('--vocab-file', default=None)
   parser.add_argument('--tokenizer', default=None,
                       help='hub tokenizer name when no --vocab-file')
@@ -478,8 +266,7 @@ def attach_args(parser):
   parser.add_argument('--epochs', type=int, default=1)
   parser.add_argument('--iters-per-epoch', type=int, default=10**9)
   parser.add_argument('--warmup', type=int, default=2,
-                      help='steps excluded from latency aggregates '
-                           '(compile steps)')
+                      help='steps excluded from latency aggregates')
   parser.add_argument('--num-workers', type=int, default=0,
                       help='collate in this many worker processes '
                            '(byte-identical output; 0 = in-process)')
@@ -494,59 +281,10 @@ def attach_args(parser):
   parser.add_argument('--log-level', default='WARNING',
                       choices=['CRITICAL', 'ERROR', 'WARNING', 'INFO',
                                'DEBUG'])
-  parser.add_argument('--profile-dir', default=None,
-                      help='write a jax.profiler trace of the measured '
-                           'scan windows here (view with TensorBoard or '
-                           'xprof) — device-time ground truth for the '
-                           'MFU numbers')
   parser.add_argument('--seq-len-dir', default=None,
                       help='dump per-rank lens_<rank>.npz here')
   parser.add_argument('--debug', action='store_true',
                       help='decode + print raw batches at each log step')
-  # train mode
-  parser.add_argument('--model', choices=sorted(MODEL_PRESETS),
-                      default='base')
-  parser.add_argument('--dp', type=int, default=1)
-  parser.add_argument('--fsdp', type=int, default=1)
-  parser.add_argument('--tp', type=int, default=1)
-  parser.add_argument('--sp', type=int, default=1)
-  parser.add_argument('--prefetch', type=int, default=2)
-  parser.add_argument('--scan-steps', type=int, default=0,
-                      help='train mode: jit this many steps into one '
-                           'program (lax.scan over a device-resident '
-                           'window) so dispatch cost amortizes; 0 = '
-                           'one program per step')
-  parser.add_argument('--scan-windows', type=int, default=8,
-                      help='timed window executions in --scan-steps mode')
-  parser.add_argument('--scan-seq-len', type=int, default=None,
-                      help='collect the scan window from the bin with this '
-                           'padded sequence length instead of the first '
-                           'bin to fill (e.g. 512 for a phase-2 row)')
-  parser.add_argument('--peak-tflops', type=float, default=None,
-                      help='override per-chip peak bf16 TFLOP/s for MFU')
-  parser.add_argument('--attention', default='dense',
-                      choices=['dense', 'flash', 'ring', 'ring_flash'],
-                      help='attention implementation (flash: Pallas '
-                           'blockwise kernel, no s^2 score tensor)')
-  parser.add_argument('--max-predictions', type=int, default=None,
-                      help='masked-only MLM head: gather this many MLM '
-                           'positions per row before the vocab projection '
-                           '(honest FLOPs accounting follows); None = '
-                           'full-sequence head')
-  parser.add_argument('--fused-qkv', action='store_true',
-                      help='single [d,3d] QKV projection (see '
-                      'BertConfig.fused_qkv)')
-  parser.add_argument('--prng', default='threefry',
-                      choices=['threefry', 'rbg'],
-                      help="jax PRNG impl; 'rbg' makes per-step dropout "
-                      'draws ~free on TPU (weaker statistical guarantees '
-                      'than threefry, fine for dropout)')
-  parser.add_argument('--dropout', type=float, default=0.1,
-                      help='model dropout rate (0 disables the per-step '
-                      'RNG draws entirely)')
-  parser.add_argument('--remat', action='store_true',
-                      help='rematerialize layer activations (trade FLOPs '
-                           'for HBM; lets bigger batches fit)')
   return parser
 
 

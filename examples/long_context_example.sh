@@ -3,8 +3,9 @@
 # document-packed id rows) -> balance -> BERT pretraining with flash
 # attention on those rows. No reference counterpart — the reference's
 # data path tops out at seq-512 NSP pairs; this is the workflow behind
-# the s=8k-32k single-chip and ring-attention capabilities
-# (benchmarks/results/long_context_packed_v5e.txt measured it on a v5e).
+# the s=8k-32k single-chip and ring-attention capabilities (the
+# benchmark's cell bert-base-pos8k.packed-s8k-longdoc measures it on a
+# v5e: PERF.md).
 #
 # Usage:
 #   bash examples/long_context_example.sh [workdir]

@@ -66,13 +66,25 @@ python -m lddl_tpu.cli balance_shards \
   --outdir "${workdir}/balanced" \
   --num-shards ${num_shards}
 
-# 4. Mock training: every host feeds its dp shard of the global batch;
-#    the mesh spans all chips of the slice.
+# 4. Mock training: every host consumes its dp shard of the global batch
+#    once and dumps its sequence lengths (benchmarks/validate_binning.py
+#    --in-dir "${workdir}/seqlens" checks them once every host has written).
 python "${repo}/benchmarks/train_bench.py" \
   --path "${workdir}/balanced" \
   --vocab-file "${workdir}/vocab.txt" \
-  --mode train \
   --bin-size ${bin_size} \
   --max-seq-length ${target_seq_length} \
   --masking static \
   --seq-len-dir "${workdir}/seqlens"
+
+# 5. Train: the product's loop on the same shards; every host feeds its
+#    dp shard of the global batch, the mesh spans all chips of the slice.
+python -m lddl_tpu.training.pretrain \
+  --comm jax \
+  --path "${workdir}/balanced" \
+  --vocab-file "${workdir}/vocab.txt" \
+  --model base \
+  --bin-size ${bin_size} \
+  --max-seq-length ${target_seq_length} \
+  --masking static \
+  --checkpoint-dir "${workdir}/checkpoints"

@@ -10,10 +10,12 @@ next_sentence_labels). Design choices for the MXU/XLA:
     rematerialization to trade FLOPs for HBM;
   - static shapes everywhere — the loader's per-bin padding means one
     compiled program per bin;
-  - attention is pluggable: 'dense' (XLA fuses the softmax chain; GSPMD
-    inserts collectives if heads/seq are sharded), 'flash' (Pallas
+  - attention is pluggable through ``BertConfig.attention_impl``;
+    :func:`lddl_tpu.ops.attention.attend` is the one place that knows
+    the back-ends: 'dense' (XLA fuses the softmax chain; GSPMD inserts
+    collectives if heads/seq are sharded), 'flash' (Pallas
     blockwise-softmax kernel, :mod:`lddl_tpu.ops.flash_attention` — no
-    O(s^2) score materialization), or 'ring'
+    O(s^2) score materialization), or 'ring' / 'ring_flash'
     (:mod:`lddl_tpu.parallel.ring`) for sequence-parallel long context;
   - tied MLM decoder (logits against the word-embedding table), vocab
     sharded over the ``tensor`` axis.
@@ -32,6 +34,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from ..ops.attention import attend
+
 
 @dataclasses.dataclass(frozen=True)
 class BertConfig:
@@ -44,12 +48,8 @@ class BertConfig:
   type_vocab_size: int = 2
   dropout_rate: float = 0.1
   dtype: Any = jnp.bfloat16
-  attention_impl: str = 'dense'  # 'dense' | 'flash' | 'ring' | 'ring_flash'
+  attention_impl: str = 'dense'  # one of ops.attention.ATTENTION_IMPLS
   remat: bool = False
-  # One [d, 3d] projection instead of three [d, d] gemms — fewer, larger
-  # MXU calls (opt-in: changes the param tree, so checkpoints are not
-  # interchangeable with the unfused layout).
-  fused_qkv: bool = False
 
   @property
   def head_dim(self):
@@ -75,53 +75,14 @@ class SelfAttention(nn.Module):
     cfg, deterministic = self.cfg, self.deterministic
     b, s, _ = x.shape
     heads, hd = cfg.num_heads, cfg.head_dim
-    if cfg.fused_qkv:
-      qkv = _dense(3 * cfg.hidden_size, cfg, 'qkv')(x)
-      q, k, v = jnp.split(qkv, 3, axis=-1)
-    else:
-      q = _dense(cfg.hidden_size, cfg, 'query')(x)
-      k = _dense(cfg.hidden_size, cfg, 'key')(x)
-      v = _dense(cfg.hidden_size, cfg, 'value')(x)
+    q = _dense(cfg.hidden_size, cfg, 'query')(x)
+    k = _dense(cfg.hidden_size, cfg, 'key')(x)
+    v = _dense(cfg.hidden_size, cfg, 'value')(x)
     q = q.reshape(b, s, heads, hd).transpose(0, 2, 1, 3)
     k = k.reshape(b, s, heads, hd).transpose(0, 2, 1, 3)
     v = v.reshape(b, s, heads, hd).transpose(0, 2, 1, 3)
-    if cfg.attention_impl in ('ring', 'ring_flash') and self.mesh is not None:
-      from ..parallel.ring import make_ring_attention
-      block_impl = 'flash' if cfg.attention_impl == 'ring_flash' else 'dense'
-      attend = make_ring_attention(self.mesh, block_impl=block_impl,
-                                   with_segment_ids=segment_ids is not None)
-      if segment_ids is not None:
-        ctx = attend(q, k, v, attention_mask, segment_ids)
-      else:
-        ctx = attend(q, k, v, attention_mask)
-    elif cfg.attention_impl in ('flash', 'ring_flash'):
-      # ring_flash without a mesh degenerates to single-chip flash.
-      from ..ops.flash_attention import (flash_attention,
-                                         make_flash_attention)
-      if self.mesh is not None:
-        attend = make_flash_attention(
-            self.mesh, with_segment_ids=segment_ids is not None)
-        if segment_ids is not None:
-          ctx = attend(q, k, v, attention_mask, segment_ids)
-        else:
-          ctx = attend(q, k, v, attention_mask)
-      else:
-        ctx = flash_attention(q, k, v, attention_mask, segment_ids,
-                              segment_ids)
-    else:
-      scale = 1.0 / (hd ** 0.5)
-      scores = jnp.einsum(
-          'bhqd,bhkd->bhqk', q, k,
-          preferred_element_type=jnp.float32) * scale
-      bias = jnp.where(attention_mask, 0.0, -1e9)[:, None, None, :]
-      if segment_ids is not None:
-        # Same block-diagonal semantics as the flash tile skip — this
-        # additive form keeps flash-vs-dense parity testable on CPU.
-        same_doc = (segment_ids[:, None, :, None] ==
-                    segment_ids[:, None, None, :])
-        bias = bias + jnp.where(same_doc, 0.0, -1e9)
-      probs = jax.nn.softmax(scores + bias.astype(jnp.float32), axis=-1)
-      ctx = jnp.einsum('bhqk,bhkd->bhqd', probs.astype(cfg.dtype), v)
+    ctx = attend(q, k, v, attention_mask, segment_ids,
+                 impl=cfg.attention_impl, mesh=self.mesh, dtype=cfg.dtype)
     ctx = ctx.transpose(0, 2, 1, 3).reshape(b, s, cfg.hidden_size)
     out = _dense(cfg.hidden_size, cfg, 'out')(ctx)
     return nn.Dropout(cfg.dropout_rate)(out, deterministic=deterministic)
@@ -254,8 +215,6 @@ _RULES = (
     ('word_embeddings/embedding', ('tensor', 'fsdp')),
     ('position_embeddings/embedding', (None, None)),
     ('token_type_embeddings/embedding', (None, None)),
-    ('qkv/kernel', ('fsdp', 'tensor')),
-    ('qkv/bias', ('tensor',)),
     ('query/kernel', ('fsdp', 'tensor')),
     ('key/kernel', ('fsdp', 'tensor')),
     ('value/kernel', ('fsdp', 'tensor')),

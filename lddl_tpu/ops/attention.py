@@ -1,0 +1,56 @@
+"""The one place that knows which attention back-ends exist.
+
+``attend`` is what :class:`lddl_tpu.models.bert.SelfAttention` calls on
+its ``[batch, heads, seq, head_dim]`` projections; a term every back-end
+must honour (the padding mask and the same-document restriction today)
+is added here and nowhere else. The kernels themselves stay in
+:mod:`lddl_tpu.ops.flash_attention` and :mod:`lddl_tpu.parallel.ring`,
+imported only by the branch that runs them.
+"""
+
+import jax
+import jax.numpy as jnp
+
+ATTENTION_IMPLS = ('dense', 'flash', 'ring', 'ring_flash')
+
+
+def attend(q, k, v, attention_mask, segment_ids, *, impl, mesh, dtype):
+  """Context ``[batch, heads, seq, head_dim]`` of softmax attention.
+
+  ``attention_mask`` bool ``[batch, seq]`` masks padding keys;
+  ``segment_ids`` int32 ``[batch, seq]`` (doc index per token, -1 =
+  padding) or None restricts attention to same-document pairs.
+
+  ``impl`` is one of :data:`ATTENTION_IMPLS`. 'ring' and 'ring_flash'
+  need a ``mesh`` (the sequence is sharded over its ``seq`` axis, each
+  chip's block dense or flash); without one there is no ring to rotate
+  and they run as 'dense' and 'flash'. 'flash' with a mesh runs the
+  Pallas kernel under ``shard_map`` (batch over data/fsdp, heads over
+  tensor). 'dense' leaves the partitioning to GSPMD and rounds its
+  probabilities to ``dtype`` before the product with ``v``.
+  """
+  if impl not in ATTENTION_IMPLS:
+    raise ValueError(f'unknown attention impl {impl!r}: expected one of '
+                     f'{ATTENTION_IMPLS}')
+  if impl in ('ring', 'ring_flash') and mesh is not None:
+    from ..parallel.ring import make_ring_attention
+    block_impl = 'flash' if impl == 'ring_flash' else 'dense'
+    return make_ring_attention(mesh, block_impl=block_impl)(
+        q, k, v, attention_mask, segment_ids)
+  if impl in ('flash', 'ring_flash'):
+    from .flash_attention import flash_attention, make_flash_attention
+    if mesh is not None:
+      return make_flash_attention(mesh)(q, k, v, attention_mask, segment_ids)
+    return flash_attention(q, k, v, attention_mask, segment_ids, segment_ids)
+  scale = 1.0 / (q.shape[-1] ** 0.5)
+  scores = jnp.einsum(
+      'bhqd,bhkd->bhqk', q, k, preferred_element_type=jnp.float32) * scale
+  bias = jnp.where(attention_mask, 0.0, -1e9)[:, None, None, :]
+  if segment_ids is not None:
+    # Same block-diagonal semantics as the flash tile skip — this
+    # additive form keeps flash-vs-dense parity testable on CPU.
+    same_doc = (segment_ids[:, None, :, None] ==
+                segment_ids[:, None, None, :])
+    bias = bias + jnp.where(same_doc, 0.0, -1e9)
+  probs = jax.nn.softmax(scores + bias.astype(jnp.float32), axis=-1)
+  return jnp.einsum('bhqk,bhkd->bhqd', probs.astype(dtype), v)

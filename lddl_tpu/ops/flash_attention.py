@@ -1,7 +1,7 @@
 """Flash attention as a Pallas TPU kernel (forward + backward).
 
 The attention score matrix is the one O(s^2) memory object in BERT-style
-training; XLA materializes it per layer (``models/bert.py`` dense path).
+training; XLA materializes it per layer (``ops/attention.py`` dense path).
 This kernel never does: softmax runs online over key blocks with a
 running (max, sum, accumulator) in VMEM, so per-core attention memory is
 O(block^2) regardless of sequence length, and the backward pass
@@ -31,7 +31,7 @@ Arithmetic: the MXU takes q, k, v and dO tiles in the dtype they arrive
 in and sums in float32; the four products with a computed operand
 (p.v, p^T.dO, dS.k, dS^T.q) round that operand to the inputs' dtype just
 before the product, where the dense path rounds its probabilities
-(``models/bert.py``: ``probs.astype(cfg.dtype)``). Scores, the running
+(``ops/attention.py``: ``probs.astype(dtype)``). Scores, the running
 max and sum, ``exp``, ``lse``, ``delta``, dS and the accumulators are
 float32. A model built in float32 therefore gets float32 operands
 throughout. No product transposes a tile: q.k^T contracts the minor
@@ -40,11 +40,11 @@ dimension of both operands, and dk/dv keep their tile key-major.
 Masking: a key-side additive bias ``[batch, seq]`` (0 = attend, -1e9 =
 padding) — the same semantics as the dense path and the ring
 (:mod:`lddl_tpu.parallel.ring`) path. Ring composes with this kernel
-(``ring_attention(block_impl='flash')`` /
-``BertConfig(attention_impl='ring_flash')``): ring shards the sequence
-across chips and rotates K/V, each chip's local block runs here via
-:func:`flash_attention_with_lse`, and the (out, lse) pair enters ring's
-streaming-softmax merge exactly.
+(``ring_attention(block_impl='flash')``, which
+:func:`lddl_tpu.ops.attention.attend` runs for ``'ring_flash'``): ring
+shards the sequence across chips and rotates K/V, each chip's local
+block runs here via :func:`flash_attention_with_lse`, and the (out, lse)
+pair enters ring's streaming-softmax merge exactly.
 
 Block-diagonal packed attention: optional per-token ``segment_ids``
 (doc index per token, -1 = padding — the packed loader derives them
@@ -73,7 +73,6 @@ fallback for an accelerator that announces itself under another name.
 """
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -121,17 +120,11 @@ def _padded_len(s):
 # intermediates (scores, p, dp, ds: 4 MB each at [1024 x 1024]) compile
 # inside Mosaic's default scoped VMEM limit, so no ``vmem_limit_bytes`` is
 # set; kv blocks of 2048 bought nothing more.
-# The four values are *caps*: a sequence shorter than a cap takes one
-# block of its own (padded) length. Env overrides
-# (LDDL_FLASH_BLOCK_{Q,KV_FWD,KV_BWD,KV_SEG}) retune a shape without a
-# code edit.
-_BLOCK_Q = int(os.environ.get('LDDL_FLASH_BLOCK_Q', 1024))
-_BLOCK_KV_FWD = int(os.environ.get('LDDL_FLASH_BLOCK_KV_FWD', 1024))
-_BLOCK_KV_BWD = int(os.environ.get('LDDL_FLASH_BLOCK_KV_BWD', 1024))
-# Segmented (block-diagonal) runs: a tile can only skip whole, so the
-# tile IS the skip granularity, and this is the cap that traffic of very
-# many very short documents a row would lower.
-_BLOCK_KV_SEG = int(os.environ.get('LDDL_FLASH_BLOCK_KV_SEG', 1024))
+# The two values are *caps*: a sequence shorter than a cap takes one
+# block of its own (padded) length. With segment ids a tile can only skip
+# whole, so the tile is also the skip granularity.
+_BLOCK_Q = 1024
+_BLOCK_KV = 1024
 
 
 def _blocking(s_pad, cap):
@@ -150,17 +143,13 @@ def _blocking(s_pad, cap):
   return block, block * n_steps
 
 
-def _tile_blocks(s_q, s_kv, segmented, backward=False):
+def _tile_blocks(s_q, s_kv):
   """((block_q, padded_q), (block_k, padded_kv)) of one kernel launch,
-  from static facts alone: the two (already ``_padded_len``-ed) sequence
-  lengths, whether segment ids came along, and which pass it is. The one
-  place a tile shape is chosen: the forward and backward launches and the
-  host's ``count_skippable_tiles`` all ask here, so the count of tiles
-  the host reports cannot drift from the grid the chip runs."""
-  kv_cap = _BLOCK_KV_BWD if backward else _BLOCK_KV_FWD
-  if segmented:
-    kv_cap = min(kv_cap, _BLOCK_KV_SEG)
-  return _blocking(s_q, _BLOCK_Q), _blocking(s_kv, kv_cap)
+  from the two (already ``_padded_len``-ed) sequence lengths alone. The
+  one place a tile shape is chosen: the forward and backward launches and
+  the host's ``count_skippable_tiles`` all ask here, so the count of
+  tiles the host reports cannot drift from the grid the chip runs."""
+  return _blocking(s_q, _BLOCK_Q), _blocking(s_kv, _BLOCK_KV)
 
 
 def _pad_to(x, axis, length, value=0.0):
@@ -254,7 +243,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, qseg_ref, kseg_ref,
     m_ref[...] = m_new
     l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
     # p is rounded to the input's dtype where the dense path rounds its
-    # probabilities (models/bert.py); float32 inputs keep it float32.
+    # probabilities (ops/attention.py); float32 inputs keep it float32.
     acc_ref[...] = acc_ref[...] * alpha + _mxu(p.astype(v_blk.dtype), v_blk,
                                                (1, 0))
 
@@ -379,8 +368,7 @@ def _flash_pair(q, k, v, bias, q_seg, kv_seg, heads):
 
 def _flash_fwd_impl(q, k, v, bias, q_seg, kv_seg, heads):
   bh, s_q, d = q.shape
-  (block_q, padded_q), (block_k, padded_kv) = _tile_blocks(
-      s_q, k.shape[1], q_seg is not None)
+  (block_q, padded_q), (block_k, padded_kv) = _tile_blocks(s_q, k.shape[1])
   # Whole blocks on both axes: zero query rows (segment id -1) are
   # sliced away below, padded keys are masked by their bias.
   q = _pad_to(q, 1, padded_q)
@@ -430,8 +418,7 @@ def _flash_bwd(heads, res, cotangents):
   bh, s_q, d = q.shape
   s_kv = k.shape[1]
   segmented = q_seg is not None
-  (block_q, padded_q), (block_k, padded_kv) = _tile_blocks(
-      s_q, s_kv, segmented, backward=True)
+  (block_q, padded_q), (block_k, padded_kv) = _tile_blocks(s_q, s_kv)
   g = g.astype(q.dtype)
   # d(out)/dS = P(delta-terms); d(lse)/dS = P — so an lse cotangent folds
   # into the shared (dp - delta) factor as delta -= g_lse.
@@ -613,7 +600,7 @@ def count_skippable_tiles(segment_ids, block_q=None, block_k=None):
   telemetry counters and the benchmark skip-fraction columns."""
   if block_q is None or block_k is None:
     s_pad = _padded_len(int(segment_ids.shape[1]))
-    (grid_q, _), (grid_k, _) = _tile_blocks(s_pad, s_pad, segmented=True)
+    (grid_q, _), (grid_k, _) = _tile_blocks(s_pad, s_pad)
     block_q, block_k = block_q or grid_q, block_k or grid_k
   import numpy as np
   seg = np.asarray(segment_ids)
@@ -625,8 +612,7 @@ def count_skippable_tiles(segment_ids, block_q=None, block_k=None):
   return total, total - int(live.sum())
 
 
-def make_flash_attention(mesh, q_spec=None, mask_spec=None,
-                         with_segment_ids=False):
+def make_flash_attention(mesh, q_spec=None, mask_spec=None):
   """Wrap :func:`flash_attention` in ``shard_map`` for jitted use over a
   mesh: batch over (data, fsdp), heads over tensor — a ``pallas_call``
   has no GSPMD partitioning rule, so without this the compiler would
@@ -634,9 +620,9 @@ def make_flash_attention(mesh, q_spec=None, mask_spec=None,
   (flash is per-chip block math; sequence sharding is ring attention's
   job — use ``attention_impl='ring_flash'`` for both).
 
-  ``with_segment_ids=True`` returns a wrapper taking an extra
-  ``segment_ids`` ``[batch, seq]`` operand (used for both q and kv —
-  self-attention), sharded like the mask.
+  The wrapper takes ``(q, k, v, mask, segment_ids)``; ``segment_ids``
+  ``[batch, seq]`` (used for both q and kv — self-attention) is sharded
+  like the mask, or is None for full attention.
   """
   from jax.sharding import PartitionSpec as P
 
@@ -650,25 +636,14 @@ def make_flash_attention(mesh, q_spec=None, mask_spec=None,
   q_spec = q_spec or P(batch_axes, head_axis, None, None)
   mask_spec = mask_spec or P(batch_axes, None)
 
-  if with_segment_ids:
-    @functools.partial(
-        jax.shard_map,
-        mesh=mesh,
-        in_specs=(q_spec, q_spec, q_spec, mask_spec, mask_spec),
-        out_specs=q_spec,
-        check_vma=False)
-    def _sharded_seg(q, k, v, mask, segment_ids):
-      return flash_attention(q, k, v, mask, segment_ids, segment_ids)
-
-    return _sharded_seg
-
+  # A None operand is an empty pytree: its spec binds to no array.
   @functools.partial(
       jax.shard_map,
       mesh=mesh,
-      in_specs=(q_spec, q_spec, q_spec, mask_spec),
+      in_specs=(q_spec, q_spec, q_spec, mask_spec, mask_spec),
       out_specs=q_spec,
       check_vma=False)
-  def _sharded(q, k, v, mask):
-    return flash_attention(q, k, v, mask)
+  def _sharded(q, k, v, mask, segment_ids):
+    return flash_attention(q, k, v, mask, segment_ids, segment_ids)
 
   return _sharded

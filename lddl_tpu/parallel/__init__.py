@@ -11,14 +11,12 @@ collectives over ICI, and ring attention for long-context scaling.
 from .mesh import (MESH_AXES, batch_pspec, canonical_batch_spec, make_mesh,
                    match_partition_rules, mesh_summary, reshard_pytree)
 from .ring import ring_attention
-from .train import (init_params, make_scan_train_step, make_train_step,
-                    shard_batch, snapshot_for_checkpoint,
-                    stack_batch_window)
+from .train import (init_params, make_train_step, shard_batch,
+                    snapshot_for_checkpoint)
 
 __all__ = [
     'MESH_AXES', 'batch_pspec', 'canonical_batch_spec', 'make_mesh',
     'match_partition_rules', 'mesh_summary', 'reshard_pytree',
-    'ring_attention', 'init_params', 'make_train_step',
-    'make_scan_train_step', 'shard_batch', 'snapshot_for_checkpoint',
-    'stack_batch_window'
+    'ring_attention', 'init_params', 'make_train_step', 'shard_batch',
+    'snapshot_for_checkpoint'
 ]

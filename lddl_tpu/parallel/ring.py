@@ -169,42 +169,31 @@ def ring_attention(q, k, v, kv_mask=None, axis_name='seq',
 
 
 def make_ring_attention(mesh, q_spec=None, mask_spec=None, axis_name='seq',
-                        block_impl='dense', with_segment_ids=False):
+                        block_impl='dense'):
   """Wrap :func:`ring_attention` in ``shard_map`` for use from jitted code.
 
   ``q_spec`` defaults to ``P(('data','fsdp'), 'tensor', 'seq', None)`` —
   batch over dp, heads over tensor parallelism, sequence over the ring.
   ``block_impl='flash'`` runs each chip's block attention as the Pallas
-  flash kernel. ``with_segment_ids=True`` returns a wrapper taking an
-  extra ``segment_ids`` ``[batch, seq]`` operand (used for both q and
-  kv — self-attention), sharded like the mask.
+  flash kernel. The wrapper takes ``(q, k, v, kv_mask, segment_ids)``;
+  ``segment_ids`` ``[batch, seq]`` (used for both q and kv —
+  self-attention) is sharded like the mask, or is None for full
+  attention.
   """
   q_spec = q_spec or P(('data', 'fsdp'), 'tensor', axis_name, None)
   mask_spec = mask_spec or P(('data', 'fsdp'), axis_name)
 
-  if with_segment_ids:
-    @functools.partial(
-        jax.shard_map,
-        mesh=mesh,
-        in_specs=(q_spec, q_spec, q_spec, mask_spec, mask_spec),
-        out_specs=q_spec,
-        check_vma=False)
-    def _sharded_seg(q, k, v, kv_mask, segment_ids):
-      return ring_attention(q, k, v, kv_mask, axis_name=axis_name,
-                            block_impl=block_impl,
-                            q_segment_ids=segment_ids,
-                            kv_segment_ids=segment_ids)
-
-    return _sharded_seg
-
+  # A None operand is an empty pytree: its spec binds to no array.
   @functools.partial(
       jax.shard_map,
       mesh=mesh,
-      in_specs=(q_spec, q_spec, q_spec, mask_spec),
+      in_specs=(q_spec, q_spec, q_spec, mask_spec, mask_spec),
       out_specs=q_spec,
       check_vma=False)
-  def _sharded(q, k, v, kv_mask):
+  def _sharded(q, k, v, kv_mask, segment_ids):
     return ring_attention(q, k, v, kv_mask, axis_name=axis_name,
-                          block_impl=block_impl)
+                          block_impl=block_impl,
+                          q_segment_ids=segment_ids,
+                          kv_segment_ids=segment_ids)
 
   return _sharded

@@ -200,39 +200,6 @@ def pretrain_loss(model, params, batch, dropout_rng=None,
     }
 
 
-def _train_step_body(model, tx, mesh, params, opt_state, rng, batch,
-                     max_predictions=None):
-  """One un-jitted train step — the single definition both
-  :func:`make_train_step` and :func:`make_scan_train_step` compile, so the
-  per-step and scan-window paths stay provably identical."""
-  with jax.named_scope('dropout_key'):
-    rng = jax.random.fold_in(
-        rng, opt_state[0].count if hasattr(opt_state[0], 'count') else 0)
-
-  def loss_fn(p):
-    return pretrain_loss(model, p, batch, dropout_rng=rng,
-                         max_predictions=max_predictions)
-
-  (loss, metrics), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
-  # Global gradient norm of the *raw* grads (pre-optimizer): one fused
-  # reduction inside the compiled step, read on the host for free once
-  # the loss scalar has already forced the device sync. This is the
-  # sentinel's grad_spike signal and the train.grad_norm gauge.
-  with jax.named_scope('grad_norm'):
-    metrics['grad_norm'] = optax.global_norm(grads)
-  with jax.named_scope('optimizer'):
-    updates, opt_state = tx.update(grads, opt_state, params)
-    params = optax.apply_updates(params, updates)
-  # The state leaves the step laid out as it came in. Left to itself the
-  # partitioner hands replicated-by-rule leaves (biases, norms) back
-  # split over fsdp, which the next call of an AOT-compiled step rejects
-  # (and a plain jit call silently recompiles for).
-  params, opt_state = jax.lax.with_sharding_constraint(
-      (params, opt_state), state_shardings(mesh, params, opt_state))
-  metrics['loss'] = loss
-  return params, opt_state, metrics
-
-
 def check_max_predictions(max_predictions, seq_len, masking,
                           mlm_probability=0.15):
   """Warn when a masked-only head budget under-covers the masking mode.
@@ -270,57 +237,34 @@ def make_train_step(model, tx, mesh, max_predictions=None):
 
   @functools.partial(jax.jit, donate_argnums=(0, 1))
   def step(params, opt_state, rng, batch):
-    return _train_step_body(model, tx, mesh, params, opt_state, rng, batch,
-                            max_predictions)
+    with jax.named_scope('dropout_key'):
+      rng = jax.random.fold_in(
+          rng, opt_state[0].count if hasattr(opt_state[0], 'count') else 0)
+
+    def loss_fn(p):
+      return pretrain_loss(model, p, batch, dropout_rng=rng,
+                           max_predictions=max_predictions)
+
+    (loss, metrics), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
+    # Global gradient norm of the *raw* grads (pre-optimizer): one fused
+    # reduction inside the compiled step, read on the host for free once
+    # the loss scalar has already forced the device sync. This is the
+    # sentinel's grad_spike signal and the train.grad_norm gauge.
+    with jax.named_scope('grad_norm'):
+      metrics['grad_norm'] = optax.global_norm(grads)
+    with jax.named_scope('optimizer'):
+      updates, opt_state = tx.update(grads, opt_state, params)
+      params = optax.apply_updates(params, updates)
+    # The state leaves the step laid out as it came in. Left to itself the
+    # partitioner hands replicated-by-rule leaves (biases, norms) back
+    # split over fsdp, which the next call of an AOT-compiled step rejects
+    # (and a plain jit call silently recompiles for).
+    params, opt_state = jax.lax.with_sharding_constraint(
+        (params, opt_state), state_shardings(mesh, params, opt_state))
+    metrics['loss'] = loss
+    return params, opt_state, metrics
 
   return step
-
-
-def make_scan_train_step(model, tx, mesh, max_predictions=None):
-  """Returns ``run(params, opt_state, rng, batches) ->
-  (params, opt_state, last_metrics)`` where every array in ``batches``
-  carries a leading steps axis: one compiled program executes the whole
-  window via ``lax.scan``, so per-step dispatch cost amortizes across the
-  window.
-
-  With K steps in one program, launch cost and the host's read of the
-  step scalars are paid once per window instead of once per step, so the
-  observed step time converges to device compute time — the idiomatic
-  shape for production TPU training loops (device-resident multi-batch
-  windows).
-  """
-
-  @functools.partial(jax.jit, donate_argnums=(0, 1))
-  def run(params, opt_state, rng, batches):
-
-    def body(carry, batch):
-      params, opt_state, metrics = _train_step_body(
-          model, tx, mesh, carry[0], carry[1], rng, batch, max_predictions)
-      return (params, opt_state), metrics
-
-    (params, opt_state), metrics = jax.lax.scan(body, (params, opt_state),
-                                                batches)
-    return params, opt_state, jax.tree.map(lambda m: m[-1], metrics)
-
-  return run
-
-
-def stack_batch_window(batches, mesh):
-  """Stack K host batch dicts into one device-resident window with a
-  leading steps axis (replicated over the mesh; each step's slice keeps
-  the canonical batch layout)."""
-  import numpy as np
-  stacked = {
-      k: np.stack([b[k] for b in batches]) for k in batches[0]
-  }
-  return {
-      k: jax.device_put(
-          v,
-          NamedSharding(
-              mesh,
-              P(None, *canonical_batch_spec(mesh, v.shape[1:]))))
-      for k, v in stacked.items()
-  }
 
 
 def shard_batch(batch, mesh):

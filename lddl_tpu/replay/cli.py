@@ -77,15 +77,14 @@ def loader_spec(args):
 
 
 def _attach_model_args(p):
+  from ..ops.attention import ATTENTION_IMPLS
   from ..training.pretrain import MODEL_SIZES
   p.add_argument('--tokenizer', default=None)
   p.add_argument('--vocab-size', type=int, default=None,
                  help='padded vocab size, replacing --vocab-file '
                       '(bundle replay needs no tokenizer)')
   p.add_argument('--model', choices=sorted(MODEL_SIZES), default='base')
-  p.add_argument('--attention',
-                 choices=['dense', 'flash', 'ring', 'ring_flash'],
-                 default='dense')
+  p.add_argument('--attention', choices=ATTENTION_IMPLS, default='dense')
   p.add_argument('--remat', action='store_true')
   p.add_argument('--dp', type=int, default=1)
   p.add_argument('--fsdp', type=int, default=1)

@@ -3,7 +3,7 @@
 The ledger's ``step`` boundary fingerprints the full train state
 (params + opt_state + rng) at every checkpoint boundary. Because the
 jitted step folds its dropout key from the optimizer's own step counter
-(:func:`~lddl_tpu.parallel.train._train_step_body`) and the loaders are
+(:func:`~lddl_tpu.parallel.train.make_train_step`) and the loaders are
 coordinate-addressable, *state at step S* is a pure function of
 *(checkpoint at S0 < S, batches S0..S-1)* — so any recorded step can be
 re-executed bit-for-bit on a fresh process: restore the newest
@@ -23,9 +23,8 @@ the spike below batch granularity.
 
 
 def _wrap_step_cache(loop):
-  from ..training.pretrain import CompiledStepCache, _step_cache_enabled
-  if _step_cache_enabled() and not isinstance(loop.step_fn,
-                                              CompiledStepCache):
+  from ..training.pretrain import CompiledStepCache
+  if not isinstance(loop.step_fn, CompiledStepCache):
     loop.step_fn = CompiledStepCache(loop.step_fn)
 
 

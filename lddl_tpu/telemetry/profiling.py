@@ -1,10 +1,10 @@
-"""On-demand ``jax.profiler`` capture, shared by bench and live runs.
+"""On-demand ``jax.profiler`` capture, for scripts and live runs.
 
 Two entry points over one code path:
 
   - :func:`trace_capture` — a context manager around one profiled region
-    (``benchmarks/train_bench.py --profile-dir`` uses this); no-op when
-    the directory is falsy, so callers never branch.
+    of a script; no-op when the directory is falsy, so callers never
+    branch.
   - :class:`StepProfiler` — the live-run half: ``GET /profile?steps=N``
     on the monitor endpoint (or ``lddl-monitor --profile N``) *arms* the
     profiler, and the train loop's per-step ``on_step()`` hook starts a
@@ -14,7 +14,7 @@ Two entry points over one code path:
     or stop: a capture holds N whole step programs, the first of them
     launched into an idle chip (:mod:`.capture` places the device's clock
     by it). Traces
-    land under ``LDDL_TELEMETRY_DIR/profiles/`` (same layout the bench
+    land under ``LDDL_TELEMETRY_DIR/profiles/`` (same layout the
     context manager uses), numbered per capture, so a long pretrain can
     be profiled without a restart and costs nothing while unarmed: the
     unarmed ``on_step`` path is two attribute reads. While a capture

@@ -49,9 +49,6 @@ class CompiledStepCache:
   ``train.xla_flops`` / ``train.xla_bytes`` counters — the measured
   numerators the roofline verdict and MFU gauge run on, at zero
   steady-state cost (two counter adds per step).
-
-  Disable with ``LDDL_STEP_CACHE=0`` (falls back to calling the jitted
-  step directly).
   """
 
   def __init__(self, step_fn):
@@ -113,11 +110,6 @@ class CompiledStepCache:
         self._flops_c.add(costs[0])
         self._bytes_c.add(costs[1])
     return fn(params, opt_state, rng, batch)
-
-
-def _step_cache_enabled():
-  return os.environ.get('LDDL_STEP_CACHE', '').strip().lower() not in (
-      '0', 'false', 'off', 'no')
 
 
 def state_fingerprint(snap):
@@ -532,8 +524,7 @@ class TrainLoop:
     tiles_total_c = tele.counter('train.attn_tiles_total')
     tiles_skipped_c = tele.counter('train.attn_tiles_skipped')
     peak_total = _peak_flops_total() if tele.enabled else None
-    if _step_cache_enabled() and not isinstance(self.step_fn,
-                                                CompiledStepCache):
+    if not isinstance(self.step_fn, CompiledStepCache):
       # Persisted on the loop (not run()-local) so repeated run() calls —
       # and every epoch within one — keep the warm per-bin executables.
       self.step_fn = CompiledStepCache(self.step_fn)
@@ -877,13 +868,13 @@ MODEL_SIZES = {
 
 
 def attach_args(parser):
+  from ..ops.attention import ATTENTION_IMPLS
   parser.add_argument('--path', required=True, help='balanced shard dir')
   parser.add_argument('--vocab-file', default=None)
   parser.add_argument('--tokenizer', default=None)
   parser.add_argument('--model', choices=sorted(MODEL_SIZES),
                       default='base')
-  parser.add_argument('--attention',
-                      choices=['dense', 'flash', 'ring', 'ring_flash'],
+  parser.add_argument('--attention', choices=ATTENTION_IMPLS,
                       default='dense')
   parser.add_argument('--remat', action='store_true')
   parser.add_argument('--prng', default='threefry',

@@ -23,15 +23,42 @@ jax.config.update('jax_platforms', 'cpu')
 import pytest  # noqa: E402
 
 
+def _descends_from(pid, ancestor):
+  """Whether the live process ``pid`` is ``ancestor`` or a child of it,
+  at any depth (``/proc/<pid>/stat``'s fourth field is the parent)."""
+  while pid > 1:
+    if pid == ancestor:
+      return True
+    try:
+      with open(f'/proc/{pid}/stat') as f:
+        pid = int(f.read().rsplit(')', 1)[1].split()[1])
+    except (OSError, ValueError, IndexError):
+      return False
+  return False
+
+
+def own_shm_segments():
+  """The ``lddl_<pid>_<nonce>`` segments whose owner is this process or a
+  live child of it. The other xdist workers' loaders make and unlink
+  theirs all the time, so the whole machine's segments say nothing about
+  this test. (An owner that was killed has its segments unlinked by its
+  own resource tracker; its name cannot be traced to a parent any more.)"""
+  from lddl_tpu.loader.shm import SEGMENT_PREFIX, live_segments
+  me = os.getpid()
+  owners = ((n, n[len(SEGMENT_PREFIX):].split('_')[0])
+            for n in live_segments())
+  return [n for n, pid in owners
+          if pid.isdigit() and _descends_from(int(pid), me)]
+
+
 @pytest.fixture(autouse=True)
 def _no_leaked_shm_segments():
   """Fail any test that leaves an ``lddl_`` shared-memory segment behind:
   the loader's shm batch transport must unlink its slot rings on clean
   shutdown, consumer abandonment, and worker SIGKILL alike."""
-  from lddl_tpu.loader.shm import live_segments
-  before = set(live_segments())
+  before = set(own_shm_segments())
   yield
-  leaked = sorted(set(live_segments()) - before)
+  leaked = sorted(set(own_shm_segments()) - before)
   assert not leaked, f'leaked shared-memory segments: {leaked}'
 
 

@@ -101,16 +101,48 @@ def test_validator_catches_loose_bins(tmp_path):
   assert rc == 1
 
 
-def test_train_mode_tiny_model(shards, tiny_vocab, tmp_path):
-  summary = _run(
-      shards, tiny_vocab, tmp_path / 'lens',
-      extra=['--mode', 'train', '--model', 'tiny', '--iters-per-epoch', '3',
-             '--warmup', '1', '--peak-tflops', '1'])
-  assert summary['mode'] == 'train'
-  assert summary['iters'] == 3
-  assert summary['model_tflops_per_sec'] > 0
-  assert 'mfu' in summary  # peak forced via --peak-tflops
-  assert summary['devices'] == 8  # conftest virtual CPU mesh
+def _example_commands(script):
+  """(program, argv) of every command an example script gives to the
+  trainer, the mock trainer or the binning validator, its ``${...}``
+  filled with a stand-in that is a path and a number alike."""
+  import re
+  import shlex
+  with open(os.path.join(_ROOT, 'examples', script)) as f:
+    text = f.read().replace('\\\n', ' ')
+  for line in text.splitlines():
+    words = shlex.split(re.sub(r'\$\{[^}]*\}', '1', line), comments=True)
+    if words[:3] == ['python', '-m', 'lddl_tpu.training.pretrain']:
+      yield 'pretrain', words[3:]
+    elif (len(words) > 1 and words[0] == 'python' and
+          words[1].startswith('1/benchmarks/')):
+      yield words[1][len('1/benchmarks/'):], words[2:]
+
+
+@pytest.mark.parametrize('script', ['local_example.sh', 'tpu_pod_example.sh'])
+def test_examples_name_programs_and_flags_that_exist(script, monkeypatch):
+  """Step 4 on of the two examples: every script they run is there and
+  every flag they pass parses under that script's own parser (the local
+  example runs the product's loop, ``lddl_tpu.training.pretrain``)."""
+  import argparse
+
+  from lddl_tpu.training import pretrain
+  seen = set()
+  for program, argv in _example_commands(script):
+    seen.add(program)
+    if program == 'pretrain':
+      args = pretrain.attach_args(argparse.ArgumentParser()).parse_args(argv)
+      assert args.model in pretrain.MODEL_SIZES
+    elif program == 'train_bench.py':
+      train_bench.attach_args(argparse.ArgumentParser()).parse_args(argv)
+    else:
+      assert program == 'validate_binning.py', program
+      # Its parser lives in main(): parse, then stop before it reads.
+      monkeypatch.setattr(validate_binning, 'collect', lambda d: 1 / 0)
+      with pytest.raises(ZeroDivisionError):
+        validate_binning.main(argv)
+  assert {'pretrain', 'train_bench.py'} <= seen
+  if script == 'local_example.sh':
+    assert 'validate_binning.py' in seen
 
 
 def test_bart_loader_bench_smoke(tiny_vocab, tmp_path, capsys):

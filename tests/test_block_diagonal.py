@@ -107,7 +107,7 @@ def test_multiblock_skip_grid_parity(monkeypatch, block_q):
   the three q blocks give interior tiles (64: whole tiles inside one
   document, no segment bias), boundary tiles and skipped ones."""
   monkeypatch.setattr(fa, '_BLOCK_Q', block_q)
-  monkeypatch.setattr(fa, '_BLOCK_KV_SEG', 128)
+  monkeypatch.setattr(fa, '_BLOCK_KV', 128)
   b, h, s, d = 2, 2, 512, 32
   seg, mask = _ragged_segments(b, s, 4, seed=11)
   total, skipped = count_skippable_tiles(seg)
@@ -171,7 +171,7 @@ def test_interior_tiles_leave_out_the_segment_bias(monkeypatch):
   still the dense block-diagonal one (forward and gradients); with a
   padded tail inside the last interior tile, too."""
   monkeypatch.setattr(fa, '_BLOCK_Q', 128)
-  monkeypatch.setattr(fa, '_BLOCK_KV_SEG', 128)
+  monkeypatch.setattr(fa, '_BLOCK_KV', 128)
   b, h, s, d = 1, 2, 512, 32
   seg = np.repeat(np.arange(s)[None, :] // 256, b, 0).astype(np.int32)
   mask = np.ones((b, s), np.int32)
@@ -240,7 +240,7 @@ def test_count_skippable_tiles(case):
   else:
     seg, _ = _ragged_segments(2, s, 16, seed=3, pad_tail=False)
   total, skipped = count_skippable_tiles(seg)
-  (block_q, padded_q), (block_k, padded_k) = fa._tile_blocks(s, s, True)
+  (block_q, padded_q), (block_k, padded_k) = fa._tile_blocks(s, s)
   assert padded_q == padded_k == s
   assert total == 2 * (s // block_q) * (s // block_k)
   assert (total, skipped) == _tiles_by_pairs(seg, block_q, block_k)
@@ -269,8 +269,7 @@ def test_ring_flash_matches_dense_block_diagonal():
   # are whole-shard skips, others straddle and fall through to flash.
   seg, mask = _ragged_segments(b, s, 4, seed=41)
   fn = make_ring_attention(mesh, q_spec=P(None, None, 'seq', None),
-                           mask_spec=P(None, 'seq'), block_impl='flash',
-                           with_segment_ids=True)
+                           mask_spec=P(None, 'seq'), block_impl='flash')
   out = fn(q, kk, v, jnp.asarray(mask), jnp.asarray(seg))
   ref = _dense_block_diagonal(q, kk, v, jnp.asarray(mask), jnp.asarray(seg))
   keep = _real_mask(mask, h, d)
@@ -289,8 +288,7 @@ def test_ring_dense_matches_dense_block_diagonal():
   q, kk, v = _inputs(b, h, s, d, seed=4)
   seg, mask = _ragged_segments(b, s, 3, seed=43)
   fn = make_ring_attention(mesh, q_spec=P(None, None, 'seq', None),
-                           mask_spec=P(None, 'seq'), block_impl='dense',
-                           with_segment_ids=True)
+                           mask_spec=P(None, 'seq'), block_impl='dense')
   out = fn(q, kk, v, jnp.asarray(mask), jnp.asarray(seg))
   ref = _dense_block_diagonal(q, kk, v, jnp.asarray(mask), jnp.asarray(seg))
   keep = _real_mask(mask, h, d)

@@ -14,7 +14,7 @@ from lddl_tpu.loader.device import (SeqlenAwarePrefetcher, prefetch_to_device)
 from lddl_tpu.pipeline.executor import Executor
 from lddl_tpu.preprocess import bert, codebert
 from lddl_tpu.preprocess.readers import read_code, read_corpus
-from lddl_tpu.training.pretrain import CompiledStepCache, _step_cache_enabled
+from lddl_tpu.training.pretrain import CompiledStepCache
 
 
 def _batches(n, batch=8, seq=8):
@@ -157,12 +157,6 @@ class TestCompiledStepCache:
     cache(None, None, None, batch)
     cache(None, None, None, batch)
     assert len(calls) == 2
-
-  def test_env_gate(self, monkeypatch):
-    monkeypatch.setenv('LDDL_STEP_CACHE', '0')
-    assert not _step_cache_enabled()
-    monkeypatch.delenv('LDDL_STEP_CACHE')
-    assert _step_cache_enabled()
 
 
 def _hash_dir(path):

@@ -46,15 +46,15 @@ class TestLoaderWorkerDeath:
     assert time.monotonic() - t0 < 30.0, 'detection took longer than the fail-fast bound'
     # The parent owns every shm slot-ring segment name and unlinks in its
     # iterator cleanup, so even a SIGKILLed worker cannot leak one.
-    from lddl_tpu.loader.shm import live_segments
-    assert live_segments() == [], 'SIGKILLed worker leaked shm segments'
+    from conftest import own_shm_segments
+    assert own_shm_segments() == [], 'SIGKILLed worker leaked shm segments'
 
   def test_abandoned_consumer_leaks_no_shm_segments(self, tmp_path):
     """A consumer that walks away mid-epoch (generator close, no epoch
     drain) must still leave /dev/shm clean."""
     import __graft_entry__ as g
     from lddl_tpu.loader import get_bert_pretrain_data_loader
-    from lddl_tpu.loader.shm import SEGMENT_PREFIX, live_segments
+    from conftest import own_shm_segments
 
     bal, vocab_file, _ = g.build_tiny_dataset(str(tmp_path), num_shards=4)
     loader = get_bert_pretrain_data_loader(
@@ -63,10 +63,10 @@ class TestLoaderWorkerDeath:
         transport='shm')
     it = iter(loader)
     next(it)
-    assert any(n.startswith(SEGMENT_PREFIX) for n in live_segments()), \
+    assert own_shm_segments(), \
         'shm transport should have live slot rings mid-epoch'
     it.close()
-    assert live_segments() == [], 'abandoned consumer leaked shm segments'
+    assert own_shm_segments() == [], 'abandoned consumer leaked shm segments'
 
 
 def _fb_rank(rendezvous, rank, world, die_at, q):

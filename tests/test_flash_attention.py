@@ -117,8 +117,7 @@ def test_bf16_gradients_match_dense(monkeypatch, segmented):
   gradients do, over several blocks on both axes."""
   from lddl_tpu.ops import flash_attention as fa
   monkeypatch.setattr(fa, '_BLOCK_Q', 128)
-  for cap in ('_BLOCK_KV_FWD', '_BLOCK_KV_BWD', '_BLOCK_KV_SEG'):
-    monkeypatch.setattr(fa, cap, 256)
+  monkeypatch.setattr(fa, '_BLOCK_KV', 256)
   b, h, s, d = 1, 2, 512, 64
   q, k, v, mask = _inputs(b, h, s, d, seed=21)
   seg = None
@@ -284,7 +283,7 @@ def test_ring_flash_matches_dense():
   q, k, v, mask = _inputs(2, 2, 64, 32, seed=2)
   fn = make_ring_attention(mesh, q_spec=P(None, None, 'seq', None),
                            mask_spec=P(None, 'seq'), block_impl='flash')
-  out = fn(q, k, v, mask)
+  out = fn(q, k, v, mask, None)
   ref = _dense_reference(q, k, v, mask)
   np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-4,
                              atol=2e-4)
@@ -295,7 +294,7 @@ def test_make_flash_attention_sharded():
   from lddl_tpu.ops.flash_attention import make_flash_attention
   mesh = make_mesh()  # data=8 over the virtual CPU devices
   q, k, v, mask = _inputs(8, 2, 64, 32, seed=6)
-  out = jax.jit(make_flash_attention(mesh))(q, k, v, mask)
+  out = jax.jit(make_flash_attention(mesh))(q, k, v, mask, None)
   ref = _dense_reference(q, k, v, mask)
   np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5,
                              atol=2e-5)
@@ -309,41 +308,52 @@ def test_make_flash_attention_rejects_seq_mesh():
     make_flash_attention(mesh)
 
 
-def test_block_env_overrides():
-  """LDDL_FLASH_BLOCK_* env vars must be honored at import (the
-  per-shape retuning knob benchmarks rely on; results stay equal across
-  blockings — test_multiblock_kv_grid)."""
-  import os
-  import subprocess
-  import sys
-  env = dict(os.environ, LDDL_FLASH_BLOCK_Q='256',
-             LDDL_FLASH_BLOCK_KV_FWD='512', LDDL_FLASH_BLOCK_KV_BWD='512',
-             JAX_PLATFORMS='cpu')
-  out = subprocess.run(
-      [sys.executable, '-c',
-       'from lddl_tpu.ops import flash_attention as fa;'
-       'print(fa._BLOCK_Q, fa._BLOCK_KV_FWD, fa._BLOCK_KV_BWD)'],
-      env=env, capture_output=True, text=True, check=True,
-      cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-  assert out.stdout.split() == ['256', '512', '512']
+@pytest.mark.parametrize('s', [128, 600])
+def test_one_tile_rule_for_every_launch(monkeypatch, s):
+  """``_tile_blocks`` gives one answer a shape: the forward launch, the
+  two backward launches and ``count_skippable_tiles``' default grid all
+  run on it, with or without segment ids (caps made small, so that 600
+  is five q blocks by three kv blocks)."""
+  from lddl_tpu.ops import flash_attention as fa
+  monkeypatch.setattr(fa, '_BLOCK_Q', 128)
+  monkeypatch.setattr(fa, '_BLOCK_KV', 256)
+  s_pad = fa._padded_len(s)
+  (block_q, padded_q), (block_k, padded_kv) = fa._tile_blocks(s_pad, s_pad)
+  n_q, n_k = padded_q // block_q, padded_kv // block_k
+  assert (n_q, n_k) == ((1, 1) if s == 128 else (5, 3))
+  seg = np.zeros((1, s), np.int32)
+  seg[:, s // 3:] = 1
+  assert fa.count_skippable_tiles(seg)[0] == n_q * n_k
+  grids = {}
+  real = fa.pl.pallas_call
+
+  def spy(kernel, *, grid, name, **kw):
+    grids.setdefault(name, set()).add(grid)
+    return real(kernel, grid=grid, name=name, **kw)
+
+  monkeypatch.setattr(fa.pl, 'pallas_call', spy)
+  q, k, v, mask = _inputs(1, 2, s, 64, seed=31)
+  for ids in (None, jnp.asarray(seg)):
+    jax.grad(lambda q: jnp.sum(
+        fa.flash_attention(q, k, v, mask, ids, ids)))(q)
+  assert grids == {'flash_fwd': {(2, n_q, n_k)}, 'flash_dq': {(2, n_q, n_k)},
+                   'flash_dkv': {(2, n_k, n_q)}}
 
 
 @pytest.mark.parametrize('block_q', [128, 256, 512])
-@pytest.mark.parametrize('caps', [(128, 128), (256, 256)])
-def test_multiblock_kv_grid(monkeypatch, caps, block_q):
+@pytest.mark.parametrize('cap', [128, 256])
+def test_multiblock_kv_grid(monkeypatch, cap, block_q):
   """Force the innermost kv grid dimension to take multiple steps (the
   default caps of 1024 make every CPU-sized test a single step, so
   the cross-step scratch accumulation — init/rescale/finalize — would
-  otherwise go untested). The (256, 256) case also exercises the
+  otherwise go untested). The 256 case also exercises the
   non-divisor overshoot: s=600 pads to 640, which blocks as 256 x 3 =
   768 with -inf-biased padding columns; the q block does the same on
   its axis (256: 3 x 256 = 768 with zero query rows sliced away; 512:
   2 x 384)."""
   from lddl_tpu.ops import flash_attention as fa
-  cap_fwd, cap_bwd = caps
   monkeypatch.setattr(fa, '_BLOCK_Q', block_q)
-  monkeypatch.setattr(fa, '_BLOCK_KV_FWD', cap_fwd)
-  monkeypatch.setattr(fa, '_BLOCK_KV_BWD', cap_bwd)
+  monkeypatch.setattr(fa, '_BLOCK_KV', cap)
   q, k, v, mask = _inputs(1, 2, 600, 64, seed=11)
   out = fa.flash_attention(q, k, v, mask)
   ref = _dense_reference(q, k, v, mask)

@@ -53,7 +53,7 @@ class TestRingAttention:
         mesh,
         q_spec=P(None, None, 'seq', None),
         mask_spec=P(None, 'seq'))
-    out = np.asarray(fn(q, k, v, mask))
+    out = np.asarray(fn(q, k, v, mask, None))
     ref = _dense_reference(q, k, v, mask)
     np.testing.assert_allclose(out, ref, rtol=2e-4, atol=2e-4)
 

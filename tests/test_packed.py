@@ -250,9 +250,8 @@ class TestPackedPipeline:
 
   def test_train_step_consumes_packed_batch(self, tmp_path):
     """One real train step (tiny model, 1024-token packed rows, CPU) on
-    loader output — the path the s>=8k chip runs take
-    (benchmarks/long_context_bench.py --packed-data exercises s=8192 on
-    real TPU; committed artifact benchmarks/results/)."""
+    loader output — the path the s>=8k chip runs take (the benchmark's
+    cell bert-base-pos8k.packed-s8k-longdoc runs s=8192 on the chip)."""
     import jax
     import jax.numpy as jnp
     import optax

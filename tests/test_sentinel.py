@@ -499,7 +499,6 @@ class TestTrainLoopIntegration:
 
   def test_nonfinite_loss_stops_behind_emergency_ckpt(
       self, shards, tiny_vocab, tmp_path, monkeypatch):
-    monkeypatch.setenv('LDDL_STEP_CACHE', '0')
     monkeypatch.delenv('LDDL_NONFINITE', raising=False)
     _fresh_gate(monkeypatch)  # the fix is independent of the gate
     ckpt = str(tmp_path / 'ckpt')
@@ -515,7 +514,6 @@ class TestTrainLoopIntegration:
 
   def test_nonfinite_ignore_opts_out(self, shards, tiny_vocab, tmp_path,
                                      monkeypatch):
-    monkeypatch.setenv('LDDL_STEP_CACHE', '0')
     monkeypatch.setenv('LDDL_NONFINITE', 'ignore')
     _fresh_gate(monkeypatch)
     loop = _loop(shards, tiny_vocab)
@@ -584,7 +582,6 @@ class TestTrainLoopIntegration:
                                          monkeypatch):
     from lddl_tpu.telemetry.live import SnapshotWindow, live_status
     from lddl_tpu.telemetry.metrics import enable
-    monkeypatch.setenv('LDDL_STEP_CACHE', '0')
     _fresh_gate(monkeypatch)
     enable()
     loop = _loop(shards, tiny_vocab)

