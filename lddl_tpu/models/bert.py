@@ -7,7 +7,9 @@ next_sentence_labels). Design choices for the MXU/XLA:
   - bfloat16 activations, float32 params and softmax/LSE accumulation;
   - ``nn.scan`` over layers: one traced layer body regardless of depth
     (compile time O(1) in num_layers), with optional ``jax.checkpoint``
-    rematerialization to trade FLOPs for HBM;
+    rematerialization to trade FLOPs for HBM: the backward pass remakes
+    all of a layer from its input but the flash kernels' ``(out, lse)``,
+    which are kept (a dense layer has neither and is remade whole);
   - static shapes everywhere — the loader's per-bin padding means one
     compiled program per bin;
   - attention is pluggable through ``BertConfig.attention_impl``;
@@ -34,7 +36,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..ops.attention import attend
+from ..ops.attention import FLASH_RESIDUAL_NAMES, attend
 
 
 @dataclasses.dataclass(frozen=True)
@@ -49,7 +51,7 @@ class BertConfig:
   dropout_rate: float = 0.1
   dtype: Any = jnp.bfloat16
   attention_impl: str = 'dense'  # one of ops.attention.ATTENTION_IMPLS
-  remat: bool = False
+  remat: bool = False  # remake each layer in the backward pass (see above)
 
   @property
   def head_dim(self):
@@ -121,7 +123,12 @@ class Encoder(nn.Module):
   @nn.compact
   def __call__(self, x, attention_mask, deterministic, segment_ids=None):
     cfg = self.cfg
-    block = nn.remat(Layer) if cfg.remat else Layer
+    block = Layer
+    if cfg.remat:
+      # What remat keeps is read from the traced layer itself: only a layer
+      # that ran the flash kernels holds a value under these names.
+      block = nn.remat(Layer, policy=jax.checkpoint_policies.
+                       save_only_these_names(*FLASH_RESIDUAL_NAMES))
 
     def body(layer, carry, _):
       return layer(carry, attention_mask, segment_ids), None

@@ -13,6 +13,12 @@ import jax.numpy as jnp
 
 ATTENTION_IMPLS = ('dense', 'flash', 'ring', 'ring_flash')
 
+# The ``checkpoint_name``s of the flash forward kernel's output and
+# log-sum-exp (tagged in ``flash_attention._flash_fwd``), which
+# ``models/bert.py`` tells ``nn.remat`` to keep. They live here so that a
+# dense model names them without importing Pallas.
+FLASH_RESIDUAL_NAMES = ('flash_out', 'flash_lse')
+
 
 def attend(q, k, v, attention_mask, segment_ids, *, impl, mesh, dtype):
   """Context ``[batch, heads, seq, head_dim]`` of softmax attention.

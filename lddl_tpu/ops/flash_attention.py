@@ -64,7 +64,11 @@ masking of arXiv:2107.02027 as a speedup rather than a cost.
 
 Differentiation is a ``jax.custom_vjp``: forward saves (out, lse); the
 backward runs two Pallas kernels — dq over q-blocks, (dk, dv) over
-k-blocks — each recomputing P = exp(s - lse) blockwise.
+k-blocks — each recomputing P = exp(s - lse) blockwise. The two saved
+values carry ``checkpoint_name``s (``ops.attention.FLASH_RESIDUAL_NAMES``)
+so that a caller's ``jax.checkpoint`` can keep them by policy: a
+rematted layer (``models/bert.py``) then runs the forward kernel once a
+step, not a second time in its backward pass (``_flash_fwd``).
 
 On the ``cpu`` backend the kernels run in Pallas interpret mode, so the
 CPU test suite exercises the identical code path. Every other backend
@@ -73,12 +77,16 @@ fallback for an accelerator that announces itself under another name.
 """
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .attention import FLASH_RESIDUAL_NAMES
 
 NEG_INF = -1e9
 # Softmax-denominator floor: a q row whose every tile was skipped (only
@@ -408,7 +416,20 @@ def _flash_fwd_impl(q, k, v, bias, q_seg, kv_seg, heads):
 
 
 def _flash_fwd(q, k, v, bias, q_seg, kv_seg, heads):
+  """The forward rule. The kernel's two results are also the residuals
+  that only it can make, so they carry the names by which a remat policy
+  keeps them (``FLASH_RESIDUAL_NAMES``; outside remat a name is the
+  identity and lowers to nothing). A kept value is stored in the shape it
+  is named in, and the chip pads the minor dimension to 128 lanes: a head
+  of 64 would keep ``out`` at twice its bytes and the ``[bh, s, 1]``
+  column of ``lse`` at 128 times. So each is named as a lane-dense view
+  and shaped back behind the name."""
   out, lse = _flash_fwd_impl(q, k, v, bias, q_seg, kv_seg, heads)
+  out_name, lse_name = FLASH_RESIDUAL_NAMES
+  bh, s_q, d = out.shape
+  dense = out.reshape(bh, -1, math.gcd(s_q * d, 128))
+  out = checkpoint_name(dense, out_name).reshape(bh, s_q, d)
+  lse = checkpoint_name(lse.reshape(bh, s_q), lse_name).reshape(bh, s_q, 1)
   return (out, lse), (q, k, v, bias, q_seg, kv_seg, out, lse)
 
 

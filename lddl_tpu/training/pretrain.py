@@ -876,7 +876,10 @@ def attach_args(parser):
                       default='base')
   parser.add_argument('--attention', choices=ATTENTION_IMPLS,
                       default='dense')
-  parser.add_argument('--remat', action='store_true')
+  parser.add_argument('--remat', action='store_true',
+                      help='remake each layer in the backward pass instead '
+                      'of storing its activations; only the flash '
+                      "kernels' (out, lse) are kept")
   parser.add_argument('--prng', default='threefry',
                       choices=['threefry', 'rbg'],
                       help="jax PRNG impl; 'rbg' draws dropout bits with "
