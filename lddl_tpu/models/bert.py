@@ -7,9 +7,16 @@ next_sentence_labels). Design choices for the MXU/XLA:
   - bfloat16 activations, float32 params and softmax/LSE accumulation;
   - ``nn.scan`` over layers: one traced layer body regardless of depth
     (compile time O(1) in num_layers), with optional ``jax.checkpoint``
-    rematerialization to trade FLOPs for HBM: the backward pass remakes
-    all of a layer from its input but the flash kernels' ``(out, lse)``,
-    which are kept (a dense layer has neither and is remade whole);
+    rematerialization to trade FLOPs for HBM, selectively (Korthikanti et
+    al. 2022, arXiv:2205.05198): beside a layer's input the backward pass
+    is handed what is O(tokens * width) and a wide gemm to remake, i.e. the
+    outputs of the ``query``, ``key``, ``value``, ``intermediate`` and
+    ``output`` projections and the context (the flash kernels'
+    ``(out, lse)``, or the dense path's p.v), in bfloat16
+    ``(5 * hidden_size + intermediate_size) * 2`` bytes a token and layer;
+    it remakes what is O(seq^2) or element-wise (the dense path's scores
+    and softmax, GELU, the layer norms, the dropout masks) and the ``out``
+    projection, which is cheaper remade than kept;
   - static shapes everywhere — the loader's per-bin padding means one
     compiled program per bin;
   - attention is pluggable through ``BertConfig.attention_impl``;
@@ -34,9 +41,10 @@ from typing import Any
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
-from ..ops.attention import FLASH_RESIDUAL_NAMES, attend
+from ..ops.attention import FLASH_IMPLS, REMAT_KEPT_NAMES, attend
 
 
 @dataclasses.dataclass(frozen=True)
@@ -51,7 +59,10 @@ class BertConfig:
   dropout_rate: float = 0.1
   dtype: Any = jnp.bfloat16
   attention_impl: str = 'dense'  # one of ops.attention.ATTENTION_IMPLS
-  remat: bool = False  # remake each layer in the backward pass (see above)
+  # Remake each layer in the backward pass but for five of its gemms'
+  # outputs and its context, which are kept (see above: 18.4 KB a token and
+  # layer for BERT-large, 13.8 KB for base).
+  remat: bool = False
 
   @property
   def head_dim(self):
@@ -77,15 +88,22 @@ class SelfAttention(nn.Module):
     cfg, deterministic = self.cfg, self.deterministic
     b, s, _ = x.shape
     heads, hd = cfg.num_heads, cfg.head_dim
-    q = _dense(cfg.hidden_size, cfg, 'query')(x)
-    k = _dense(cfg.hidden_size, cfg, 'key')(x)
-    v = _dense(cfg.hidden_size, cfg, 'value')(x)
+    # A name is what ``Encoder``'s remat policy keeps by (elsewhere it is
+    # the identity and lowers to nothing). A kept value is stored in the
+    # shape it is named in, so each is named as ``[b, s, width]``: a minor
+    # dimension of a head's 64 would be padded to the chip's 128 lanes.
+    q = checkpoint_name(_dense(cfg.hidden_size, cfg, 'query')(x), 'query_out')
+    k = checkpoint_name(_dense(cfg.hidden_size, cfg, 'key')(x), 'key_out')
+    v = checkpoint_name(_dense(cfg.hidden_size, cfg, 'value')(x), 'value_out')
     q = q.reshape(b, s, heads, hd).transpose(0, 2, 1, 3)
     k = k.reshape(b, s, heads, hd).transpose(0, 2, 1, 3)
     v = v.reshape(b, s, heads, hd).transpose(0, 2, 1, 3)
     ctx = attend(q, k, v, attention_mask, segment_ids,
                  impl=cfg.attention_impl, mesh=self.mesh, dtype=cfg.dtype)
     ctx = ctx.transpose(0, 2, 1, 3).reshape(b, s, cfg.hidden_size)
+    if cfg.attention_impl not in FLASH_IMPLS:
+      # The flash kernels name their own output: a value is kept once.
+      ctx = checkpoint_name(ctx, 'dense_context')
     out = _dense(cfg.hidden_size, cfg, 'out')(ctx)
     return nn.Dropout(cfg.dropout_rate)(out, deterministic=deterministic)
 
@@ -106,10 +124,12 @@ class Layer(nn.Module):
     with jax.named_scope('residual'):
       x = x + attn
     x = nn.LayerNorm(dtype=cfg.dtype, name='attention_norm')(x)
-    h = _dense(cfg.intermediate_size, cfg, 'intermediate')(x)
+    h = checkpoint_name(_dense(cfg.intermediate_size, cfg, 'intermediate')(x),
+                        'intermediate_out')
     with jax.named_scope('gelu'):
       h = nn.gelu(h, approximate=True)
-    h = _dense(cfg.hidden_size, cfg, 'output')(h)
+    h = checkpoint_name(_dense(cfg.hidden_size, cfg, 'output')(h),
+                        'output_out')
     h = nn.Dropout(cfg.dropout_rate)(h, deterministic=deterministic)
     with jax.named_scope('residual'):
       x = x + h
@@ -125,10 +145,10 @@ class Encoder(nn.Module):
     cfg = self.cfg
     block = Layer
     if cfg.remat:
-      # What remat keeps is read from the traced layer itself: only a layer
-      # that ran the flash kernels holds a value under these names.
+      # What remat keeps is read from the traced layer itself: a name that
+      # the layer's back-end did not tag keeps nothing.
       block = nn.remat(Layer, policy=jax.checkpoint_policies.
-                       save_only_these_names(*FLASH_RESIDUAL_NAMES))
+                       save_only_these_names(*REMAT_KEPT_NAMES))
 
     def body(layer, carry, _):
       return layer(carry, attention_mask, segment_ids), None

@@ -12,12 +12,26 @@ import jax
 import jax.numpy as jnp
 
 ATTENTION_IMPLS = ('dense', 'flash', 'ring', 'ring_flash')
+# The back-ends whose blocks run the Pallas kernels, with or without a mesh.
+FLASH_IMPLS = ('flash', 'ring_flash')
 
 # The ``checkpoint_name``s of the flash forward kernel's output and
-# log-sum-exp (tagged in ``flash_attention._flash_fwd``), which
-# ``models/bert.py`` tells ``nn.remat`` to keep. They live here so that a
-# dense model names them without importing Pallas.
+# log-sum-exp (tagged in ``flash_attention._flash_fwd``). They live here so
+# that a dense model names them without importing Pallas.
 FLASH_RESIDUAL_NAMES = ('flash_out', 'flash_lse')
+
+# Everything ``nn.remat``'s policy keeps of a layer (``models/bert.py``
+# tags the rest): the outputs of the ``query``, ``key``, ``value``,
+# ``intermediate`` and ``output`` projections, each as
+# ``[batch, seq, width]``, and its context, which the flash kernels name
+# themselves and the dense path below names as ``dense_context``. What is
+# O(seq^2) or element-wise carries no name and is remade, and so is the
+# ``out`` projection: its output feeds a layer norm, so keeping it costs a
+# store, a load and a pass of the norm's own where remaking it costs one
+# narrow gemm that carries the norm in its fusion (PERF.md, PR 35).
+REMAT_KEPT_NAMES = FLASH_RESIDUAL_NAMES + (
+    'query_out', 'key_out', 'value_out', 'dense_context',
+    'intermediate_out', 'output_out')
 
 
 def attend(q, k, v, attention_mask, segment_ids, *, impl, mesh, dtype):
@@ -40,10 +54,10 @@ def attend(q, k, v, attention_mask, segment_ids, *, impl, mesh, dtype):
                      f'{ATTENTION_IMPLS}')
   if impl in ('ring', 'ring_flash') and mesh is not None:
     from ..parallel.ring import make_ring_attention
-    block_impl = 'flash' if impl == 'ring_flash' else 'dense'
+    block_impl = 'flash' if impl in FLASH_IMPLS else 'dense'
     return make_ring_attention(mesh, block_impl=block_impl)(
         q, k, v, attention_mask, segment_ids)
-  if impl in ('flash', 'ring_flash'):
+  if impl in FLASH_IMPLS:
     from .flash_attention import flash_attention, make_flash_attention
     if mesh is not None:
       return make_flash_attention(mesh)(q, k, v, attention_mask, segment_ids)

@@ -1,11 +1,18 @@
-"""What ``nn.remat(Layer)`` keeps: the flash forward kernel's ``(out, lse)``
-under ``FLASH_RESIDUAL_NAMES``, and nothing else.
+"""What ``nn.remat(Layer)`` keeps (``ops.attention.REMAT_KEPT_NAMES``): the
+outputs of five of the layer's six projections (not of ``out``, which the
+chip remakes for less than keeping it costs) and its context, which is the
+flash forward kernel's ``(out, lse)`` or the dense path's p.v.
 
   - a rematted flash layer runs ``flash_fwd`` once in ``jax.grad``, not
     twice, and with the names dropped it runs twice again;
-  - keeping the two values changes no gradient, on any flash path;
-  - a dense layer holds neither name: its step lowers to the program
-    ``nn.remat(Layer)`` gave, and never imports the Pallas module.
+  - the recomputation holds two matrix products (q.k^T and ``out``) on
+    the dense path and one (``out``) on the flash path; with the
+    projections' names dropped it holds the layer's eight, or its six
+    beside the kernel;
+  - keeping the values changes no gradient, on any path, mesh or none;
+  - without remat a name is nothing: the step lowers to the text it has
+    with ``checkpoint_name`` patched to the identity;
+  - a dense model never imports the Pallas module.
 """
 
 import os
@@ -19,7 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from lddl_tpu.ops.attention import FLASH_RESIDUAL_NAMES
+from lddl_tpu.ops.attention import FLASH_RESIDUAL_NAMES, REMAT_KEPT_NAMES
 
 KEEP = jax.checkpoint_policies.save_only_these_names(*FLASH_RESIDUAL_NAMES)
 B, S = 2, 32
@@ -57,14 +64,20 @@ def _loss_fn(model, segmented):
   return loss, lambda: model.init(jax.random.key(0), ids, types, mask)['params']
 
 
-def _without_policy(monkeypatch):
-  """``nn.remat(Layer)`` as it stood before the policy."""
+def _with_policy(monkeypatch, policy):
+  """``nn.remat(Layer)`` under another policy: ``None`` is what it was
+  before any, ``KEEP`` what PR 32 left (the projections' names dropped)."""
   remat = nn.remat
-  monkeypatch.setattr(nn, 'remat', lambda layer, policy: remat(layer))
+  monkeypatch.setattr(nn, 'remat',
+                      lambda layer, **_: remat(layer, policy=policy))
+
+
+def _count(pattern, fn, *args):
+  return len(re.findall(pattern, str(jax.make_jaxpr(fn)(*args))))
 
 
 def _count_flash_fwd(fn, *args):
-  return len(re.findall(r'name=flash_fwd\b', str(jax.make_jaxpr(fn)(*args))))
+  return _count(r'name=flash_fwd\b', fn, *args)
 
 
 @pytest.mark.parametrize('segmented', [False, True],
@@ -75,9 +88,51 @@ def test_rematted_flash_layer_runs_the_forward_kernel_once(
   # The scan holds one layer body whatever num_layers is: the forward
   # pass's kernel, and under policy=None the backward pass's second.
   if policy == 'none':
-    _without_policy(monkeypatch)
+    _with_policy(monkeypatch, None)
   loss, init = _loss_fn(_model('flash', remat=True), segmented)
   assert _count_flash_fwd(jax.grad(loss), init()) == expected
+
+
+def _count_products(impl, remat, segmented):
+  loss, init = _loss_fn(_model(impl, remat=remat), segmented)
+  return _count(r'\bdot_general\b', jax.grad(loss), init())
+
+
+@pytest.mark.parametrize('segmented', [False, True],
+                         ids=['full', 'block-diagonal'])
+@pytest.mark.parametrize('impl,policy,expected', [
+    ('dense', 'names', 2),  # q.k^T for the softmax that is remade; out
+    ('dense', 'flash names alone', 8),
+    ('flash', 'names', 1),  # out
+    ('flash', 'flash names alone', 6),
+])
+def test_the_recomputation_of_a_rematted_layer_holds_these_products(
+    monkeypatch, impl, policy, expected, segmented):
+  # What jax.grad of the rematted model holds beyond the plain model's
+  # forward and backward products is what the backward pass remakes (one
+  # scanned layer body, whatever num_layers is; the kernels' own products
+  # are in both counts).
+  if policy == 'flash names alone':
+    _with_policy(monkeypatch, KEEP)
+  remade = (_count_products(impl, True, segmented) -
+            _count_products(impl, False, segmented))
+  assert remade == expected
+
+
+def test_every_kept_name_is_tagged_by_the_layer_that_runs():
+  # A misspelt name keeps nothing and says nothing: each name of the tuple
+  # is in the traced layer of the back-end that owns it, and in no other
+  # (the kernel's names are in its forward rule, so under jax.grad).
+  def tagged(impl):
+    loss, init = _loss_fn(_model(impl, remat=False, num_layers=1), True)
+    return set(re.findall(r'name\[name=(\w+)\]',
+                          str(jax.make_jaxpr(jax.grad(loss))(init()))))
+
+  context = set(FLASH_RESIDUAL_NAMES) | {'dense_context'}
+  shared = set(REMAT_KEPT_NAMES) - context
+  assert len(shared) == 5
+  assert tagged('dense') == shared | {'dense_context'}
+  assert tagged('flash') == shared | set(FLASH_RESIDUAL_NAMES)
 
 
 def _mesh(**axes):
@@ -90,12 +145,20 @@ def _mesh(**axes):
     ('flash', None),
     ('flash', dict(data=2)),
     ('ring_flash', dict(data=1, fsdp=1, tensor=1, seq=2)),
+    ('dense', None),
+    ('dense', dict(data=1, fsdp=1, tensor=2)),
 ])
 def test_keeping_the_residuals_changes_no_gradient(impl, axes):
+  from lddl_tpu.parallel.train import init_params
   mesh = _mesh(**axes) if axes else None
   grads = {}
   for remat in (False, True):
-    loss, init = _loss_fn(_model(impl, remat, mesh, num_layers=1), True)
+    model = _model(impl, remat, mesh, num_layers=1)
+    loss, init = _loss_fn(model, True)
+    if axes and axes.get('tensor', 1) > 1:
+      # The parameters placed as a tensor-parallel job places them: the
+      # kept values are then sharded as their gemms' outputs are.
+      init = lambda: init_params(model, mesh, jax.random.key(0), seq_len=S)
     grads[remat] = jax.jit(jax.grad(loss))(init())
   for (path, kept), plain in zip(
       jax.tree_util.tree_flatten_with_path(grads[True])[0],
@@ -143,7 +206,7 @@ def _lowered_dense_step():
   from lddl_tpu.parallel import make_mesh, make_train_step
   from lddl_tpu.parallel.train import init_params
   mesh = make_mesh(data=1, devices=jax.devices()[:1])
-  model = _model('dense', remat=True, num_layers=2)
+  model = _model('dense', remat=False, num_layers=2)
   tx = optax.adamw(1e-4)
   params = init_params(model, mesh, jax.random.key(0), seq_len=S)
   ids, types, mask, seg = _batch(True)
@@ -154,16 +217,21 @@ def _lowered_dense_step():
       'next_sentence_labels': jnp.zeros((B,), jnp.int32),
   }
   step = make_train_step(model, tx, mesh, max_predictions=8)
-  return step.lower(params, jax.jit(tx.init)(params), jax.random.key(1),
+  text = step.lower(params, jax.jit(tx.init)(params), jax.random.key(1),
                     batch).as_text()
+  # A private function's symbol ends in the number of the equation that
+  # called it, and a name is an equation (that lowers to nothing).
+  return re.sub(r'(@\w+?)_\d+\b', r'\1', text)
 
 
-def test_dense_remat_step_lowers_to_the_program_it_was(monkeypatch):
-  # A dense layer holds neither name, so the policy keeps what
-  # policy=None keeps: nothing.
-  with_policy = _lowered_dense_step()
-  _without_policy(monkeypatch)
-  assert with_policy == _lowered_dense_step()
+def test_step_without_remat_lowers_to_the_program_it_was(monkeypatch):
+  # Outside remat a name is the identity: the step of a model that does
+  # not remat (bert-base.pairs-s128's) is the text it is with no name.
+  from lddl_tpu.models import bert
+  with_names = _lowered_dense_step()
+  assert 'dot_general' in with_names
+  monkeypatch.setattr(bert, 'checkpoint_name', lambda x, name: x)
+  assert with_names == _lowered_dense_step()
 
 
 def test_dense_model_does_not_import_the_flash_module():

@@ -31,8 +31,7 @@ def _tiny_config(**kwargs):
                     **MODEL_SIZES['tiny'], **kwargs)
 
 
-@pytest.fixture(scope='module')
-def op_names():
+def _compiled_op_names(**kwargs):
   """The ``op_name`` of every instruction of the compiled tiny step (remat
   and dropout on, masked-only head: the cells' program in small)."""
   import optax
@@ -41,7 +40,7 @@ def op_names():
   from lddl_tpu.parallel import make_mesh, make_train_step
   from lddl_tpu.parallel.train import init_params
   mesh = make_mesh(data=1, devices=jax.devices()[:1])
-  model = BertForPretraining(_tiny_config())
+  model = BertForPretraining(_tiny_config(**kwargs))
   tx = optax.adamw(1e-4)
   params = init_params(model, mesh, jax.random.key(0), seq_len=128)
   opt_state = jax.jit(tx.init)(params)
@@ -57,6 +56,16 @@ def op_names():
   text = step.lower(params, opt_state, jax.random.key(1),
                     batch).compile().as_text()
   return re.findall(r'op_name="([^"]*)"', text)
+
+
+@pytest.fixture(scope='module')
+def op_names():
+  return _compiled_op_names()
+
+
+@pytest.fixture(scope='module')
+def flash_op_names():
+  return _compiled_op_names(attention_impl='flash')
 
 
 # scan_carry: the layer scan's own traffic, which no module owns.
@@ -82,6 +91,62 @@ def test_nothing_of_the_optimizer_or_the_loss_is_unscoped(op_names):
   assert [n for n in outside if classify(n)[0] == 'unscoped'] == []
   for scope in ('loss', 'optimizer', 'grad_norm'):
     assert any(re.search(rf'[/(]{scope}[/)]', n) for n in outside), scope
+
+
+LAYER = 'encoder/while/body/closed_call/layers.body/'
+REMADE = (f'jit(step)/transpose(jvp(BertForPretraining))/{LAYER}layers.body/'
+          'checkpoint/rematted_computation/layers/')
+
+
+def _products(names, pass_):
+  return sorted({n.split('layers/')[-1] for n in names
+                 if n.endswith('/dot_general') and LAYER in n
+                 and classify(n)[1] == pass_})
+
+
+GEMMS = ['attention/key/dot_general', 'attention/out/dot_general',
+         'attention/query/dot_general', 'attention/value/dot_general',
+         'intermediate/dot_general', 'output/dot_general']
+SCORES, CONTEXT, OUT = ('attention/bhqd,bhkd->bhqk/dot_general',
+                        'attention/bhqk,bhkd->bhqd/dot_general',
+                        'attention/out/dot_general')
+
+
+def test_the_recompute_pass_of_a_dense_layer_holds_scores_and_out(op_names):
+  # Five projections' outputs and the context are kept by name
+  # (models/bert.py): they run in the forward pass, their two gradients in
+  # the backward pass.
+  assert _products(op_names, 'forward') == sorted(GEMMS + [SCORES, CONTEXT])
+  assert _products(op_names, 'backward') == sorted(GEMMS + [SCORES, CONTEXT])
+  assert _products(op_names, 'recompute') == [SCORES, OUT]
+  assert classify(REMADE + SCORES) == ('attention', 'recompute')
+  assert classify(REMADE + OUT) == ('attention', 'recompute')
+
+
+def test_the_recompute_pass_of_a_flash_layer_holds_out_alone(flash_op_names):
+  in_kernels = [n for n in flash_op_names if re.search(r'/flash_\w+/', n)]
+  assert {classify(n)[0] for n in in_kernels} == {'attention'}
+  assert 'recompute' not in {classify(n)[1] for n in in_kernels}
+  outside = [n for n in flash_op_names if n not in in_kernels]
+  assert _products(outside, 'forward') == GEMMS
+  assert _products(outside, 'backward') == GEMMS
+  assert _products(outside, 'recompute') == [OUT]
+
+
+@pytest.mark.parametrize('names', ['op_names', 'flash_op_names'])
+def test_what_no_module_owns_in_the_encoder(names, request):
+  # The kept values' stores and loads are the scan's own traffic
+  # (scan_carry); beside them stand only jax's own two: the remat call's
+  # container and the reduce_precision it puts on a kept value's producer,
+  # which the TPU compiler folds into that producer (a bfloat16 value
+  # reduced to bfloat16).
+  names = [n for n in request.getfixturevalue(names) if '/encoder/' in n]
+  assert {n.rsplit('/', 1)[-1] for n in names
+          if classify(n)[0] == 'unscoped'} == {'reduce_precision', 'remat2'}
+  carried = {(n.rsplit('/', 1)[-1], classify(n)[1]) for n in names
+             if classify(n)[0] == 'scan_carry'}
+  assert {('dynamic_update_slice', 'forward'),
+          ('dynamic_slice', 'backward')} <= carried
 
 
 @pytest.mark.parametrize('op_name,expected', [
@@ -130,6 +195,21 @@ def test_nothing_of_the_optimizer_or_the_loss_is_unscoped(op_names):
     ('jit(step)/transpose(jvp(BertForPretraining))/encoder/while/body/'
      'closed_call/layers.body/layers.body/checkpoint/rematted_computation/'
      'layers/attention/reshape', ('attention', 'recompute')),
+    # Projections' outputs are kept too: such a projection's gradient stands
+    # beside the rematted computation, what is element-wise inside it.
+    ('jit(step)/transpose(jvp(BertForPretraining))/encoder/while/body/'
+     'closed_call/layers.body/layers.body/checkpoint/layers/intermediate/'
+     'dot_general', ('ffn', 'backward')),
+    ('jit(step)/transpose(jvp(BertForPretraining))/encoder/while/body/'
+     'closed_call/layers.body/layers.body/checkpoint/rematted_computation/'
+     'layers/gelu/tanh', ('ffn', 'recompute')),
+    ('jit(step)/transpose(jvp(BertForPretraining))/encoder/while/body/'
+     'closed_call/layers.body/layers.body/checkpoint/rematted_computation/'
+     'layers/attention_norm/rsqrt', ('norms', 'recompute')),
+    ('jit(step)/transpose(jvp(BertForPretraining))/encoder/while/body/'
+     'dynamic_slice', ('scan_carry', 'backward')),
+    ('jit(step)/jvp(BertForPretraining)/encoder/while/body/closed_call/'
+     'layers.body/layers/reduce_precision', ('unscoped', 'forward')),
     ('jit(step)/jvp(BertForPretraining)/encoder/while/body/'
      'broadcast_in_dim', ('scan_carry', 'forward')),
     ('jit(step)/transpose(jvp(BertForPretraining))/encoder/while/body/'
