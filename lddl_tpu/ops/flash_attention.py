@@ -10,22 +10,23 @@ storing them.
 
 Layout: inputs ``[batch, heads, seq, head_dim]`` are flattened to
 ``[batch*heads, seq, head_dim]``; the grid walks (batch*heads,
-q-blocks, k-blocks) for forward/dq and (batch*heads, k-blocks,
-q-blocks) for dk/dv — the contracted sequence axis is the *innermost*
-(sequential) grid dimension, with the running state (max/sum/acc or
-gradient accumulators) in VMEM scratch that persists across those
-steps. One grid step works on a ``[block_q, block_k]`` tile of up to
-1024 x 1024 (``_tile_blocks``; a grid step costs ~0.45 us before it
-computes anything, so the tile must be large enough to hide that):
-VMEM per step is the q/output tile, one k and one v block (128 KB each
-in bfloat16, double-buffered) and the float32 ``[block_q, block_k]``
-intermediates (4 MB each, which Mosaic works through in pieces) —
-inside the default scoped limit, independent of sequence length (an
-earlier revision held full per-head K/V in VMEM, capping single-chip
-sequences at ~8k; the grid-blocked form runs 32k+). Lengths that don't
-divide into whole blocks are padded up to the next block boundary —
-keys with -inf-biased columns, queries with zero rows that are sliced
-away (``_blocking``) — never dropped to slow 128-wide blocks.
+q-blocks, k-blocks) for the forward and (batch*heads, k-blocks,
+q-blocks) for the backward — the innermost (sequential) grid dimension
+is the one the forward's softmax and the backward's dk/dv are summed
+over, with the running state (max/sum/acc or gradient accumulators) in
+VMEM scratch that persists across those steps. One grid step works on a
+``[block_q, block_k]`` tile of up to 1024 x 1024 (``_tile_blocks``; a
+grid step costs ~0.45 us before it computes anything, so the tile must
+be large enough to hide that): VMEM per step is the q/output tile, one
+k and one v block (128 KB each in bfloat16, double-buffered) and the
+float32 ``[block_q, block_k]`` intermediates (4 MB each, which Mosaic
+works through in pieces), independent of sequence length in the forward
+(an earlier revision held full per-head K/V in VMEM, capping single-chip
+sequences at ~8k; the grid-blocked form runs 32k+). The backward also
+holds one head's dq (below). Lengths that don't divide into whole blocks
+are padded up to the next block boundary — keys with -inf-biased
+columns, queries with zero rows that are sliced away (``_blocking``) —
+never dropped to slow 128-wide blocks.
 
 Arithmetic: the MXU takes q, k, v and dO tiles in the dtype they arrive
 in and sums in float32; the four products with a computed operand
@@ -34,8 +35,10 @@ before the product, where the dense path rounds its probabilities
 (``ops/attention.py``: ``probs.astype(dtype)``). Scores, the running
 max and sum, ``exp``, ``lse``, ``delta``, dS and the accumulators are
 float32. A model built in float32 therefore gets float32 operands
-throughout. No product transposes a tile: q.k^T contracts the minor
-dimension of both operands, and dk/dv keep their tile key-major.
+throughout. No ``[block, block]`` tile is turned for a product: it
+stands as it is or contracts its minor dimension, as k does in q.k^T;
+what the backward turns are ``[block, head_dim]`` operands, so that its
+gradients come out of the MXU as lane-dense ``[head_dim, block]``.
 
 Masking: a key-side additive bias ``[batch, seq]`` (0 = attend, -1e9 =
 padding) — the same semantics as the dense path and the ring
@@ -63,12 +66,20 @@ computing and masking all of them — the "no cross-contamination"
 masking of arXiv:2107.02027 as a speedup rather than a cost.
 
 Differentiation is a ``jax.custom_vjp``: forward saves (out, lse); the
-backward runs two Pallas kernels — dq over q-blocks, (dk, dv) over
-k-blocks — each recomputing P = exp(s - lse) blockwise. The two saved
-values carry ``checkpoint_name``s (``ops.attention.FLASH_RESIDUAL_NAMES``)
-so that a caller's ``jax.checkpoint`` can keep them by policy: a
-rematted layer (``models/bert.py``) then runs the forward kernel once a
-step, not a second time in its backward pass (``_flash_fwd``).
+backward is one Pallas kernel (``_bwd_kernel``) that recomputes
+P = exp(s - lse), dP and dS of a tile once and takes dq, dk and dv from
+them: five products a tile. dk and dv are summed over the innermost
+grid axis in scratch; dq is summed over the *outer* one, so one head's
+dq stays resident in VMEM as the kernel's float32 output block (2 MB at
+8192 x 64, two buffers), and XLA turns, scales and casts it behind the
+call. A query axis too long for that (``_DQ_RESIDENT_BYTES``: past 32k
+tokens at 64 wide) is cut into spans, one launch of the same kernel a
+span, dk and dv summed over the spans in float32 (``_flash_bwd``). The
+two saved values carry ``checkpoint_name``s
+(``ops.attention.FLASH_RESIDUAL_NAMES``) so that a caller's
+``jax.checkpoint`` can keep them by policy: a rematted layer
+(``models/bert.py``) then runs the forward kernel once a step, not a
+second time in its backward pass (``_flash_fwd``).
 
 On the ``cpu`` backend the kernels run in Pallas interpret mode, so the
 CPU test suite exercises the identical code path. Every other backend
@@ -126,13 +137,24 @@ def _padded_len(s):
 # a larger tile skips more coarsely, 39.8 / 15.9 / 13.1 / 12.4: the
 # largest tile wins there too. The float32 [block_q, block_k]
 # intermediates (scores, p, dp, ds: 4 MB each at [1024 x 1024]) compile
-# inside Mosaic's default scoped VMEM limit, so no ``vmem_limit_bytes`` is
-# set; kv blocks of 2048 bought nothing more.
+# inside Mosaic's default scoped VMEM limit in the forward, which sets no
+# ``vmem_limit_bytes``; kv blocks of 2048 bought nothing more.
 # The two values are *caps*: a sequence shorter than a cap takes one
 # block of its own (padded) length. With segment ids a tile can only skip
 # whole, so the tile is also the skip granularity.
 _BLOCK_Q = 1024
 _BLOCK_KV = 1024
+# The backward kernel keeps one head's dq in VMEM, float32 [d, span] in
+# two buffers (Pallas double-buffers an output block): up to this many
+# bytes, 32,768 query rows at d = 64; a longer query axis takes one
+# launch a span (``_flash_bwd``). Beside it the kernel's blocks and tile
+# intermediates take 6.6 MiB at [1024 x 1024] and d = 64 (the TPU
+# compiler's count of the scoped allocation: 10.57 MiB with the 4 MiB of
+# an 8192-row dq), so 16 + 6.6 = 22.6 MiB have to fit the limit: 32 MiB
+# of a v5e core's 128, where Mosaic's default is 16. The raised limit
+# costs nothing at 8192 (PERF.md section 5, PR 36's table).
+_DQ_RESIDENT_BYTES = 16 * 2**20
+_VMEM_LIMIT_BYTES = 32 * 2**20
 
 
 def _blocking(s_pad, cap):
@@ -264,48 +286,35 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, qseg_ref, kseg_ref,
     lse_ref[0] = m_ref[...] + jnp.log(l)
 
 
-def _dq_kernel(q_ref, k_ref, v_ref, bias_ref, qseg_ref, kseg_ref, do_ref,
-               lse_ref, delta_ref, dq_ref, dq_acc_ref, *, scale):
-  """Grid (bh, q-blocks, kv-blocks), kv innermost; dq accumulates in
-  scratch across the kv sweep. Cross-doc tiles contribute exactly zero
-  (P underflows against their -1e9 bias) so they are skipped whole."""
-  j = pl.program_id(2)
+def _bwd_kernel(q_ref, k_ref, v_ref, bias_ref, qseg_ref, kseg_ref, do_ref,
+                lse_ref, delta_ref, dq_ref, dk_ref, dv_ref, dk_acc_ref,
+                dv_acc_ref, *, scale):
+  """Grid (bh, kv-blocks, q-blocks), q innermost: a tile's scores, P, dP
+  and dS are made once and all three gradients taken from them, five
+  products a tile. Cross-doc tiles contribute exactly zero (P underflows
+  against their -1e9 bias) so they are skipped whole.
 
-  @pl.when(j == 0)
-  def _init():
-    dq_acc_ref[...] = jnp.zeros_like(dq_acc_ref)
+  Every gradient is summed *transposed*, ``[d, block]``: a product whose
+  result is ``[block, 64]`` leaves the MXU in half-empty tiles (PERF.md
+  section 5, PR 36's table). dk^T and dv^T accumulate in scratch across
+  the q sweep while the (k, v) block stays resident. dq is summed over the
+  *outer* axis, so it stays in VMEM for the whole head: ``dq_ref`` is the
+  head's float32 ``[q-blocks, d, block_q]`` output block, zeroed at the
+  head's first step (skipped tiles never write) and written back when the
+  head changes.
 
-  def _tile(seg_bias):
-    k_blk = k_ref[0]
-    segs = (qseg_ref[0, 0, :][:, None], kseg_ref[0]) if seg_bias else ()
-    scores = _scores(q_ref[0], k_blk, bias_ref[0], scale, *segs)
-    p = jnp.exp(scores - lse_ref[0])            # lse, delta: [bq, 1]
-    dp = _mxu(do_ref[0], v_ref[0], (1, 1))      # do.v^T
-    ds = p * (dp - delta_ref[0])
-    dq_acc_ref[...] = dq_acc_ref[...] + _mxu(ds.astype(k_blk.dtype), k_blk,
-                                             (1, 0))
+  The tile is key-major, ``[block_k, block_q]``: it enters dq^T = k^T.dS^T
+  as it stands and dv^T = dO^T.P, dk^T = q^T.dS by its minor dimension, as
+  k enters q.k^T. So the per-key rows (bias, kv segment ids) arrive as
+  columns ``[block_k, 1]`` and the per-query ones (lse, delta, q segment
+  ids) as rows ``[1, block_q]`` — which also keeps what the innermost
+  axis fetches every step small (a ``[block_q, 1]`` float32 column moves
+  a whole 128-lane tile a row)."""
+  j, i = pl.program_id(1), pl.program_id(2)
 
-  _for_live_tile(_tile, qseg_ref, kseg_ref)
-
-  @pl.when(j == pl.num_programs(2) - 1)
-  def _finalize():
-    dq_ref[0] = (dq_acc_ref[...] * scale).astype(dq_ref.dtype)
-
-
-def _dkv_kernel(q_ref, k_ref, v_ref, bias_ref, qseg_ref, kseg_ref, do_ref,
-                lse_ref, delta_ref, dk_ref, dv_ref, dk_acc_ref, dv_acc_ref,
-                *, scale):
-  """Grid (bh, kv-blocks, q-blocks), q innermost; dk/dv accumulate in
-  scratch across the q sweep while the (k, v) block stays resident.
-
-  The tile is kept key-major, ``[block_k, block_q]``: dv = P^T.dO and
-  dk = dS^T.q are then plain products of the tile, where a query-major
-  tile would have to be transposed twice a step. So the per-key rows
-  (bias, kv segment ids) arrive as columns ``[block_k, 1]`` and the
-  per-query ones (lse, delta, q segment ids) as rows ``[1, block_q]`` —
-  which also keeps what the innermost axis fetches every step small (a
-  ``[block_q, 1]`` float32 column moves a whole 128-lane tile a row)."""
-  i = pl.program_id(2)
+  @pl.when((j == 0) & (i == 0))
+  def _zero_dq():
+    dq_ref[...] = jnp.zeros_like(dq_ref)
 
   @pl.when(i == 0)
   def _init():
@@ -313,18 +322,18 @@ def _dkv_kernel(q_ref, k_ref, v_ref, bias_ref, qseg_ref, kseg_ref, do_ref,
     dv_acc_ref[...] = jnp.zeros_like(dv_acc_ref)
 
   def _tile(seg_bias):
-    q = q_ref[0]    # [bq, d]
-    do = do_ref[0]
+    q, k, do = q_ref[0], k_ref[0], do_ref[0]  # [bq, d], [bk, d], [bq, d]
     segs = (kseg_ref[0], qseg_ref[0]) if seg_bias else ()
-    scores_t = _scores(k_ref[0], q, bias_ref[0], scale, *segs)  # k.q^T
+    scores_t = _scores(k, q, bias_ref[0], scale, *segs)  # k.q^T
     # Rows beyond the real sequence carry lse from padded-q garbage; their
     # dO is zero (cotangents of padding outputs are never produced by the
     # loss) so they contribute nothing — but guard exp() overflow anyway.
     p_t = jnp.exp(jnp.minimum(scores_t - lse_ref[0], 30.0))
-    dv_acc_ref[...] = dv_acc_ref[...] + _mxu(p_t.astype(do.dtype), do, (1, 0))
+    dv_acc_ref[...] = dv_acc_ref[...] + _mxu(do, p_t.astype(do.dtype), (0, 1))
     dp_t = _mxu(v_ref[0], do, (1, 1))  # v.do^T
-    ds_t = p_t * (dp_t - delta_ref[0])
-    dk_acc_ref[...] = dk_acc_ref[...] + _mxu(ds_t.astype(q.dtype), q, (1, 0))
+    ds_t = (p_t * (dp_t - delta_ref[0])).astype(q.dtype)
+    dk_acc_ref[...] = dk_acc_ref[...] + _mxu(q, ds_t, (0, 1))
+    dq_ref[0, i] = dq_ref[0, i] + _mxu(k, ds_t, (0, 0))
 
   _for_live_tile(_tile, qseg_ref, kseg_ref)
 
@@ -351,19 +360,6 @@ def _plain(kernel):
 # arrays — bias/segment ids ``[b, 1, s]``, lse/delta ``[bh, s_q, 1]``.
 
 
-def _qkv_specs(block_q, block_k, d, heads):
-  """Shared specs for the (bh, q-blocks, kv-blocks) grid used by both
-  the forward and dq pallas_calls — one point of truth so their block
-  shapes and index maps cannot desynchronize. Returns
-  (q_spec, kv_spec, bias_spec, qseg_spec, row_spec)."""
-  q_spec = pl.BlockSpec((1, block_q, d), lambda i, b, j: (i, b, 0))
-  kv_spec = pl.BlockSpec((1, block_k, d), lambda i, b, j: (i, j, 0))
-  bias_spec = pl.BlockSpec((1, 1, block_k), lambda i, b, j: (i // heads, 0, j))
-  qseg_spec = pl.BlockSpec((1, 1, block_q), lambda i, b, j: (i // heads, 0, b))
-  row_spec = pl.BlockSpec((1, block_q, 1), lambda i, b, j: (i, b, 0))
-  return q_spec, kv_spec, bias_spec, qseg_spec, row_spec
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
 def _flash_pair(q, k, v, bias, q_seg, kv_seg, heads):
   """(out, lse) with gradients defined for both outputs — lse cotangents
@@ -385,8 +381,11 @@ def _flash_fwd_impl(q, k, v, bias, q_seg, kv_seg, heads):
   bias = _pad_to(bias, 2, padded_kv, NEG_INF)
   kv_seg = _pad_to(kv_seg, 2, padded_kv, -1.0)
   grid = (bh, padded_q // block_q, padded_kv // block_k)
-  q_spec, kv_spec, bias_spec, qseg_spec, row_spec = _qkv_specs(
-      block_q, block_k, d, heads)
+  q_spec = pl.BlockSpec((1, block_q, d), lambda i, b, j: (i, b, 0))
+  kv_spec = pl.BlockSpec((1, block_k, d), lambda i, b, j: (i, j, 0))
+  bias_spec = pl.BlockSpec((1, 1, block_k), lambda i, b, j: (i // heads, 0, j))
+  qseg_spec = pl.BlockSpec((1, 1, block_q), lambda i, b, j: (i // heads, 0, b))
+  row_spec = pl.BlockSpec((1, block_q, 1), lambda i, b, j: (i, b, 0))
   if q_seg is None:
     kernel, in_specs = _plain(_fwd_kernel), [q_spec, kv_spec, kv_spec,
                                              bias_spec]
@@ -438,7 +437,6 @@ def _flash_bwd(heads, res, cotangents):
   g, g_lse = cotangents
   bh, s_q, d = q.shape
   s_kv = k.shape[1]
-  segmented = q_seg is not None
   (block_q, padded_q), (block_k, padded_kv) = _tile_blocks(s_q, s_kv)
   g = g.astype(q.dtype)
   # d(out)/dS = P(delta-terms); d(lse)/dS = P — so an lse cotangent folds
@@ -448,77 +446,75 @@ def _flash_bwd(heads, res, cotangents):
   delta = delta - g_lse.astype(jnp.float32)
   # Whole blocks on both axes. A padded query row has q = dO = delta = 0
   # and lse = 0: its P is finite and its dS and P^T.dO terms are zeros.
-  q_padded, g = _pad_to(q, 1, padded_q), _pad_to(g, 1, padded_q)
-  lse, delta = _pad_to(lse, 1, padded_q), _pad_to(delta, 1, padded_q)
-  q_seg_padded = _pad_to(q_seg, 2, padded_q, -1.0)
-  k, v = _pad_to(k, 1, padded_kv), _pad_to(v, 1, padded_kv)
-  bias_padded = _pad_to(bias, 2, padded_kv, NEG_INF)
-  kv_seg_padded = _pad_to(kv_seg, 2, padded_kv, -1.0)
-  scale = 1.0 / d**0.5
-
-  # dq: grid (bh, q-blocks, kv-blocks), kv innermost.
-  q_spec, kv_spec, bias_spec, qseg_spec, row_blocked = _qkv_specs(
-      block_q, block_k, d, heads)
-  if segmented:
-    dq_kernel = _dq_kernel
-    dq_specs = [q_spec, kv_spec, kv_spec, bias_spec, qseg_spec, bias_spec,
-                q_spec, row_blocked, row_blocked]
-    dq_inputs = (q_padded, k, v, bias_padded, q_seg_padded, kv_seg_padded, g,
-                 lse, delta)
-  else:
-    dq_kernel = _plain(_dq_kernel)
-    dq_specs = [q_spec, kv_spec, kv_spec, bias_spec, q_spec,
-                row_blocked, row_blocked]
-    dq_inputs = (q_padded, k, v, bias_padded, g, lse, delta)
-  dq = pl.pallas_call(
-      functools.partial(dq_kernel, scale=scale),
-      grid=(bh, padded_q // block_q, padded_kv // block_k),
-      in_specs=dq_specs,
-      out_specs=q_spec,
-      out_shape=jax.ShapeDtypeStruct((bh, padded_q, d), q.dtype),
-      scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-      interpret=_interpret(),
-      name='flash_dq',
-  )(*dq_inputs)
-
-  # dk/dv: grid (bh, kv-blocks, q-blocks), q innermost; the (k, v) block
-  # stays resident across the q sweep. The tile is key-major (see
-  # ``_dkv_kernel``): per-key rows go in as columns, per-query columns as
-  # rows (a singleton axis swapped: one small copy outside the kernel).
+  # The tile is key-major (``_bwd_kernel``): per-key rows go in as columns,
+  # per-query columns as rows (a singleton axis swapped: one small copy
+  # outside the kernel).
   turned = lambda x: jnp.swapaxes(x, 1, 2)  # row <-> column
+  q_pad, g = _pad_to(q, 1, padded_q), _pad_to(g, 1, padded_q)
+  lse_row = turned(_pad_to(lse, 1, padded_q))
+  delta_row = turned(_pad_to(delta, 1, padded_q))
+  k, v = _pad_to(k, 1, padded_kv), _pad_to(v, 1, padded_kv)
+  bias_col = turned(_pad_to(bias, 2, padded_kv, NEG_INF))
+  scale = 1.0 / d**0.5
   q_by_i = pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0))
   kv_by_j = pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0))
   kcol_by_j = pl.BlockSpec((1, block_k, 1), lambda b, j, i: (b // heads, j, 0))
-  qseg_by_i = pl.BlockSpec((1, 1, block_q), lambda b, j, i: (b // heads, 0, i))
   qrow_by_i = pl.BlockSpec((1, 1, block_q), lambda b, j, i: (b, 0, i))
-  if segmented:
-    dkv_kernel = _dkv_kernel
-    dkv_specs = [q_by_i, kv_by_j, kv_by_j, kcol_by_j, qseg_by_i, kcol_by_j,
-                 q_by_i, qrow_by_i, qrow_by_i]
-    dkv_inputs = (q_padded, k, v, turned(bias_padded), q_seg_padded,
-                  turned(kv_seg_padded), g, turned(lse), turned(delta))
-  else:
-    dkv_kernel = _plain(_dkv_kernel)
-    dkv_specs = [q_by_i, kv_by_j, kv_by_j, kcol_by_j, q_by_i,
-                 qrow_by_i, qrow_by_i]
-    dkv_inputs = (q_padded, k, v, turned(bias_padded), g, turned(lse),
-                  turned(delta))
-  dk, dv = pl.pallas_call(
-      functools.partial(dkv_kernel, scale=scale),
-      grid=(bh, padded_kv // block_k, padded_q // block_q),
-      in_specs=dkv_specs,
-      out_specs=[kv_by_j, kv_by_j],
-      out_shape=[
-          jax.ShapeDtypeStruct((bh, padded_kv, d), q.dtype),
-          jax.ShapeDtypeStruct((bh, padded_kv, d), q.dtype),
-      ],
-      scratch_shapes=[
-          pltpu.VMEM((block_k, d), jnp.float32),
-          pltpu.VMEM((block_k, d), jnp.float32),
-      ],
-      interpret=_interpret(),
-      name='flash_dkv',
-  )(*dkv_inputs)
+  kernel, seg_specs = _plain(_bwd_kernel), []
+  if q_seg is not None:
+    kernel = _bwd_kernel
+    seg_specs = [pl.BlockSpec((1, 1, block_q),
+                              lambda b, j, i: (b // heads, 0, i)), kcol_by_j]
+    qseg_row = _pad_to(q_seg, 2, padded_q, -1.0)
+    kseg_col = turned(_pad_to(kv_seg, 2, padded_kv, -1.0))
+
+  # One launch a span of the query axis, the longest whose dq stays
+  # resident (``_DQ_RESIDENT_BYTES``: two buffers of [d, span] float32);
+  # dk and dv are summed over the spans below, in float32 where there is
+  # more than one.
+  span = max(1, _DQ_RESIDENT_BYTES // (2 * 4 * d * block_q)) * block_q
+  starts = range(0, padded_q, span)
+  share_dtype = q.dtype if len(starts) == 1 else jnp.float32
+
+  def launch(lo):
+    """(dq^T / scale in float32, dk^T, dv^T) of the query rows from
+    ``lo`` on, one span, against every key: dq whole, of dk and dv that
+    span's share, each a block at a time as ``[d, block]``."""
+    n = min(span, padded_q - lo)
+    rows = lambda x: x[:, lo:lo + n]
+    cols = lambda x: x[:, :, lo:lo + n]
+    segs = () if q_seg is None else (cols(qseg_row), kseg_col)
+    kt_by_j = pl.BlockSpec((1, d, block_k), lambda b, j, i: (b, 0, j))
+    return pl.pallas_call(
+        functools.partial(kernel, scale=scale),
+        grid=(bh, padded_kv // block_k, n // block_q),
+        in_specs=[q_by_i, kv_by_j, kv_by_j, kcol_by_j, *seg_specs, q_by_i,
+                  qrow_by_i, qrow_by_i],
+        out_specs=[pl.BlockSpec((1, n // block_q, d, block_q),
+                                lambda b, j, i: (b, 0, 0, 0)),
+                   kt_by_j, kt_by_j],
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, n // block_q, d, block_q), jnp.float32),
+            jax.ShapeDtypeStruct((bh, d, padded_kv), share_dtype),
+            jax.ShapeDtypeStruct((bh, d, padded_kv), share_dtype),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((d, block_k), jnp.float32),
+            pltpu.VMEM((d, block_k), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
+        interpret=_interpret(),
+        name='flash_bwd',
+    )(rows(q_pad), k, v, bias_col, *segs, rows(g), cols(lse_row),
+      cols(delta_row))
+
+  dq_t, dk_t, dv_t = zip(*map(launch, starts))
+  # Turned back by XLA behind the call; dq is scaled and cast here, where
+  # dk is in the kernel's last step: its sum ends only with the head.
+  dq = jnp.swapaxes(jnp.concatenate(dq_t, axis=1), 2, 3)
+  dq = (dq.reshape(bh, padded_q, d) * scale).astype(q.dtype)
+  dk, dv = (turned(sum(x[1:], x[0])).astype(q.dtype) for x in (dk_t, dv_t))
   return (dq[:, :s_q, :], dk[:, :s_kv, :], dv[:, :s_kv, :],
           jnp.zeros_like(bias),
           None if q_seg is None else jnp.zeros_like(q_seg),

@@ -9,14 +9,25 @@ import pytest
 from lddl_tpu.ops.flash_attention import flash_attention
 
 
-def _dense_reference(q, k, v, mask):
+def _dense_with_lse(q, k, v, mask, q_seg=None, kv_seg=None):
+  """(out, lse) of the dense path in float32, with the key-side mask and
+  the block-diagonal one of the two id arrays."""
   scale = 1.0 / (q.shape[-1] ** 0.5)
   s = jnp.einsum('bhqd,bhkd->bhqk', q.astype(jnp.float32),
                  k.astype(jnp.float32)) * scale
-  if mask is not None:
-    s = s + jnp.where(mask, 0.0, -1e9)[:, None, None, :]
-  p = jax.nn.softmax(s, axis=-1)
-  return jnp.einsum('bhqk,bhkd->bhqd', p, v.astype(jnp.float32))
+  s = s + jnp.where(mask, 0.0, -1e9)[:, None, None, :]
+  if q_seg is not None:
+    same = q_seg[:, None, :, None] == kv_seg[:, None, None, :]
+    s = s + jnp.where(same, 0.0, -1e9)
+  lse = jax.nn.logsumexp(s, axis=-1)
+  p = jnp.exp(s - lse[..., None])
+  return jnp.einsum('bhqk,bhkd->bhqd', p, v.astype(jnp.float32)), lse
+
+
+def _dense_reference(q, k, v, mask):
+  if mask is None:
+    mask = jnp.ones((k.shape[0], k.shape[2]), jnp.int32)
+  return _dense_with_lse(q, k, v, mask)[0]
 
 
 def _inputs(b, h, s, d, seed=0, masked=True):
@@ -153,7 +164,7 @@ def test_bf16_gradients_match_dense(monkeypatch, segmented):
 
 def _kernel_products(dtype, segmented):
   """{kernel name: [(operand dtypes, dimension numbers), ...]} and the
-  set of all primitives inside the three traced kernels, for a forward
+  set of all primitives inside the traced kernels, for a forward
   and backward pass at ``dtype``."""
   b, h, s, d = 1, 2, 256, 64
   x = jnp.ones((b, h, s, d), dtype)
@@ -199,20 +210,28 @@ def _kernel_products(dtype, segmented):
 @pytest.mark.parametrize('dtype', ['bfloat16', 'float32'])
 def test_kernels_feed_the_mxu_the_input_dtype(dtype, segmented):
   """The mechanism engages: with bfloat16 inputs no product inside
-  flash_fwd / flash_dq / flash_dkv has a float32 operand; with float32
-  inputs every operand is float32 (today's arithmetic); either way every
-  product sums in float32 and no tile is transposed."""
+  flash_fwd / flash_bwd has a float32 operand; with float32 inputs every
+  operand is float32 (today's arithmetic); either way every product sums
+  in float32, no tile is transposed by an operation of its own, and the
+  backward makes its five products a tile, each gradient's transposed."""
   products, primitives = _kernel_products(jnp.dtype(dtype), segmented)
-  assert set(products) == {'flash_fwd', 'flash_dq', 'flash_dkv'}
-  per_tile = {'flash_fwd': 2, 'flash_dq': 3, 'flash_dkv': 4}
+  minor, major = (1,), (0,)
+  per_tile = {
+      # q.k^T (both minor dimensions), p.v (a plain product)
+      'flash_fwd': [(minor, minor), (minor, major)],
+      # k.q^T, dv^T = dO^T.P, v.dO^T, dk^T = q^T.dS, dq^T = k^T.dS^T: the
+      # [block_k, block_q] tile contracts its minor dimension or stands
+      # as it is; what is turned is a [block, d] operand.
+      'flash_bwd': [(minor, minor), (major, minor), (minor, minor),
+                    (major, minor), (major, major)],
+  }
+  assert set(products) == set(per_tile)
   bodies = 2 if segmented else 1  # interior and boundary tiles
   for name, found in products.items():
-    assert len(found) == per_tile[name] * bodies, (name, found)
-    for operands, (contract, batch) in found:
+    assert [contract for _, (contract, _) in found] == per_tile[name] * bodies
+    for operands, (_, batch) in found:
       assert operands == (dtype, dtype), (name, operands)
       assert batch == ((), ())
-      # q.k^T-like (both minor dimensions) or a plain product.
-      assert contract in (((1,), (1,)), ((1,), (0,))), (name, contract)
   assert 'transpose' not in primitives
 
 
@@ -274,6 +293,84 @@ def test_lse_cotangent_merge_matches_dense():
                                atol=2e-4, err_msg=f'd{name}')
 
 
+def _ids(*runs):
+  """[1, s] ids from (id, length) runs."""
+  return np.concatenate([np.full(n, i, np.int32) for i, n in runs])[None, :]
+
+
+# Each case: (s_q, s_kv, q ids, kv ids, whether lse has a cotangent, dtype,
+# q blocks a span). Ids of -1 are padding and come with a masked key.
+_BACKWARD_CASES = {
+    # The ring's local block: the queries of one shard against the keys
+    # of another, other lengths and other documents.
+    'unequal-lengths': (72, 200, _ids((0, 40), (1, 32)),
+                        _ids((0, 100), (1, 60), (2, 40)), False, 'float32',
+                        None),
+    # The last q block is all padding: every one of its tiles is skipped,
+    # and its dq rows are never written.
+    'skipped-rows': (384, 384, _ids((0, 200), (1, 56), (-1, 128)), None,
+                     False, 'float32', None),
+    'lse-cotangent': (200, 200, None, None, True, 'float32', None),
+    'padding-keys': (200, 200, _ids((0, 90), (1, 70), (-1, 40)), None, True,
+                     'float32', None),
+    'bfloat16': (256, 256, _ids((0, 150), (1, 106)), None, False,
+                 'bfloat16', None),
+    # Spans of two q blocks and of one: dk and dv summed over two launches.
+    'two-spans': (384, 384, _ids((0, 300), (1, 84)), None, True, 'float32',
+                  2),
+}
+
+
+@pytest.mark.parametrize('case', list(_BACKWARD_CASES))
+def test_backward_cases_match_dense(monkeypatch, case):
+  """The one backward kernel against ``jax.grad`` of the dense path, in
+  the corners its callers reach (blocks of 128, several on both axes)."""
+  from lddl_tpu.ops import flash_attention as fa
+  s_q, s_kv, q_ids, kv_ids, lse_cot, dtype, span_blocks = _BACKWARD_CASES[case]
+  monkeypatch.setattr(fa, '_BLOCK_Q', 128)
+  monkeypatch.setattr(fa, '_BLOCK_KV', 128)
+  d = 64
+  if span_blocks:
+    monkeypatch.setattr(fa, '_DQ_RESIDENT_BYTES', span_blocks * 2 * 4 * d * 128)
+  if q_ids is not None and kv_ids is None:
+    kv_ids = q_ids
+  rng = np.random.default_rng(41)
+  q, k, v, cot = (jnp.asarray(rng.standard_normal((1, 1, n, d),
+                                                  dtype=np.float32), dtype)
+                  for n in (s_q, s_kv, s_kv, s_q))
+  mask = jnp.ones((1, s_kv), jnp.int32)
+  q_seg = kv_seg = None
+  if q_ids is not None:
+    q_seg, kv_seg = jnp.asarray(q_ids), jnp.asarray(kv_ids)
+    mask = (kv_seg >= 0).astype(jnp.int32)
+    # No cotangent reaches a padding query's output or lse.
+    cot = cot * (q_seg >= 0)[:, None, :, None].astype(cot.dtype)
+  lse_w = jnp.asarray(rng.standard_normal((1, 1, s_q), dtype=np.float32))
+  if q_seg is not None:
+    lse_w = lse_w * (q_seg >= 0)[:, None, :]
+
+  def loss(attend):
+    def fn(q, k, v):
+      out, lse = attend(q, k, v, mask, q_seg, kv_seg)
+      total = jnp.sum(out.astype(jnp.float32) * cot.astype(jnp.float32))
+      return total + jnp.sum(lse * lse_w) if lse_cot else total
+    return fn
+
+  flash = jax.grad(loss(fa.flash_attention_with_lse), argnums=(0, 1, 2))(
+      q, k, v)
+  dense = jax.grad(loss(_dense_with_lse), argnums=(0, 1, 2))(q, k, v)
+  tol = dict(rtol=2e-4, atol=2e-4) if dtype == 'float32' else dict(
+      rtol=3e-2, atol=3e-2)
+  for got, want, name in zip(flash, dense, 'qkv'):
+    assert got.dtype == jnp.dtype(dtype)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               err_msg=f'd{name}', **tol)
+  if case == 'skipped-rows':
+    assert not np.asarray(flash[0])[:, :, 256:].any()
+    assert not np.asarray(flash[1])[:, :, 256:].any()
+
+
 def test_ring_flash_matches_dense():
   from lddl_tpu.parallel import make_mesh
   from lddl_tpu.parallel.ring import make_ring_attention
@@ -287,6 +384,17 @@ def test_ring_flash_matches_dense():
   ref = _dense_reference(q, k, v, mask)
   np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-4,
                              atol=2e-4)
+  # The ring's local block is the one caller of the backward kernel with
+  # an lse cotangent and k/v that are not the queries' own.
+  cot = jnp.asarray(
+      np.random.default_rng(8).standard_normal(q.shape, dtype=np.float32))
+  ring = jax.grad(lambda *a: jnp.sum(fn(*a, mask, None) * cot),
+                  argnums=(0, 1, 2))(q, k, v)
+  dense = jax.grad(lambda *a: jnp.sum(_dense_reference(*a, mask) * cot),
+                   argnums=(0, 1, 2))(q, k, v)
+  for a, b, name in zip(ring, dense, 'qkv'):
+    np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4,
+                               atol=2e-4, err_msg=f'd{name}')
 
 
 def test_make_flash_attention_sharded():
@@ -311,7 +419,7 @@ def test_make_flash_attention_rejects_seq_mesh():
 @pytest.mark.parametrize('s', [128, 600])
 def test_one_tile_rule_for_every_launch(monkeypatch, s):
   """``_tile_blocks`` gives one answer a shape: the forward launch, the
-  two backward launches and ``count_skippable_tiles``' default grid all
+  backward launch and ``count_skippable_tiles``' default grid all
   run on it, with or without segment ids (caps made small, so that 600
   is five q blocks by three kv blocks)."""
   from lddl_tpu.ops import flash_attention as fa
@@ -336,8 +444,7 @@ def test_one_tile_rule_for_every_launch(monkeypatch, s):
   for ids in (None, jnp.asarray(seg)):
     jax.grad(lambda q: jnp.sum(
         fa.flash_attention(q, k, v, mask, ids, ids)))(q)
-  assert grids == {'flash_fwd': {(2, n_q, n_k)}, 'flash_dq': {(2, n_q, n_k)},
-                   'flash_dkv': {(2, n_k, n_q)}}
+  assert grids == {'flash_fwd': {(2, n_q, n_k)}, 'flash_bwd': {(2, n_k, n_q)}}
 
 
 @pytest.mark.parametrize('block_q', [128, 256, 512])

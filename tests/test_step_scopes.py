@@ -191,7 +191,7 @@ def test_what_no_module_owns_in_the_encoder(names, request):
     # itself lowers to nothing.
     ('jit(step)/transpose(jvp(BertForPretraining))/encoder/while/body/'
      'closed_call/layers.body/layers.body/checkpoint/layers/attention/'
-     'flash_dq/pallas_call', ('attention', 'backward')),
+     'flash_bwd/pallas_call', ('attention', 'backward')),
     ('jit(step)/transpose(jvp(BertForPretraining))/encoder/while/body/'
      'closed_call/layers.body/layers.body/checkpoint/rematted_computation/'
      'layers/attention/reshape', ('attention', 'recompute')),
