@@ -16,7 +16,8 @@ next_sentence_labels). Design choices for the MXU/XLA:
     ``(5 * hidden_size + intermediate_size) * 2`` bytes a token and layer;
     it remakes what is O(seq^2) or element-wise (the dense path's scores
     and softmax, GELU, the layer norms, the dropout masks) and the ``out``
-    projection, which is cheaper remade than kept;
+    projection, which is cheaper remade than kept; without remat the FFN's
+    GELU alone is remade, from its input;
   - static shapes everywhere — the loader's per-bin padding means one
     compiled program per bin;
   - attention is pluggable through ``BertConfig.attention_impl``;
@@ -36,6 +37,7 @@ block needs a single all-reduce (inserted by GSPMD from the param specs in
 """
 
 import dataclasses
+import functools
 from typing import Any
 
 import flax.linen as nn
@@ -126,8 +128,18 @@ class Layer(nn.Module):
     x = nn.LayerNorm(dtype=cfg.dtype, name='attention_norm')(x)
     h = checkpoint_name(_dense(cfg.intermediate_size, cfg, 'intermediate')(x),
                         'intermediate_out')
+    gelu = functools.partial(nn.gelu, approximate=True)
+    if not cfg.remat:
+      # Nothing else remakes it here: its backward keeps its input alone and
+      # remakes tanh and its derivative, where its linearisation would keep
+      # five more [tokens, intermediate_size] values a layer. Under remat
+      # the policy decides. The scan keeps the two passes apart, so no CSE
+      # barrier is needed; with one, the TPU compiler does not fuse the
+      # remade GELU into the `output` gemm's backward and writes its
+      # derivative out (tests/test_deviceless_compile.py).
+      gelu = jax.checkpoint(gelu, prevent_cse=False)
     with jax.named_scope('gelu'):
-      h = nn.gelu(h, approximate=True)
+      h = gelu(h)
     h = checkpoint_name(_dense(cfg.hidden_size, cfg, 'output')(h),
                         'output_out')
     h = nn.Dropout(cfg.dropout_rate)(h, deterministic=deterministic)
