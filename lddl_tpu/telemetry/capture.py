@@ -12,7 +12,10 @@ otherwise needs xprof for:
     ``jax.profiler.TraceAnnotation`` sink of ``tracer.phase``, see
     :mod:`.trace`), which sit on a ``/host:CPU`` thread line on the
     device's clock. A gap under several phases is split by overlap; what
-    no phase covers is ``unattributed``.
+    no phase covers is ``unattributed``. Beside the phases, and never
+    one of them: what the feed's thread did meanwhile, and how much of
+    the gap lay under a pause of Python's collector (the ``host.gc``
+    spans of :mod:`.stalls`, from any thread).
   - **Which part of the model was the chip busy with?** Every device
     operation inside a step program is billed to a module class
     (:data:`CLASSES`) and, independently, to a pass (:data:`PASSES`),
@@ -46,6 +49,8 @@ import os
 import re
 import statistics
 import time
+
+from .stalls import GC_SPAN  # a collector's pause: no phase of the loop
 
 DEVICE_PLANE_PREFIX = '/device:TPU:'
 HOST_PLANE_PREFIX = '/host:'
@@ -305,13 +310,14 @@ def _extract_device(buf, name, lines, metas, stat_names):
 
 
 def _extract_host(buf, lines, metas, stat_names):
-  """The thread lines that hold a phase or the runtime's enqueue span. A
-  host plane holds every call the profiler's Python tracer saw, so an
-  event is read past its first field only when that names one of them."""
+  """The thread lines that hold a phase, a collector's pause or the
+  runtime's enqueue span. A host plane holds every call the profiler's
+  Python tracer saw, so an event is read past its first field only when
+  that names one of them."""
   phases = {}
   for meta_id, span in metas.items():
     name = _metadata(buf, span)['name']
-    if name.startswith(PHASE_PREFIXES) or name == ENQUEUE:
+    if name.startswith(PHASE_PREFIXES) or name in (ENQUEUE, GC_SPAN):
       phases[meta_id] = name
   out = []
   if not phases:
@@ -340,8 +346,8 @@ def extract(path):
   start_ns, duration_ns]`` per executed operation) and ``modules``
   (``[name, start_ns, duration_ns]`` per executed program). From the host
   planes: every thread line that holds a phase (or the runtime's
-  ``DoEnqueueProgram``), as ``[name, start_ns, duration_ns, step or
-  None]`` rows."""
+  ``DoEnqueueProgram``, or a ``host.gc`` pause), as ``[name, start_ns,
+  duration_ns, step or None]`` rows."""
   with open(path, 'rb') as f:
     buf = f.read()
   out = {'devices': [], 'host': []}
@@ -377,17 +383,20 @@ def _overlaps(spans, starts, lo, hi):
 
 
 def _threads(host):
-  """``(main, feed)``: the phase spans of the thread that runs
-  ``TrainLoop.run`` (the line that holds ``train.step``) and those of the
-  other threads (the device feed's producer), each sorted by start."""
-  main, feed = [], []
+  """``(main, feed, pauses)``: the phase spans of the thread that runs
+  ``TrainLoop.run`` (the line that holds ``train.step``), those of the
+  other threads (the device feed's producer), and the collector's pauses
+  of every thread, each sorted by start."""
+  main, feed, pauses = [], [], []
   for line in host:
-    rows = [r for r in line['events'] if r[0] != ENQUEUE]
+    rows = [r for r in line['events'] if r[0] not in (ENQUEUE, GC_SPAN)]
+    pauses.extend(r for r in line['events'] if r[0] == GC_SPAN)
     if not main and any(r[0] == PARENT_PHASE for r in rows):
       main = rows
     else:
       feed.extend(rows)
-  return sorted(main, key=lambda r: r[1]), sorted(feed, key=lambda r: r[1])
+  return tuple(sorted(rows, key=lambda r: r[1])
+               for rows in (main, feed, pauses))
 
 
 def _device_clock_shift(steps, enqueues, main):
@@ -416,7 +425,7 @@ def _device_clock_shift(steps, enqueues, main):
   return min(own) - steps[0][0]
 
 
-def _summarize_device(device, main, feed, enqueues):
+def _summarize_device(device, main, feed, pauses, enqueues):
   names = [(n, cat, *classify(op_name)) for n, cat, op_name in
            device['names']]
   steps = sorted((s, s + d) for n, s, d in device['modules']
@@ -454,6 +463,7 @@ def _summarize_device(device, main, feed, enqueues):
   parent_starts = [r[1] for r in parents]
   first_step = parents[0] if parents else None
   feed_starts = [r[1] for r in feed]
+  pause_starts = [r[1] for r in pauses]
   gaps = []
   for (_, lo), (hi, _) in zip(steps, steps[1:]):
     # Back-to-back programs (the next one was queued before this one
@@ -474,14 +484,19 @@ def _summarize_device(device, main, feed, enqueues):
     meanwhile = collections.Counter()
     for name, over in _overlaps(feed, feed_starts, lo, hi):
       meanwhile[name] += over
-    gaps.append({
+    gap = {
         'ns': hi - lo, 'step': step, 'phases': dict(phases),
         'feed': dict(meanwhile),
         'epoch_turn': phases.get(EPOCH_TURN, 0) > 0,
         'first_step': bool(
             first_step and
             first_step[1] < hi and first_step[1] + first_step[2] > lo),
-    })
+    }
+    if pauses:
+      # Collections never overlap one another: the parts add up.
+      gap['gc'] = sum(over for _, over in
+                      _overlaps(pauses, pause_starts, lo, hi))
+    gaps.append(gap)
   return {
       'plane': device['plane'], 'steps': len(steps),
       'step_programs_ns': sum(e - s for s, e in steps),
@@ -497,10 +512,10 @@ def summarize(events):
   """The capture summary of :func:`extract`'s output: per device, over
   whole ``jit_step`` programs, the gaps by phase and the busy time by
   module class and by pass."""
-  main, feed = _threads(events['host'])
+  main, feed, pauses = _threads(events['host'])
   enqueues = [row[1] for line in events['host'] for row in line['events']
               if row[0] == ENQUEUE]
-  devices = [d for d in (_summarize_device(dev, main, feed, enqueues)
+  devices = [d for d in (_summarize_device(dev, main, feed, pauses, enqueues)
                          for dev in events['devices']) if d]
   return {'devices': devices,
           'phases_seen': sorted({r[0] for r in main})}
@@ -563,9 +578,11 @@ def format_table(summary):
            (g['first_step'], "the capture's first step")) if flag)
       meanwhile = ', '.join(f'{n} {ns / 1e6:.3f}'
                             for n, ns in sorted(g['feed'].items()))
+      collector = (f'; the collector: {g["gc"] / 1e6:.3f}' if 'gc' in g
+                   else '')
       lines.append(f'    longest: {g["ns"] / 1e6:.3f} ms before step '
                    f'{g["step"]}{marks}: {parts}; the feed\'s thread '
-                   f'meanwhile: {meanwhile or "no span"}')
+                   f'meanwhile: {meanwhile or "no span"}{collector}')
     for key, order in (('classes', CLASSES), ('passes', PASSES)):
       shares = '  '.join(
           f'{name} {100.0 * d[key][name] / max(d["busy_ns"], 1):.1f}'

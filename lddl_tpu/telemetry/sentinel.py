@@ -19,6 +19,12 @@ device reads, no threads, no I/O on the hot path:
                         grad norm fires unconditionally
   ``data_stall``        one batch wait exceeded a wall-time budget —
                         the input pipeline wedged, not the model
+  ``step_stall``        the train loop's stall rule (``stalls.py``:
+                        the interval to finding a step finished over
+                        twice its shape's running median) judged the
+                        step stalled; the incident keeps the stall's
+                        facts and the ring tracer's spans of every
+                        thread inside the interval
   ``hbm_headroom``      roofline ``sample_hbm`` headroom collapsed
                         below a floor (probed every N steps; the probe
                         is the only detector that costs a device query)
@@ -63,7 +69,8 @@ _ENV = 'LDDL_SENTINEL'
 
 #: Every detector, in the order ``LDDL_SENTINEL=1`` enables them.
 DETECTORS = ('nonfinite_loss', 'loss_spike', 'grad_spike', 'data_stall',
-             'hbm_headroom', 'serve_backlog', 'ledger_divergence')
+             'step_stall', 'hbm_headroom', 'serve_backlog',
+             'ledger_divergence')
 
 #: How many incident registrations ``note_incident`` retains.
 MAX_INCIDENT_NOTES = 16
@@ -93,7 +100,8 @@ class NoopSentinel:
   detectors = ()
   triggers = 0
 
-  def observe_step(self, step, loss=None, grad_norm=None, data_wait=None):
+  def observe_step(self, step, loss=None, grad_norm=None, data_wait=None,
+                   stall=None):
     return None
 
   def observe_backlog(self, backlog):
@@ -216,11 +224,13 @@ class Sentinel:
 
   # -- observations
 
-  def observe_step(self, step, loss=None, grad_norm=None, data_wait=None):
+  def observe_step(self, step, loss=None, grad_norm=None, data_wait=None,
+                   stall=None):
     """One training step's signals. Returns a trigger dict when a
     detector fires (at most one per call; earlier detectors win) or
-    None. Never raises — a sentinel must not take down the run it
-    watches."""
+    None. ``stall`` is the loop's stall verdict for the step: the facts
+    :class:`~.stalls.StepWatch` returned, or None. Never raises — a
+    sentinel must not take down the run it watches."""
     step = int(step)
     try:
       faults.inject('sentinel.trigger', step=step)
@@ -261,6 +271,12 @@ class Sentinel:
           'data_stall', step,
           f'batch wait {float(data_wait):.1f}s exceeded the '
           f'{self.stall_sec:.0f}s stall budget', value=float(data_wait))
+    if fired is None and stall is not None and 'step_stall' in det:
+      fired = self._fire(
+          'step_stall', step,
+          f'step interval {1e3 * stall["interval_s"]:.1f} ms over twice '
+          f'its shape\'s running median {1e3 * stall["median_s"]:.1f} ms',
+          value=stall['interval_s'], stats=stall)
     if (fired is None and 'hbm_headroom' in det and self.hbm_every > 0
         and step % self.hbm_every == 0):
       fired = self._check_hbm(step)

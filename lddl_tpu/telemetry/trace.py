@@ -127,7 +127,7 @@ class NoopTracer:
       return _annotation(name, step)
     return _NOOP_SPAN
 
-  def complete(self, name, start, duration, tid=None, args=None):
+  def complete(self, name, start, duration, tid=None, args=None, flush=True):
     pass
 
   def instant(self, name, args=None):
@@ -239,10 +239,16 @@ class Tracer:
       return _BothSinks(_annotation(name, step), span)
     return span
 
-  def complete(self, name, start, duration, tid=None, args=None):
-    """A 'X' event with explicit monotonic ``start`` and ``duration``."""
-    self._record(('X', name, start, duration,
-                  threading.get_ident() if tid is None else tid, args))
+  def complete(self, name, start, duration, tid=None, args=None, flush=True):
+    """A 'X' event with explicit monotonic ``start`` and ``duration``.
+    ``flush=False`` only appends it: the call never writes the buffer out
+    (for a caller that must do no file I/O, such as a ``gc`` callback)."""
+    ev = ('X', name, start, duration,
+          threading.get_ident() if tid is None else tid, args)
+    if flush:
+      self._record(ev)
+    else:
+      self._events.append(ev)
 
   def instant(self, name, args=None):
     self._record(('i', name, time.monotonic(), None,

@@ -58,6 +58,7 @@ class CompiledStepCache:
     self._costs = {}   # key -> (process flops, process bytes) per step
     self.hits = 0
     self.misses = 0
+    self.last_key = None  # the shape of the step called last
     self.retrace_seconds = 0.0
     # Process-total costs of the most recently executed entry (the MFU
     # numerator); None until a compiled entry reported a cost model.
@@ -76,7 +77,7 @@ class CompiledStepCache:
         sorted((k, tuple(v.shape), str(v.dtype)) for k, v in batch.items()))
 
   def __call__(self, params, opt_state, rng, batch):
-    key = self.key_of(batch)
+    key = self.last_key = self.key_of(batch)
     fn = self._compiled.get(key)
     if fn is None:
       t0 = time.perf_counter()
@@ -484,6 +485,7 @@ class TrainLoop:
     from ..telemetry.profiling import get_step_profiler
     from ..telemetry.sentinel import get_sentinel
     from ..telemetry.server import maybe_start_monitor
+    from ..telemetry.stalls import StepWatch, spans_during
     from ..telemetry.trace import get_tracer
     from .elastic import (AsyncCheckpointWriter, PreemptionGuard,
                           async_ckpt_enabled)
@@ -541,6 +543,7 @@ class TrainLoop:
     # steps and trails it by one while a step is in flight.
     next_step = self.step
     in_flight = None
+    t_pull = None  # the first pull since the last step was found finished
 
     def open_stream():
       # The flight recorder tees the *host* iterator (device arrays
@@ -551,13 +554,19 @@ class TrainLoop:
                                   ordinal0=next_step),
           mesh=self.mesh, size=prefetch)
 
-    def read_loss(launched):
+    def read_loss(launched, queued=None):
       """Blocks until the device has finished ``launched``: the device
-      sync, with the next step (if one was launched) already queued."""
+      sync, with ``queued`` (the next step, None in a drain) already on
+      the device. Where it returns the step is found finished: the stall
+      watch judges the interval since the last such time."""
       t_read = time.perf_counter()
       with phase('train.loss_read', launched.step):
         loss = float(launched.metrics['loss'])
-      loss_read_h.observe(time.perf_counter() - t_read)
+      t_found = time.perf_counter()
+      loss_read_h.observe(t_found - t_read)
+      launched.stall = watch.found(launched.step, launched.key,
+                                   self.step_fn.misses, t_read, t_found,
+                                   queued)
       return loss
 
     def observe(launched, loss, drained=False):
@@ -583,11 +592,15 @@ class TrainLoop:
           self.stop_reason = 'nonfinite_loss'
         trigger = sentinel.observe_step(step_no, loss=loss,
                                         grad_norm=grad_norm,
-                                        data_wait=launched.data_wait)
+                                        data_wait=launched.data_wait,
+                                        stall=launched.stall)
         flight.record_step(step_no, loss=loss, grad_norm=grad_norm,
                            data_wait=launched.data_wait)
         if trigger is not None:
-          incident = flight.capture(trigger)
+          extra = None
+          if trigger['detector'] == 'step_stall':
+            extra = {'spans': spans_during(tracer, launched.stall)}
+          incident = flight.capture(trigger, extra)
           if incident:
             print(f'sentinel: {trigger["detector"]} fired at step '
                   f'{step_no} — incident captured to {incident}')
@@ -657,8 +670,9 @@ class TrainLoop:
       """Wait for the step in flight and observe it: afterwards the
       device is idle and ``self.step`` describes ``self.params``, so this
       is where the in-loop checkpoint is written."""
-      nonlocal in_flight
+      nonlocal in_flight, t_pull
       launched, in_flight = in_flight, None
+      t_pull = None
       loss = read_loss(launched)
       if launched.t_end is None:
         launched.t_end = time.perf_counter()
@@ -669,6 +683,8 @@ class TrainLoop:
         flight.note_checkpoint(ckpt_dir, self.step)
 
     stream = None
+    # Python's collector is timed (and traced) while the loop runs.
+    watch = StepWatch(tele, tracer).install()
     try:
       if next_step < max_steps:
         stream = open_stream()
@@ -695,6 +711,8 @@ class TrainLoop:
           # while the step that reads them may still run (loader/device.py:
           # the deletion waits for it).
           t_wait = time.perf_counter()
+          if t_pull is None:
+            t_pull = t_wait
           if in_flight is not None and in_flight.t_end is None:
             in_flight.t_end = t_wait
           with phase('train.data_wait', next_step):
@@ -734,7 +752,8 @@ class TrainLoop:
               self.params, self.opt_state, metrics = self.step_fn(
                   self.params, self.opt_state, self.rng, batch)
             launched = _Launched(next_step, metrics, t_wait, t_step,
-                                 data_wait)
+                                 data_wait, t_pull, self.step_fn.last_key)
+            t_pull = None
             if tele.enabled:
               # What the observers report of this step's batch is taken
               # now: the next pull deletes the batch.
@@ -743,7 +762,7 @@ class TrainLoop:
             next_step += 1
             previous, in_flight = in_flight, launched
             if previous is not None:
-              loss = read_loss(previous)
+              loss = read_loss(previous, launched)
           if previous is not None:
             observe(previous, loss)
       if in_flight is not None:
@@ -765,6 +784,7 @@ class TrainLoop:
         self.save(ckpt_dir)
         flight.note_checkpoint(ckpt_dir, self.step)
     finally:
+      watch.uninstall()
       guard.uninstall()
       if writer is not None:
         # Idempotent after flush(); raise_errors=False so cleanup
@@ -785,6 +805,9 @@ class _Launched:
   t_wait: float      # perf_counter at its batch pull
   t_step: float      # perf_counter at its launch
   data_wait: float
+  t_pull: float      # at the first pull since a step was found finished
+  key: object        # its shape: the step cache's key
+  stall: object = None   # the stall watch's facts, if its interval stalled
   t_end: object = None   # at the first pull after its launch: pull to pull
   flops: object = None   # the train.mfu numerator, or None
   tiles: object = None   # (total, skipped) attention tiles of a packed batch

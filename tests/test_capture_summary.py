@@ -211,6 +211,39 @@ def test_programs_that_overlap_by_rounding_give_a_gap_of_nought():
   assert all(sum(g['phases'].values()) == g['ns'] for g in d['gaps'])
 
 
+def with_collections(events, main_pauses=(), feed_pauses=()):
+  """``events`` with ``host.gc`` spans (``[start, duration]``) on the main
+  and the feed's thread lines."""
+  feed, main = events['host']
+  main['events'] += [['host.gc', s, d, None] for s, d in main_pauses]
+  feed['events'] += [['host.gc', s, d, None] for s, d in feed_pauses]
+  return events
+
+
+def test_a_collection_is_laid_over_the_gaps_and_is_no_phase():
+  # On the feed's thread, 20 of the first gap (200..300) and 10 of the
+  # second (400..700); on the main thread, 5 under train.after_step.
+  events = with_collections(hand_made(), main_pauses=[(220, 5)],
+                            feed_pauses=[(205, 20), (390, 20)])
+  out = capture.summarize(events)
+  plain = capture.summarize(hand_made())
+  assert out['phases_seen'] == plain['phases_seen']
+  (d,), (p,) = out['devices'], plain['devices']
+  assert [g['gc'] for g in d['gaps']] == [25, 10]
+  for gap, before in zip(d['gaps'], p['gaps']):
+    assert {k: v for k, v in gap.items() if k != 'gc'} == before
+  table = capture.format_table(dict(out, seconds=0.0, trace_bytes=0))
+  assert ("the feed's thread meanwhile: no span; the collector: 0.000"
+          in table)
+
+
+def test_a_trace_without_collections_gives_no_gc_field():
+  (d,) = capture.summarize(hand_made())['devices']
+  assert all('gc' not in g for g in d['gaps'])
+  assert 'collector' not in capture.format_table(
+      dict(capture.summarize(hand_made()), seconds=0.0, trace_bytes=0))
+
+
 def test_no_step_program_or_no_phases():
   events = hand_made()
   events['host'] = []
