@@ -17,7 +17,8 @@ next_sentence_labels). Design choices for the MXU/XLA:
     it remakes what is O(seq^2) or element-wise (the dense path's scores
     and softmax, GELU, the layer norms, the dropout masks) and the ``out``
     projection, which is cheaper remade than kept; without remat the FFN's
-    GELU alone is remade, from its input;
+    GELU and the layer's two norms alone are remade, from their inputs
+    (``_keeps_its_input``);
   - static shapes everywhere — the loader's per-bin padding means one
     compiled program per bin;
   - attention is pluggable through ``BertConfig.attention_impl``;
@@ -71,6 +72,32 @@ class BertConfig:
     return self.hidden_size // self.num_heads
 
 
+def _row_sums(prim, *_, **__):
+  """What a kept op's backward takes from its forward beside the input: its
+  sums over a row (a LayerNorm's of x and x**2), one float32 a row each,
+  which the backward would otherwise remake by reading every row again."""
+  return prim is jax.lax.reduce_sum_p
+
+
+def _keeps_its_input(cfg, op):
+  """``op`` (a function, or a flax module class) as the layer applies it.
+
+  Without remat nothing else remakes ``op``: its backward keeps its input
+  (and its row sums, ``_row_sums``) and remakes the rest, where its
+  linearisation would keep what it made on the way as stacks over the
+  layers: GELU's tanh and its derivatives at the intermediate width, a
+  LayerNorm's float32 centred input and scales at the hidden width. Under
+  remat the policy decides and ``op`` is left as it is. The scan keeps the
+  two passes apart, so no CSE barrier is needed; with one, the TPU compiler
+  does not fuse the remade GELU into the `output` gemm's backward and writes
+  its derivative out (tests/test_deviceless_compile.py).
+  """
+  if cfg.remat:
+    return op
+  checkpoint = nn.remat if isinstance(op, type) else jax.checkpoint
+  return checkpoint(op, prevent_cse=False, policy=_row_sums)
+
+
 def _dense(features, cfg, name=None):
   return nn.Dense(
       features,
@@ -121,23 +148,15 @@ class Layer(nn.Module):
     cfg, deterministic = self.cfg, self.deterministic
     attn = SelfAttention(cfg, self.mesh, deterministic, name='attention')(
         x, attention_mask, segment_ids)
+    norm = _keeps_its_input(cfg, nn.LayerNorm)
     # The scopes name what is no flax module, for the capture summary
     # (telemetry/capture.py); they are metadata and change no arithmetic.
     with jax.named_scope('residual'):
       x = x + attn
-    x = nn.LayerNorm(dtype=cfg.dtype, name='attention_norm')(x)
+    x = norm(dtype=cfg.dtype, name='attention_norm')(x)
     h = checkpoint_name(_dense(cfg.intermediate_size, cfg, 'intermediate')(x),
                         'intermediate_out')
-    gelu = functools.partial(nn.gelu, approximate=True)
-    if not cfg.remat:
-      # Nothing else remakes it here: its backward keeps its input alone and
-      # remakes tanh and its derivative, where its linearisation would keep
-      # five more [tokens, intermediate_size] values a layer. Under remat
-      # the policy decides. The scan keeps the two passes apart, so no CSE
-      # barrier is needed; with one, the TPU compiler does not fuse the
-      # remade GELU into the `output` gemm's backward and writes its
-      # derivative out (tests/test_deviceless_compile.py).
-      gelu = jax.checkpoint(gelu, prevent_cse=False)
+    gelu = _keeps_its_input(cfg, functools.partial(nn.gelu, approximate=True))
     with jax.named_scope('gelu'):
       h = gelu(h)
     h = checkpoint_name(_dense(cfg.hidden_size, cfg, 'output')(h),
@@ -145,7 +164,7 @@ class Layer(nn.Module):
     h = nn.Dropout(cfg.dropout_rate)(h, deterministic=deterministic)
     with jax.named_scope('residual'):
       x = x + h
-    return nn.LayerNorm(dtype=cfg.dtype, name='output_norm')(x)
+    return norm(dtype=cfg.dtype, name='output_norm')(x)
 
 
 class Encoder(nn.Module):

@@ -1,17 +1,23 @@
-"""What the FFN's tanh-GELU hands its backward pass (``models/bert.py:Layer``).
+"""What the FFN's tanh-GELU and the two LayerNorms hand their backward pass
+(``models/bert.py:Layer``, ``_keeps_its_input``).
 
-Without remat nothing else remakes the GELU, so it keeps its input alone
-and remakes tanh and its derivative in the backward pass; its plain
-linearisation, the parent's formulation, kept six values at the
-intermediate width a layer. Under remat the policy decides, and the GELU
-traces as it did:
+Without remat nothing else remakes them, so each keeps its input (and a
+norm its two row sums) and remakes the rest in the backward pass; their
+plain linearisation kept six values at the intermediate width a layer for
+GELU and six float32 values at the hidden width for the norms. Under remat
+the policy decides, and they trace as they did:
 
   - without remat at most two values at the intermediate width are kept a
     layer (the ``intermediate`` gemm's output and GELU's, which the
-    ``output`` gemm's weight gradient takes), where the parent kept six;
+    ``output`` gemm's weight gradient takes), where GELU linearised kept six;
+  - without remat no float32 value at the hidden width is kept a layer in a
+    bfloat16 model: the norms keep their bfloat16 inputs, where linearised
+    they kept six float32 values (a float32 model keeps two for six);
   - with remat the saved set is the parent's, value for value;
   - the gradients of a tiny pretraining step are the parent's: bitwise in
-    float32, within one bfloat16 step in bfloat16.
+    float32, within one bfloat16 step in bfloat16;
+  - the parameter tree is the parent's, so the benchmark's adapter still
+    maps its seed weights onto it.
 """
 
 import contextlib
@@ -25,20 +31,27 @@ import pytest
 
 from lddl_tpu.models import bert
 
-B, S, LAYERS, FF = 2, 16, 2, 96
+# At 32 wide the CPU compiler sums a norm's row in another order wherever
+# the program's fusions differ, so float32 gradients part in the last bit;
+# from 64 wide they are bit-identical.
+B, S, LAYERS, HIDDEN, FF = 2, 16, 2, 64, 96
 
 
-class _ParentJax:
-  """``bert``'s ``jax`` as the parent formulation sees it: GELU under no
-  checkpoint, linearised where it stands. Everything else is jax's."""
-  checkpoint = staticmethod(lambda fn, **_: fn)
+def _linearised(*kinds):
+  """``bert._keeps_its_input`` with the ops of ``kinds`` ('gelu', 'norm')
+  left linearised where they stand, as they were before the rule took
+  them."""
+  keeps = bert._keeps_its_input
 
-  def __getattr__(self, name):
-    return getattr(jax, name)
+  def rule(cfg, op):
+    kind = 'norm' if isinstance(op, type) else 'gelu'
+    return op if kind in kinds else keeps(cfg, op)
+
+  return rule
 
 
 def _model(remat, dtype, impl='dense'):
-  cfg = bert.BertConfig(vocab_size=64, hidden_size=32, num_layers=LAYERS,
+  cfg = bert.BertConfig(vocab_size=64, hidden_size=HIDDEN, num_layers=LAYERS,
                         num_heads=2, intermediate_size=FF,
                         max_position_embeddings=S, dtype=dtype,
                         attention_impl=impl, remat=remat)
@@ -82,9 +95,10 @@ def _saved(model):
   return out.getvalue().splitlines()
 
 
-def _layer_stacks_at_ff(lines):
-  # The scan stacks a layer's kept values over its layers: [L, b, s, FF].
-  return [l for l in lines if re.match(rf'\w+\[{LAYERS},{B},{S},{FF}\] ', l)]
+def _layer_stacks(lines, *width):
+  # The scan stacks a layer's kept values over its layers: [L, b, s, ...].
+  dims = ','.join(map(str, (LAYERS, B, S) + width))
+  return [l.split('[')[0] for l in lines if re.match(rf'\w+\[{dims}\] ', l)]
 
 
 @pytest.mark.parametrize('dtype', [jnp.bfloat16, jnp.float32],
@@ -95,32 +109,51 @@ def _layer_stacks_at_ff(lines):
 def test_without_remat_gelu_keeps_only_its_input(monkeypatch, formulation,
                                                  expected, impl, dtype):
   if formulation == 'parent':
-    monkeypatch.setattr(bert, 'jax', _ParentJax())
-  assert len(_layer_stacks_at_ff(_saved(_model(False, dtype, impl)))) == \
+    monkeypatch.setattr(bert, '_keeps_its_input', _linearised('gelu'))
+  assert len(_layer_stacks(_saved(_model(False, dtype, impl)), FF)) == \
       expected
+
+
+@pytest.mark.parametrize('dtype', [jnp.bfloat16, jnp.float32],
+                         ids=['bf16', 'f32'])
+@pytest.mark.parametrize('impl', ['dense', 'flash'])
+@pytest.mark.parametrize('formulation,expected', [
+    ('change', {jnp.bfloat16: {'bf16': 5}, jnp.float32: {'f32': 5}}),
+    ('parent', {jnp.bfloat16: {'bf16': 3, 'f32': 6},
+                jnp.float32: {'f32': 9}})])
+def test_without_remat_the_norms_keep_only_their_inputs(monkeypatch,
+                                                        formulation,
+                                                        expected, impl, dtype):
+  if formulation == 'parent':
+    monkeypatch.setattr(bert, '_keeps_its_input', _linearised('norm'))
+  saved = _saved(_model(False, dtype, impl))
+  at_hidden = _layer_stacks(saved, HIDDEN)
+  # The two dropout masks are kept either way; the rest are activations.
+  assert at_hidden.count('bool') == 2
+  assert {t: at_hidden.count(t) for t in set(at_hidden) - {'bool'}} == \
+      expected[dtype]
+  # Four float32 values a row and layer either way: the linearised norms'
+  # statistics, or the row sums (of x and x**2) the change keeps.
+  assert _layer_stacks(saved) == ['f32'] * 4
 
 
 @pytest.mark.parametrize('impl', ['dense', 'flash'])
 def test_under_remat_the_saved_set_is_the_parents(monkeypatch, impl):
   change = _saved(_model(True, jnp.bfloat16, impl))
-  monkeypatch.setattr(bert, 'jax', _ParentJax())
+  monkeypatch.setattr(bert, '_keeps_its_input', _linearised('gelu', 'norm'))
   parent = _saved(_model(True, jnp.bfloat16, impl))
   assert change == parent
   # The policy's one value at the intermediate width: intermediate_out.
-  assert len(_layer_stacks_at_ff(change)) == 1
+  assert len(_layer_stacks(change, FF)) == 1
 
 
-def _grads(model):
+def _grads(model, compiler_options=None):
   loss, params = _loss_and_params(model)
-  return jax.jit(jax.grad(loss))(params)
+  return jax.jit(jax.grad(loss)).lower(params).compile(compiler_options)(
+      params)
 
 
-@pytest.mark.parametrize('dtype', [jnp.float32, jnp.bfloat16],
-                         ids=['f32', 'bf16'])
-def test_gradients_are_the_parents(monkeypatch, dtype):
-  change = _grads(_model(False, dtype))
-  monkeypatch.setattr(bert, 'jax', _ParentJax())
-  parent = _grads(_model(False, dtype))
+def _assert_same_gradients(change, parent, dtype):
   for (path, c), p in zip(jax.tree_util.tree_flatten_with_path(change)[0],
                           jax.tree.leaves(parent)):
     c, p = np.asarray(c), np.asarray(p)
@@ -130,3 +163,41 @@ def test_gradients_are_the_parents(monkeypatch, dtype):
       # One bfloat16 step (8 significant bits) of the parent's value.
       step = np.exp2(np.floor(np.log2(np.maximum(np.abs(p), 1e-30))) - 7)
       assert np.all(np.abs(c - p) <= step), jax.tree_util.keystr(path)
+
+
+@pytest.mark.parametrize('dtype', [jnp.float32, jnp.bfloat16],
+                         ids=['f32', 'bf16'])
+def test_gradients_are_the_parents(monkeypatch, dtype):
+  change = _grads(_model(False, dtype))
+  monkeypatch.setattr(bert, '_keeps_its_input', _linearised('gelu'))
+  parent = _grads(_model(False, dtype))
+  _assert_same_gradients(change, parent, dtype)
+
+
+@pytest.mark.parametrize('dtype', [jnp.float32, jnp.bfloat16],
+                         ids=['f32', 'bf16'])
+@pytest.mark.parametrize('impl', ['dense', 'flash'])
+def test_norm_gradients_are_the_parents(monkeypatch, impl, dtype):
+  # The norms' backward is fused otherwise than the parent's, and the CPU
+  # compiler may leave a bfloat16 rounding out inside a fusion (its excess
+  # precision), in one program and not in the other: both are held to
+  # every rounding their arithmetic asks for.
+  exact = {'xla_allow_excess_precision': False}
+  change = _grads(_model(False, dtype, impl), exact)
+  monkeypatch.setattr(bert, '_keeps_its_input', _linearised('norm'))
+  parent = _grads(_model(False, dtype, impl), exact)
+  _assert_same_gradients(change, parent, dtype)
+
+
+@pytest.mark.parametrize('remat', [False, True], ids=['no_remat', 'remat'])
+def test_the_parameter_tree_is_the_parents(monkeypatch, remat):
+  from chipbench import adapter
+  _, change = _loss_and_params(_model(remat, jnp.bfloat16))
+  monkeypatch.setattr(bert, '_keeps_its_input', _linearised('norm'))
+  _, parent = _loss_and_params(_model(remat, jnp.bfloat16))
+  assert jax.tree.structure(change) == jax.tree.structure(parent)
+  assert jax.tree.map(jnp.shape, change) == jax.tree.map(jnp.shape, parent)
+  adapter.check_tree({'hidden_size': HIDDEN, 'intermediate_size': FF,
+                      'num_hidden_layers': LAYERS, 'vocab_size': 64,
+                      'max_position_embeddings': S, 'type_vocab_size': 2},
+                     change)
