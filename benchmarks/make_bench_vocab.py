@@ -11,7 +11,11 @@ distribution of :mod:`lddl_tpu.core.synth`, and commits the result as
 
 Usage (regenerate only if synth.py's distribution changes)::
 
-  python benchmarks/make_bench_vocab.py
+  python benchmarks/make_bench_vocab.py [size]
+
+``size`` defaults to BERT's 30,522; 16,384 gives the vocabulary of the
+LFM2 configuration's quarter of the vocabulary
+(``bench_vocab_16384.txt``).
 """
 
 import os
@@ -24,13 +28,13 @@ VOCAB_SIZE = 30522
 SPECIALS = ['[PAD]', '[UNK]', '[CLS]', '[SEP]', '[MASK]']
 
 
-def main():
+def main(size=VOCAB_SIZE):
   from tokenizers import Tokenizer, models, normalizers, pre_tokenizers, \
       trainers
 
   from lddl_tpu.core.synth import write_corpus
   out = os.path.join(os.path.dirname(os.path.abspath(__file__)), 'assets',
-                     f'bench_vocab_{VOCAB_SIZE}.txt')
+                     f'bench_vocab_{size}.txt')
   os.makedirs(os.path.dirname(out), exist_ok=True)
   with tempfile.TemporaryDirectory(prefix='bench_vocab_') as work:
     src = os.path.join(work, 'text')
@@ -41,7 +45,7 @@ def main():
     tok.normalizer = normalizers.BertNormalizer(lowercase=True)
     tok.pre_tokenizer = pre_tokenizers.BertPreTokenizer()
     trainer = trainers.WordPieceTrainer(
-        vocab_size=VOCAB_SIZE,
+        vocab_size=size,
         min_frequency=2,
         special_tokens=SPECIALS,
         continuing_subword_prefix='##')
@@ -49,7 +53,7 @@ def main():
     print('training WordPiece ...')
     tok.train(files, trainer)
   vocab = tok.get_vocab()
-  assert len(vocab) == VOCAB_SIZE, len(vocab)
+  assert len(vocab) == size, len(vocab)
   by_id = sorted(vocab.items(), key=lambda kv: kv[1])
   with open(out, 'w', encoding='utf-8') as f:
     f.write('\n'.join(t for t, _ in by_id) + '\n')
@@ -58,4 +62,4 @@ def main():
 
 
 if __name__ == '__main__':
-  main()
+  main(*map(int, sys.argv[1:2]))

@@ -65,6 +65,19 @@ documents therefore runs ~1/k of its attention tiles instead of
 computing and masking all of them — the "no cross-contamination"
 masking of arXiv:2107.02027 as a speedup rather than a cost.
 
+Causal attention (``causal=True``, a decoder's): a query sees no key
+after it. The tile rule takes a third term beside the two intervals: a
+tile wholly above the diagonal is skipped like a cross-document one, a
+tile wholly below it needs no causal mask, and a tile on it takes the
+elementwise ``q >= k`` mask; a tile is bias-free (*interior*) only where
+both rules leave every pair of it unmasked, so a diagonal tile never is.
+With segment ids the two compose: causal within each document.
+
+Grouped query heads: k and v may hold fewer heads than q, a divisor of its
+count. Query head ``h`` reads key/value head ``h // group`` through the
+BlockSpec's index map, in place (nothing is copied); the backward sums
+each query head's share of dk and dv over its group in float32.
+
 Differentiation is a ``jax.custom_vjp``: forward saves (out, lse); the
 backward is one Pallas kernel (``_bwd_kernel``) that recomputes
 P = exp(s - lse), dP and dS of a tile once and takes dq, dk and dv from
@@ -205,25 +218,51 @@ def _seg_interval(seg):
   return lo, hi
 
 
-def _for_live_tile(tile, qseg_ref, kseg_ref):
-  """Run ``tile(seg_bias)`` unless the (q-block, kv-block) tile holds no
-  same-document pair. Doc ids are monotone within a packed row, so each
+def _for_live_tile(tile, qseg_ref, kseg_ref, diagonal=None):
+  """Run ``tile(masked)`` unless the (q-block, kv-block) tile holds no
+  pair that may attend. Doc ids are monotone within a packed row, so each
   block spans a contiguous id interval and interval overlap is exact.
-  A live tile is *interior* when both intervals are the same single
-  document: every real pair is a same-document pair, padding keys carry
-  the key-side bias already (an id of -1 comes with a masked key), so
-  the elementwise segment bias would add zeros and is left out.
-  The refs hold the two blocks' ids (row or column), or are None for
-  full attention: one tile body, no skip machinery in the trace."""
-  if qseg_ref is None:
+  ``diagonal`` is None where every key may be seen, or, for causal
+  attention, ``(q0, k0)``: the tile's first query and key position (its
+  block sizes come from the refs' shapes). A live tile is *interior* when
+  no pair of it is masked: both intervals are the same single document
+  (padding keys carry the key-side bias already; an id of -1 comes with a
+  masked key) and, under causality, every key stands at or before every
+  query. An interior tile runs without the elementwise masks, which would
+  add zeros; a tile on the diagonal is never interior.
+  The seg refs hold the two blocks' ids (row or column), or are None for
+  attention across documents: with no diagonal either, one tile body and
+  no skip machinery in the trace."""
+  if qseg_ref is None and diagonal is None:
     tile(False)
     return
-  qlo, qhi = _seg_interval(qseg_ref[...])
-  klo, khi = _seg_interval(kseg_ref[...])
-  live = (qlo <= khi) & (klo <= qhi)
-  interior = (qlo == qhi) & (klo == khi) & (qlo == klo)
+  live = interior = None
+  if qseg_ref is not None:
+    qlo, qhi = _seg_interval(qseg_ref[...])
+    klo, khi = _seg_interval(kseg_ref[...])
+    live = (qlo <= khi) & (klo <= qhi)
+    interior = (qlo == qhi) & (klo == khi) & (qlo == klo)
+  if diagonal is not None:
+    (q0, block_q), (k0, block_k) = diagonal
+    seen = k0 <= q0 + (block_q - 1)   # some key at or before some query
+    below = k0 + (block_k - 1) <= q0  # every key at or before every query
+    live = seen if live is None else live & seen
+    interior = below if interior is None else interior & below
   pl.when(interior)(lambda: tile(False))
   pl.when(live & jnp.logical_not(interior))(lambda: tile(True))
+
+
+def _causal_order(diagonal, key_major):
+  """bool tile: the query stands at or after the key. ``diagonal`` as in
+  :func:`_for_live_tile`; the tile is ``[block_q, block_k]``, or
+  ``[block_k, block_q]`` where ``key_major``."""
+  (q0, block_q), (k0, block_k) = diagonal
+  shape = (block_k, block_q) if key_major else (block_q, block_k)
+  rows = lax.broadcasted_iota(jnp.int32, shape, 0)
+  cols = lax.broadcasted_iota(jnp.int32, shape, 1)
+  if key_major:
+    return k0 + rows <= q0 + cols
+  return q0 + rows >= k0 + cols
 
 
 def _mxu(a, b, contract):
@@ -235,26 +274,35 @@ def _mxu(a, b, contract):
                          preferred_element_type=jnp.float32)
 
 
-def _scores(a, b, bias, scale, a_seg=None, b_seg=None):
+def _scores(a, b, bias, scale, a_seg=None, b_seg=None, order=None):
   """float32 ``[rows of a, rows of b]`` scores of one tile: a.b^T scaled,
   plus the key-side padding bias (a row ``[1, n]`` where ``b`` holds the
   keys, a column ``[m, 1]`` where ``a`` does) and, given the two blocks'
   segment ids (a column ``[m, 1]`` and a row ``[1, n]``) on a tile that
-  straddles a document boundary, the elementwise cross-document mask."""
+  straddles a document boundary, the elementwise cross-document mask;
+  given ``order`` (:func:`_causal_order`) on a tile that straddles the
+  diagonal, the causal one."""
   s = _mxu(a, b, (1, 1)) * scale + bias
   if a_seg is not None:
     s = s + jnp.where(a_seg == b_seg, 0.0, NEG_INF)
+  if order is not None:
+    s = s + jnp.where(order, 0.0, NEG_INF)
   return s
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, qseg_ref, kseg_ref,
-                o_ref, lse_ref, m_ref, l_ref, acc_ref, *, scale):
+                o_ref, lse_ref, m_ref, l_ref, acc_ref, *, scale, causal):
   """Grid (bh, q-blocks, kv-blocks); kv is the innermost (sequential)
   dimension. The running (max, sum, accumulator) lives in VMEM scratch,
   which persists across grid steps: reset on the first kv block,
-  updated by every *live* tile (cross-doc tiles skip the whole body),
-  finalized into (o, lse) on the last."""
+  updated by every *live* tile (cross-doc tiles, and under ``causal``
+  tiles above the diagonal, skip the whole body), finalized into
+  (o, lse) on the last."""
   j = pl.program_id(2)
+  diagonal = None
+  if causal:
+    diagonal = ((pl.program_id(1) * q_ref.shape[1], q_ref.shape[1]),
+                (j * k_ref.shape[1], k_ref.shape[1]))
 
   @pl.when(j == 0)
   def _init():
@@ -262,10 +310,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, qseg_ref, kseg_ref,
     l_ref[...] = jnp.zeros_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
 
-  def _tile(seg_bias):
+  def _tile(masked):
     v_blk = v_ref[0]  # [bk, d], the input's dtype
-    segs = (qseg_ref[0, 0, :][:, None], kseg_ref[0]) if seg_bias else ()
-    scores = _scores(q_ref[0], k_ref[0], bias_ref[0], scale, *segs)
+    segs = ((qseg_ref[0, 0, :][:, None], kseg_ref[0])
+            if masked and qseg_ref is not None else (None, None))
+    order = _causal_order(diagonal, False) if masked and causal else None
+    scores = _scores(q_ref[0], k_ref[0], bias_ref[0], scale, *segs, order)
     m = m_ref[...]
     m_new = jnp.maximum(m, jnp.max(scores, axis=-1, keepdims=True))
     p = jnp.exp(scores - m_new)
@@ -277,7 +327,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, qseg_ref, kseg_ref,
     acc_ref[...] = acc_ref[...] * alpha + _mxu(p.astype(v_blk.dtype), v_blk,
                                                (1, 0))
 
-  _for_live_tile(_tile, qseg_ref, kseg_ref)
+  _for_live_tile(_tile, qseg_ref, kseg_ref, diagonal)
 
   @pl.when(j == pl.num_programs(2) - 1)
   def _finalize():
@@ -288,7 +338,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, qseg_ref, kseg_ref,
 
 def _bwd_kernel(q_ref, k_ref, v_ref, bias_ref, qseg_ref, kseg_ref, do_ref,
                 lse_ref, delta_ref, dq_ref, dk_ref, dv_ref, dk_acc_ref,
-                dv_acc_ref, *, scale):
+                dv_acc_ref, *, scale, causal, q_offset):
   """Grid (bh, kv-blocks, q-blocks), q innermost: a tile's scores, P, dP
   and dS are made once and all three gradients taken from them, five
   products a tile. Cross-doc tiles contribute exactly zero (P underflows
@@ -309,8 +359,13 @@ def _bwd_kernel(q_ref, k_ref, v_ref, bias_ref, qseg_ref, kseg_ref, do_ref,
   columns ``[block_k, 1]`` and the per-query ones (lse, delta, q segment
   ids) as rows ``[1, block_q]`` — which also keeps what the innermost
   axis fetches every step small (a ``[block_q, 1]`` float32 column moves
-  a whole 128-lane tile a row)."""
+  a whole 128-lane tile a row). Under ``causal`` the query rows of this
+  launch start at position ``q_offset``."""
   j, i = pl.program_id(1), pl.program_id(2)
+  diagonal = None
+  if causal:
+    diagonal = ((q_offset + i * q_ref.shape[1], q_ref.shape[1]),
+                (j * k_ref.shape[1], k_ref.shape[1]))
 
   @pl.when((j == 0) & (i == 0))
   def _zero_dq():
@@ -321,10 +376,12 @@ def _bwd_kernel(q_ref, k_ref, v_ref, bias_ref, qseg_ref, kseg_ref, do_ref,
     dk_acc_ref[...] = jnp.zeros_like(dk_acc_ref)
     dv_acc_ref[...] = jnp.zeros_like(dv_acc_ref)
 
-  def _tile(seg_bias):
+  def _tile(masked):
     q, k, do = q_ref[0], k_ref[0], do_ref[0]  # [bq, d], [bk, d], [bq, d]
-    segs = (kseg_ref[0], qseg_ref[0]) if seg_bias else ()
-    scores_t = _scores(k, q, bias_ref[0], scale, *segs)  # k.q^T
+    segs = ((kseg_ref[0], qseg_ref[0])
+            if masked and qseg_ref is not None else (None, None))
+    order = _causal_order(diagonal, True) if masked and causal else None
+    scores_t = _scores(k, q, bias_ref[0], scale, *segs, order)  # k.q^T
     # Rows beyond the real sequence carry lse from padded-q garbage; their
     # dO is zero (cotangents of padding outputs are never produced by the
     # loss) so they contribute nothing — but guard exp() overflow anyway.
@@ -335,7 +392,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, bias_ref, qseg_ref, kseg_ref, do_ref,
     dk_acc_ref[...] = dk_acc_ref[...] + _mxu(q, ds_t, (0, 1))
     dq_ref[0, i] = dq_ref[0, i] + _mxu(k, ds_t, (0, 0))
 
-  _for_live_tile(_tile, qseg_ref, kseg_ref)
+  _for_live_tile(_tile, qseg_ref, kseg_ref, diagonal)
 
   @pl.when(i == pl.num_programs(2) - 1)
   def _finalize():
@@ -360,17 +417,25 @@ def _plain(kernel):
 # arrays — bias/segment ids ``[b, 1, s]``, lse/delta ``[bh, s_q, 1]``.
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
-def _flash_pair(q, k, v, bias, q_seg, kv_seg, heads):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
+def _flash_pair(q, k, v, bias, q_seg, kv_seg, heads, group, causal):
   """(out, lse) with gradients defined for both outputs — lse cotangents
   arise when results of separate flash calls are merged downstream (the
   ring composition's streaming-softmax combine). ``q_seg``/``kv_seg``
   are either both None (full attention) or float32 ``[b, 1, s]`` doc
-  ids (-1 = padding) enabling the block-diagonal tile skip."""
-  return _flash_fwd_impl(q, k, v, bias, q_seg, kv_seg, heads)
+  ids (-1 = padding) enabling the block-diagonal tile skip. ``group``
+  query heads share one key/value head (k and v hold ``bh // group``
+  heads); ``causal`` lets a query see no later key."""
+  return _flash_fwd_impl(q, k, v, bias, q_seg, kv_seg, heads, group, causal)
 
 
-def _flash_fwd_impl(q, k, v, bias, q_seg, kv_seg, heads):
+def _kv_index(group, index):
+  """The key/value head of query head ``index`` (grouped heads are read in
+  place by the BlockSpec, never copied); no arithmetic without groups."""
+  return index if group == 1 else index // group
+
+
+def _flash_fwd_impl(q, k, v, bias, q_seg, kv_seg, heads, group, causal):
   bh, s_q, d = q.shape
   (block_q, padded_q), (block_k, padded_kv) = _tile_blocks(s_q, k.shape[1])
   # Whole blocks on both axes: zero query rows (segment id -1) are
@@ -382,7 +447,8 @@ def _flash_fwd_impl(q, k, v, bias, q_seg, kv_seg, heads):
   kv_seg = _pad_to(kv_seg, 2, padded_kv, -1.0)
   grid = (bh, padded_q // block_q, padded_kv // block_k)
   q_spec = pl.BlockSpec((1, block_q, d), lambda i, b, j: (i, b, 0))
-  kv_spec = pl.BlockSpec((1, block_k, d), lambda i, b, j: (i, j, 0))
+  kv_spec = pl.BlockSpec((1, block_k, d),
+                         lambda i, b, j: (_kv_index(group, i), j, 0))
   bias_spec = pl.BlockSpec((1, 1, block_k), lambda i, b, j: (i // heads, 0, j))
   qseg_spec = pl.BlockSpec((1, 1, block_q), lambda i, b, j: (i // heads, 0, b))
   row_spec = pl.BlockSpec((1, block_q, 1), lambda i, b, j: (i, b, 0))
@@ -395,7 +461,7 @@ def _flash_fwd_impl(q, k, v, bias, q_seg, kv_seg, heads):
     in_specs = [q_spec, kv_spec, kv_spec, bias_spec, qseg_spec, bias_spec]
     inputs = (q, k, v, bias, q_seg, kv_seg)
   out, lse = pl.pallas_call(
-      functools.partial(kernel, scale=1.0 / d**0.5),
+      functools.partial(kernel, scale=1.0 / d**0.5, causal=causal),
       grid=grid,
       in_specs=in_specs,
       out_specs=[q_spec, row_spec],
@@ -414,7 +480,7 @@ def _flash_fwd_impl(q, k, v, bias, q_seg, kv_seg, heads):
   return out[:, :s_q, :], lse[:, :s_q, :]
 
 
-def _flash_fwd(q, k, v, bias, q_seg, kv_seg, heads):
+def _flash_fwd(q, k, v, bias, q_seg, kv_seg, heads, group, causal):
   """The forward rule. The kernel's two results are also the residuals
   that only it can make, so they carry the names by which a remat policy
   keeps them (``FLASH_RESIDUAL_NAMES``; outside remat a name is the
@@ -423,7 +489,8 @@ def _flash_fwd(q, k, v, bias, q_seg, kv_seg, heads):
   of 64 would keep ``out`` at twice its bytes and the ``[bh, s, 1]``
   column of ``lse`` at 128 times. So each is named as a lane-dense view
   and shaped back behind the name."""
-  out, lse = _flash_fwd_impl(q, k, v, bias, q_seg, kv_seg, heads)
+  out, lse = _flash_fwd_impl(q, k, v, bias, q_seg, kv_seg, heads, group,
+                             causal)
   out_name, lse_name = FLASH_RESIDUAL_NAMES
   bh, s_q, d = out.shape
   dense = out.reshape(bh, -1, math.gcd(s_q * d, 128))
@@ -432,7 +499,7 @@ def _flash_fwd(q, k, v, bias, q_seg, kv_seg, heads):
   return (out, lse), (q, k, v, bias, q_seg, kv_seg, out, lse)
 
 
-def _flash_bwd(heads, res, cotangents):
+def _flash_bwd(heads, group, causal, res, cotangents):
   q, k, v, bias, q_seg, kv_seg, out, lse = res
   g, g_lse = cotangents
   bh, s_q, d = q.shape
@@ -457,7 +524,8 @@ def _flash_bwd(heads, res, cotangents):
   bias_col = turned(_pad_to(bias, 2, padded_kv, NEG_INF))
   scale = 1.0 / d**0.5
   q_by_i = pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0))
-  kv_by_j = pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0))
+  kv_by_j = pl.BlockSpec((1, block_k, d),
+                         lambda b, j, i: (_kv_index(group, b), j, 0))
   kcol_by_j = pl.BlockSpec((1, block_k, 1), lambda b, j, i: (b // heads, j, 0))
   qrow_by_i = pl.BlockSpec((1, 1, block_q), lambda b, j, i: (b, 0, i))
   kernel, seg_specs = _plain(_bwd_kernel), []
@@ -470,11 +538,11 @@ def _flash_bwd(heads, res, cotangents):
 
   # One launch a span of the query axis, the longest whose dq stays
   # resident (``_DQ_RESIDENT_BYTES``: two buffers of [d, span] float32);
-  # dk and dv are summed over the spans below, in float32 where there is
-  # more than one.
+  # dk and dv are summed over the spans below, and over the query heads of
+  # a group, in float32 where there is more than one share.
   span = max(1, _DQ_RESIDENT_BYTES // (2 * 4 * d * block_q)) * block_q
   starts = range(0, padded_q, span)
-  share_dtype = q.dtype if len(starts) == 1 else jnp.float32
+  share_dtype = q.dtype if len(starts) == 1 and group == 1 else jnp.float32
 
   def launch(lo):
     """(dq^T / scale in float32, dk^T, dv^T) of the query rows from
@@ -486,7 +554,7 @@ def _flash_bwd(heads, res, cotangents):
     segs = () if q_seg is None else (cols(qseg_row), kseg_col)
     kt_by_j = pl.BlockSpec((1, d, block_k), lambda b, j, i: (b, 0, j))
     return pl.pallas_call(
-        functools.partial(kernel, scale=scale),
+        functools.partial(kernel, scale=scale, causal=causal, q_offset=lo),
         grid=(bh, padded_kv // block_k, n // block_q),
         in_specs=[q_by_i, kv_by_j, kv_by_j, kcol_by_j, *seg_specs, q_by_i,
                   qrow_by_i, qrow_by_i],
@@ -514,7 +582,11 @@ def _flash_bwd(heads, res, cotangents):
   # dk is in the kernel's last step: its sum ends only with the head.
   dq = jnp.swapaxes(jnp.concatenate(dq_t, axis=1), 2, 3)
   dq = (dq.reshape(bh, padded_q, d) * scale).astype(q.dtype)
-  dk, dv = (turned(sum(x[1:], x[0])).astype(q.dtype) for x in (dk_t, dv_t))
+  dk, dv = (turned(sum(x[1:], x[0])) for x in (dk_t, dv_t))
+  if group > 1:  # each query head's share of its key/value head
+    dk, dv = (x.reshape(bh // group, group, padded_kv, d).sum(axis=1)
+              for x in (dk, dv))
+  dk, dv = dk.astype(q.dtype), dv.astype(q.dtype)
   return (dq[:, :s_q, :], dk[:, :s_kv, :], dv[:, :s_kv, :],
           jnp.zeros_like(bias),
           None if q_seg is None else jnp.zeros_like(q_seg),
@@ -536,7 +608,8 @@ def _prep_segments(segment_ids, s, s_pad):
 
 
 def flash_attention_with_lse(q, k, v, attention_mask=None,
-                             q_segment_ids=None, kv_segment_ids=None):
+                             q_segment_ids=None, kv_segment_ids=None,
+                             causal=False):
   """Like :func:`flash_attention` but also returns the per-query
   log-sum-exp ``[batch, heads, seq]`` (float32) — the quantity needed to
   exactly merge attention results computed over disjoint key sets (ring
@@ -544,7 +617,13 @@ def flash_attention_with_lse(q, k, v, attention_mask=None,
   outputs.
   """
   b, h, s_q, d = q.shape
-  s_kv = k.shape[2]
+  s_kv, kv_heads = k.shape[2], k.shape[1]
+  if h % kv_heads:
+    raise ValueError(f'{h} query heads do not share {kv_heads} key/value '
+                     'heads evenly')
+  if causal and s_q != s_kv:
+    raise ValueError('causal attention needs queries and keys of one '
+                     'sequence')
   if (q_segment_ids is None) != (kv_segment_ids is None):
     raise ValueError('q_segment_ids and kv_segment_ids must be given '
                      'together (self-attention passes the same array)')
@@ -567,28 +646,32 @@ def flash_attention_with_lse(q, k, v, attention_mask=None,
     q_seg = _prep_segments(q_segment_ids, s_q, sq_pad)
     kv_seg = _prep_segments(kv_segment_ids, s_kv, skv_pad)
   out, lse = _flash_pair(q.reshape(b * h, sq_pad, d),
-                         k.reshape(b * h, skv_pad, d),
-                         v.reshape(b * h, skv_pad, d), bias, q_seg, kv_seg,
-                         h)
+                         k.reshape(b * kv_heads, skv_pad, d),
+                         v.reshape(b * kv_heads, skv_pad, d), bias, q_seg,
+                         kv_seg, h, h // kv_heads, causal)
   out = out.reshape(b, h, sq_pad, d)[:, :, :s_q, :]
   lse = lse.reshape(b, h, sq_pad)[:, :, :s_q]
   return out, lse
 
 
 def flash_attention(q, k, v, attention_mask=None, q_segment_ids=None,
-                    kv_segment_ids=None):
+                    kv_segment_ids=None, causal=False):
   """Blockwise-softmax attention; drop-in for the dense einsum path.
 
-  ``q, k, v``: ``[batch, heads, seq, head_dim]``; ``attention_mask``:
+  ``q``: ``[batch, heads, seq, head_dim]``; ``k, v``: the same with
+  ``heads`` or a divisor of it (grouped query heads: head ``h`` reads
+  key/value head ``h // (heads // kv_heads)``); ``attention_mask``:
   ``[batch, seq]`` with 1 = attend, 0 = padding (key side). Optional
   ``q_segment_ids``/``kv_segment_ids`` ``[batch, seq]`` int32 (doc index
   per token, -1 = padding) restrict attention block-diagonally to
   same-document pairs, skipping provably cross-document tiles (see
-  module docstring). Returns the context ``[batch, heads, seq,
-  head_dim]`` in the input dtype.
+  module docstring). ``causal`` lets a query see no key after it (the
+  two compose: causal within a document), skipping the tiles above the
+  diagonal. Returns the context ``[batch, heads, seq, head_dim]`` in the
+  input dtype.
   """
   return flash_attention_with_lse(q, k, v, attention_mask, q_segment_ids,
-                                  kv_segment_ids)[0]
+                                  kv_segment_ids, causal)[0]
 
 
 def segment_block_intervals(segment_ids, block):
@@ -608,13 +691,15 @@ def segment_block_intervals(segment_ids, block):
   return lo, hi
 
 
-def count_skippable_tiles(segment_ids, block_q=None, block_k=None):
+def count_skippable_tiles(segment_ids, block_q=None, block_k=None,
+                          causal=False):
   """(total, skipped) forward-grid tile counts for a ``[b, s]``
-  segment-id batch under the kernel's interval-disjointness rule — the
-  exact host-side account of the tiles the Pallas grid will skip (per
-  (batch, q-block, kv-block); multiply by heads for per-head counts;
-  the fraction is heads-invariant). Feeds the ``train.attn_tiles_*``
-  telemetry counters and the benchmark skip-fraction columns."""
+  segment-id batch under the kernel's interval-disjointness rule (and,
+  where ``causal``, its diagonal rule) — the exact host-side account of
+  the tiles the Pallas grid will skip (per (batch, q-block, kv-block);
+  multiply by heads for per-head counts; the fraction is
+  heads-invariant). Feeds the ``train.attn_tiles_*`` telemetry counters
+  and the benchmark skip-fraction columns."""
   if block_q is None or block_k is None:
     s_pad = _padded_len(int(segment_ids.shape[1]))
     (grid_q, _), (grid_k, _) = _tile_blocks(s_pad, s_pad)
@@ -625,11 +710,15 @@ def count_skippable_tiles(segment_ids, block_q=None, block_k=None):
   klo, khi = segment_block_intervals(seg, block_k)
   live = ((qlo[:, :, None] <= khi[:, None, :]) &
           (klo[:, None, :] <= qhi[:, :, None]))
+  if causal:
+    q_end = (np.arange(qlo.shape[1]) + 1) * block_q - 1
+    k_start = np.arange(klo.shape[1]) * block_k
+    live &= (k_start[None, :] <= q_end[:, None])[None]
   total = int(live.size)
   return total, total - int(live.sum())
 
 
-def make_flash_attention(mesh, q_spec=None, mask_spec=None):
+def make_flash_attention(mesh, q_spec=None, mask_spec=None, causal=False):
   """Wrap :func:`flash_attention` in ``shard_map`` for jitted use over a
   mesh: batch over (data, fsdp), heads over tensor — a ``pallas_call``
   has no GSPMD partitioning rule, so without this the compiler would
@@ -661,6 +750,6 @@ def make_flash_attention(mesh, q_spec=None, mask_spec=None):
       out_specs=q_spec,
       check_vma=False)
   def _sharded(q, k, v, mask, segment_ids):
-    return flash_attention(q, k, v, mask, segment_ids, segment_ids)
+    return flash_attention(q, k, v, mask, segment_ids, segment_ids, causal)
 
   return _sharded

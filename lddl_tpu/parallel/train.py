@@ -1,18 +1,24 @@
-"""Sharded BERT-pretraining train step.
+"""Sharded train step, for any model family.
 
 The reference stops at the DataLoader boundary; its consumers (NVIDIA BERT
 training recipes) own the step. Here the step is part of the framework so
 the binned loader's static-shape contract can be demonstrated end-to-end:
-one jitted program per bin shape, params laid out by
-:func:`lddl_tpu.models.spec_for_param` over the
-(data, fsdp, tensor, seq) mesh, gradients reduced by GSPMD over ICI.
+one jitted program per bin shape, params laid out by a family's
+``param_spec_fn`` over the (data, fsdp, tensor, seq) mesh, gradients
+reduced by GSPMD over ICI.
 
-Loss = masked-LM cross entropy (ignore label -100, mean over masked
-positions) + next-sentence-prediction cross entropy — the standard BERT
-pretraining objective over exactly the dict the loader yields.
+What a model family hands the step is an :class:`Objective`: its loss, the
+placement of its parameters and its FLOPs per step (the seam every family
+goes through; :func:`bert_objective` is BERT's). BERT's loss
+(:func:`pretrain_loss`) is masked-LM cross entropy (ignore label -100, mean
+over masked positions) + next-sentence-prediction cross entropy — the
+standard BERT pretraining objective over exactly the dict the loader
+yields.
 """
 
+import dataclasses
 import functools
+from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -24,25 +30,62 @@ from ..models import spec_for_param
 from .mesh import canonical_batch_spec
 
 
-def param_shardings(mesh, abs_params):
+@dataclasses.dataclass(frozen=True)
+class Objective:
+  """What a model family hands the step and the loop.
+
+  ``loss_fn(params, batch, rng) -> (loss, metrics)``: ``rng`` is the
+  step's dropout key, or None for a deterministic step.
+  ``param_spec_fn(path, shape) -> PartitionSpec`` places each parameter.
+  ``flops_fn(batch, seq)``: analytic FLOPs of one step (the ``train.mfu``
+  gauge's fallback numerator). ``after_update(params, metrics) ->
+  params``, where given, moves state that no gradient moves, after the
+  optimizer. ``causal``: the family's attention sees no later key (the
+  loop's host mirror of the tile skip needs to know)."""
+  loss_fn: Callable
+  param_spec_fn: Callable = spec_for_param
+  flops_fn: Optional[Callable] = None
+  after_update: Optional[Callable] = None
+  causal: bool = False
+
+
+def bert_objective(model, max_predictions=None, cfg=None):
+  """BERT's :class:`Objective`: :func:`pretrain_loss` with a dropout key,
+  :func:`lddl_tpu.models.spec_for_param`, and the analytic FLOPs of
+  ``cfg`` (where given)."""
+  flops_fn = None
+  if cfg is not None:
+    from ..models.flops import bert_pretrain_flops_per_step
+    flops_fn = functools.partial(bert_pretrain_flops_per_step, cfg,
+                                 max_predictions=max_predictions)
+
+  def loss_fn(params, batch, rng):
+    return pretrain_loss(model, params, batch, dropout_rng=rng,
+                         max_predictions=max_predictions)
+
+  return Objective(loss_fn=loss_fn, flops_fn=flops_fn)
+
+
+def param_shardings(mesh, abs_params, spec_fn=spec_for_param):
   """NamedSharding tree for a (possibly abstract) param tree."""
   flat = jax.tree_util.tree_flatten_with_path(abs_params)[0]
   tree = jax.tree_util.tree_structure(abs_params)
   shardings = [
       NamedSharding(mesh,
-                    spec_for_param([getattr(k, 'key', k) for k in path],
-                                   leaf.shape)) for path, leaf in flat
+                    spec_fn([getattr(k, 'key', k) for k in path],
+                            leaf.shape)) for path, leaf in flat
   ]
   return jax.tree_util.tree_unflatten(tree, shardings)
 
 
-def state_shardings(mesh, params, opt_state):
+def state_shardings(mesh, params, opt_state, spec_fn=spec_for_param):
   """NamedSharding trees for ``(params, opt_state)``, from tree paths and
   shapes alone (abstract and traced trees work). Params follow
-  :func:`lddl_tpu.models.spec_for_param`; every optimizer-state subtree
-  that mirrors the params tree (Adam's ``mu``/``nu``) inherits the
-  params' layout; the rest (step counters) is replicated."""
-  p_sh = param_shardings(mesh, params)
+  ``spec_fn`` (BERT's :func:`lddl_tpu.models.spec_for_param` by
+  default); every optimizer-state subtree that mirrors the params tree
+  (Adam's ``mu``/``nu``) inherits the params' layout; the rest (step
+  counters) is replicated."""
+  p_sh = param_shardings(mesh, params, spec_fn)
   p_def = jax.tree_util.tree_structure(params)
 
   def mirrors(node):
@@ -73,8 +116,13 @@ def init_params(model, mesh, rng, seq_len=128, batch=None):
     return model.init(rng, dummy['input_ids'], dummy['token_type_ids'],
                       dummy['attention_mask'])['params']
 
-  abs_params = jax.eval_shape(init_fn)
-  shardings = param_shardings(mesh, abs_params)
+  return init_sharded(init_fn, mesh)
+
+
+def init_sharded(init_fn, mesh, spec_fn=spec_for_param):
+  """``init_fn()``'s parameters, made by one jitted call straight into the
+  placement ``spec_fn`` gives them."""
+  shardings = param_shardings(mesh, jax.eval_shape(init_fn), spec_fn)
   return jax.jit(init_fn, out_shardings=shardings)()
 
 
@@ -223,17 +271,20 @@ def check_max_predictions(max_predictions, seq_len, masking,
         'positions silently drop their overflow MLM targets from the loss')
 
 
-def make_train_step(model, tx, mesh, max_predictions=None):
+def make_train_step(objective, tx, mesh, max_predictions=None):
   """Returns ``step(params, opt_state, rng, batch) ->
   (params, opt_state, metrics)``, jitted with donated state.
 
+  ``objective`` is the family's :class:`Objective`; a BERT model in its
+  place stands for :func:`bert_objective` of it (``max_predictions``
+  selects the masked-only MLM head, see :func:`pretrain_loss`).
   Batches arrive sharded ``P(('data','fsdp'), 'seq')`` (the loader's
   device pipeline does this); params carry their own shardings from
-  :func:`init_params`, so jit needs no in_shardings — placement is taken
+  :func:`init_sharded`, so jit needs no in_shardings — placement is taken
   from the arguments and GSPMD inserts every collective.
-  ``max_predictions`` selects the masked-only MLM head (see
-  :func:`pretrain_loss`).
   """
+  if not isinstance(objective, Objective):
+    objective = bert_objective(objective, max_predictions)
 
   @functools.partial(jax.jit, donate_argnums=(0, 1))
   def step(params, opt_state, rng, batch):
@@ -242,8 +293,7 @@ def make_train_step(model, tx, mesh, max_predictions=None):
           rng, opt_state[0].count if hasattr(opt_state[0], 'count') else 0)
 
     def loss_fn(p):
-      return pretrain_loss(model, p, batch, dropout_rng=rng,
-                           max_predictions=max_predictions)
+      return objective.loss_fn(p, batch, rng)
 
     (loss, metrics), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
     # Global gradient norm of the *raw* grads (pre-optimizer): one fused
@@ -255,12 +305,15 @@ def make_train_step(model, tx, mesh, max_predictions=None):
     with jax.named_scope('optimizer'):
       updates, opt_state = tx.update(grads, opt_state, params)
       params = optax.apply_updates(params, updates)
+      if objective.after_update is not None:
+        params = objective.after_update(params, metrics)
     # The state leaves the step laid out as it came in. Left to itself the
     # partitioner hands replicated-by-rule leaves (biases, norms) back
     # split over fsdp, which the next call of an AOT-compiled step rejects
     # (and a plain jit call silently recompiles for).
     params, opt_state = jax.lax.with_sharding_constraint(
-        (params, opt_state), state_shardings(mesh, params, opt_state))
+        (params, opt_state),
+        state_shardings(mesh, params, opt_state, objective.param_spec_fn))
     metrics['loss'] = loss
     return params, opt_state, metrics
 

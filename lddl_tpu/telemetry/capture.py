@@ -21,7 +21,9 @@ otherwise needs xprof for:
     (:data:`CLASSES`) and, independently, to a pass (:data:`PASSES`),
     from the ``op_name`` XLA keeps for it: flax's module path plus the
     ``jax.named_scope`` s of what is no module (``loss``, ``optimizer``,
-    ``grad_norm``, ``gelu``, ``residual``, ...).
+    ``grad_norm``, ``gelu``, ``residual``, ...). Beside the classes, the
+    busy time under each of :data:`SCOPES` (a part of one class that a
+    reader needs alone: the experts' grouped products).
 
 Two stages, so that the second can be checked on a small recorded file:
 :func:`extract` reads the trace into plain lists, :func:`summarize`
@@ -70,22 +72,34 @@ UNATTRIBUTED = 'unattributed'
 # Module classes, first match wins, tried on the op_name's path elements
 # with their jvp()/transpose()/jit() wrappers taken off. Dropout first:
 # its draws sit under whichever module drew them (attention/Dropout_0).
-CLASSES = ('attention', 'ffn', 'norms', 'dropout', 'embed', 'head_loss',
-           'optimizer', 'scan_carry', 'unscoped')
+CLASSES = ('attention', 'ffn', 'moe', 'conv', 'norms', 'dropout', 'embed',
+           'head_loss', 'optimizer', 'scan_carry', 'unscoped')
+# The classes only a decoder's blocks have (models/lfm2.py).
+DECODER_CLASSES = ('moe', 'conv')
 _DROPOUT = re.compile(
     r'[Dd]ropout|threefry|_bernoulli|random_bits|rng_bit_generator')
 _CLASS_RULES = (
     ('optimizer', re.compile(r'^(optimizer|grad_norm)$')),
     ('head_loss', re.compile(r'^(loss$|mlm_|nsp|pool)')),
     ('attention', re.compile(r'^attention$')),
-    ('ffn', re.compile(r'^(intermediate|output|gelu)$')),
-    ('norms', re.compile(r'^(attention_norm|output_norm|residual)$')),
+    # Sparse experts: router, dispatch, grouped products and combine
+    # (ops/moe.py under models/lfm2.py's module ``moe``).
+    ('moe', re.compile(r'^moe$')),
+    # The short-convolution operator with its two projections.
+    ('conv', re.compile(r'^conv$')),
+    ('ffn', re.compile(r'^(intermediate|output|gelu|ffn)$')),
+    ('norms', re.compile(r'^(attention_norm|output_norm|residual|'
+                         r'operator_norm|ffn_norm|final_norm)$')),
     ('embed', re.compile(r'^(embed$|embed_norm$|\w+_embeddings)')),
 )
 # The layer scan's own traffic (stacking what the backward pass needs,
 # slicing the stacked weights, summing stacked gradients): ops directly
 # under the encoder's while body, inside no layer module.
-_SCAN_CARRY = re.compile(r'(^|/)encoder(/while/(body|cond))?/[^/]*$')
+_SCAN_CARRY = re.compile(
+    r'(^|/)(encoder|decoder)(/while/(body|cond))?/[^/]*$')
+# Scopes counted on their own, each by a path element of that name: the
+# experts' grouped products and the gate between them (ops/moe.py).
+SCOPES = ('experts',)
 PASSES = ('forward', 'backward', 'recompute', 'update')
 _WRAPPER = re.compile(r'^\w+\((.*)\)$')
 
@@ -437,6 +451,10 @@ def _summarize_device(device, main, feed, pauses, enqueues):
   step_starts = [s for s, _ in steps]
   by_class = dict.fromkeys(CLASSES, 0)
   by_pass = dict.fromkeys(PASSES, 0)
+  by_scope = dict.fromkeys(SCOPES, 0)
+  in_scope = [[scope for scope in SCOPES
+               if f'/{scope}/' in f'/{op_name}'] for _, _, op_name in
+              device['names']]
   per_op = collections.Counter()
   busy = 0
   end = steps[0][0]
@@ -453,6 +471,8 @@ def _summarize_device(device, main, feed, pauses, enqueues):
     if hi > lo:
       by_class[cls] += hi - lo
       by_pass[pass_] += hi - lo
+      for scope in in_scope[i]:
+        by_scope[scope] += hi - lo
       per_op[(name, cls, pass_)] += hi - lo
       busy += hi - lo
       end = hi
@@ -501,6 +521,7 @@ def _summarize_device(device, main, feed, pauses, enqueues):
       'plane': device['plane'], 'steps': len(steps),
       'step_programs_ns': sum(e - s for s, e in steps),
       'busy_ns': busy, 'classes': by_class, 'passes': by_pass,
+      'scopes': by_scope,
       'device_clock_shift_ns': shift,
       'top_ops': [[n, cls, pass_, ns] for (n, cls, pass_), ns in
                   per_op.most_common(10)],

@@ -1,4 +1,10 @@
-"""BERT pretraining loop: mesh-sharded steps + checkpoint/resume.
+"""Pretraining loop: mesh-sharded steps + checkpoint/resume.
+
+One loop for every model family: BERT (MLM + NSP on pair or packed
+shards) and LFM2-MoE (a causal decoder on packed shards,
+:mod:`lddl_tpu.models.lfm2`); ``TrainLoop.build`` builds the one its
+model configuration names, through the step's
+:class:`~lddl_tpu.parallel.train.Objective`.
 
 The reference delegates training to external consumers and supports their
 checkpoints only through ``start_epoch``/``samples_seen`` loader replay
@@ -21,7 +27,6 @@ CLI: ``python -m lddl_tpu.cli pretrain_bert --path <balanced> ...``.
 
 import argparse
 import dataclasses
-import functools
 import json
 import logging
 import math
@@ -151,6 +156,9 @@ class TrainLoop:
   # (per_rank_batch, seq_len) -> analytic FLOPs of one train step; set by
   # build() so run() can report MFU without re-deriving the model config.
   flops_fn: object = None
+  # The model's attention is causal (the host mirror of the tile skip
+  # counts the tiles above the diagonal as skipped).
+  causal: bool = False
   dp_rank: int = 0
   dp_world: int = 1
   # Why the last run() stopped early (preemption / membership event), or
@@ -173,14 +181,23 @@ class TrainLoop:
 
     from ..loader import (get_bert_pretrain_data_loader,
                           get_packed_pretrain_data_loader)
-    from ..models import BertForPretraining
+    from ..models import BertForPretraining, lfm2
     from ..parallel import make_train_step
-    from ..parallel.train import init_params, state_shardings
+    from ..parallel.train import bert_objective, init_params, state_shardings
 
-    model = BertForPretraining(model_cfg, mesh=mesh)
+    causal = isinstance(model_cfg, lfm2.Lfm2Config)
     schedule = optax.warmup_cosine_decay_schedule(
         0.0, learning_rate, warmup_steps, max(total_steps, warmup_steps + 1))
-    tx = optax.adamw(schedule, weight_decay=weight_decay)
+    if causal:
+      if data_format != 'packed':
+        raise ValueError("a causal decoder trains on data_format='packed'")
+      model, objective = lfm2.build_objective(model_cfg, mesh)
+      tx = optax.adamw(schedule, weight_decay=weight_decay,
+                       mask=lfm2.decay_mask)
+    else:
+      model = BertForPretraining(model_cfg, mesh=mesh)
+      objective = bert_objective(model, max_predictions, model_cfg)
+      tx = optax.adamw(schedule, weight_decay=weight_decay)
     # Overridable for elastic resume: a fleet reformed at a different
     # world size passes its new coordinates explicitly (and the file-
     # backend multi-rank tests run several dp ranks inside independent
@@ -196,8 +213,9 @@ class TrainLoop:
       loader = None
     elif data_format == 'packed':
       # Long-context document-packed shards (preprocess_packed_pretrain):
-      # always dynamic masking, no NSP pairs.
-      if masking != 'dynamic':
+      # always dynamic masking, no NSP pairs; a decoder's next-token
+      # batches where the model is causal.
+      if masking != 'dynamic' and not causal:
         raise ValueError("data_format='packed' supports masking='dynamic' "
                          'only (no stored masks in packed shards)')
       loader = get_packed_pretrain_data_loader(
@@ -211,6 +229,7 @@ class TrainLoop:
           base_seed=seed,
           samples_seen=samples_seen,
           block_diagonal=block_diagonal,
+          causal=causal,
           **(loader_kwargs or {}))
     else:
       loader = get_bert_pretrain_data_loader(
@@ -225,8 +244,11 @@ class TrainLoop:
           base_seed=seed,
           samples_seen=samples_seen,
           **(loader_kwargs or {}))
-    params = init_params(model, mesh, jax.random.key(seed),
-                         seq_len=min(128, max_seq_length))
+    if causal:
+      params = lfm2.init_params(model_cfg, mesh, jax.random.key(seed))
+    else:
+      params = init_params(model, mesh, jax.random.key(seed),
+                           seq_len=min(128, max_seq_length))
     # Every optimizer-state leaf gets an explicit mesh placement (the
     # same one the step keeps it in): a layout jit happened to pick
     # would be reproduced faithfully by a checkpoint restore and then
@@ -234,22 +256,20 @@ class TrainLoop:
     opt_state = jax.jit(
         tx.init,
         out_shardings=state_shardings(
-            mesh, params, jax.eval_shape(tx.init, params))[1])(params)
+            mesh, params, jax.eval_shape(tx.init, params),
+            objective.param_spec_fn)[1])(params)
     if max_predictions is not None:
       from ..parallel.train import check_max_predictions
       check_max_predictions(
           max_predictions, max_seq_length, masking,
           mlm_probability=(loader_kwargs or {}).get('mlm_probability', 0.15))
-    step_fn = make_train_step(model, tx, mesh,
-                              max_predictions=max_predictions)
+    step_fn = make_train_step(objective, tx, mesh)
     global_batch = batch_size_per_rank * dp_world
-    from ..models.flops import bert_pretrain_flops_per_step
-    flops_fn = functools.partial(bert_pretrain_flops_per_step, model_cfg,
-                                 max_predictions=max_predictions)
     return cls(model=model, tx=tx, mesh=mesh, loader=loader, params=params,
                opt_state=opt_state, rng=jax.random.key(seed + 1),
                step_fn=step_fn, samples_seen=samples_seen,
-               step=samples_seen // global_batch, flops_fn=flops_fn,
+               step=samples_seen // global_batch,
+               flops_fn=objective.flops_fn, causal=objective.causal,
                dp_rank=dp_rank, dp_world=dp_world)
 
   # ---- checkpointing ----
@@ -634,6 +654,11 @@ class TrainLoop:
           if launched.tiles is not None:
             tiles_total_c.add(launched.tiles[0])
             tiles_skipped_c.add(launched.tiles[1])
+          if 'expert_load' in metrics:
+            # Materialized with the loss read above: a host copy, no sync.
+            from ..ops.moe import observe_load
+            observe_load(tele, step_no, metrics['expert_load'],
+                         self.model.cfg)
         if log_every and self.step % log_every == 0:
           dt = time.perf_counter() - t_log
           t_log = time.perf_counter()
@@ -758,7 +783,7 @@ class TrainLoop:
               # What the observers report of this step's batch is taken
               # now: the next pull deletes the batch.
               launched.note_batch(batch, self.step_fn, self.flops_fn,
-                                  peak_total)
+                                  peak_total, self.causal)
             next_step += 1
             previous, in_flight = in_flight, launched
             if previous is not None:
@@ -812,7 +837,7 @@ class _Launched:
   flops: object = None   # the train.mfu numerator, or None
   tiles: object = None   # (total, skipped) attention tiles of a packed batch
 
-  def note_batch(self, batch, step_fn, flops_fn, peak_total):
+  def note_batch(self, batch, step_fn, flops_fn, peak_total, causal=False):
     if peak_total:
       # Prefer XLA's own cost model (captured at compile time by the
       # step cache) over the analytic estimate: the measured numerator
@@ -830,7 +855,8 @@ class _Launched:
       import numpy as np
 
       from ..ops.flash_attention import count_skippable_tiles
-      self.tiles = count_skippable_tiles(np.asarray(batch['segment_ids']))
+      self.tiles = count_skippable_tiles(np.asarray(batch['segment_ids']),
+                                         causal=causal)
 
 
 def _peak_flops_total():
@@ -890,13 +916,45 @@ MODEL_SIZES = {
 }
 
 
+def model_config(name, vocab_size, max_seq_length, attention, remat):
+  """The model configuration ``--model name`` builds, over a vocabulary of
+  ``vocab_size`` rows: a BERT size of :data:`MODEL_SIZES`, the LFM2 preset
+  of :data:`lddl_tpu.models.lfm2.PRESETS`, or an LFM2-MoE configuration
+  file in the source's keys (``*.json``,
+  :func:`lddl_tpu.models.lfm2.config_from_hf`)."""
+  from ..models import BertConfig, lfm2
+  if name in lfm2.PRESETS:
+    return lfm2.Lfm2Config(vocab_size=vocab_size, attention_impl=attention,
+                           remat=remat, **lfm2.PRESETS[name])
+  if name.endswith('.json'):
+    with open(name) as f:
+      return lfm2.config_from_hf(json.load(f), vocab_size=vocab_size,
+                                 attention_impl=attention, remat=remat)
+  if name not in MODEL_SIZES:
+    raise ValueError(f'--model {name!r}: a BERT size of '
+                     f'{sorted(MODEL_SIZES)}, an LFM2 preset of '
+                     f'{sorted(lfm2.PRESETS)} or an LFM2 *.json file')
+  return BertConfig(
+      vocab_size=vocab_size,
+      max_position_embeddings=max(max_seq_length, 512),
+      attention_impl=attention,
+      remat=remat,
+      **MODEL_SIZES[name])
+
+
 def attach_args(parser):
+  from ..models.lfm2 import PRESETS
   from ..ops.attention import ATTENTION_IMPLS
   parser.add_argument('--path', required=True, help='balanced shard dir')
   parser.add_argument('--vocab-file', default=None)
   parser.add_argument('--tokenizer', default=None)
-  parser.add_argument('--model', choices=sorted(MODEL_SIZES),
-                      default='base')
+  parser.add_argument('--model', default='base',
+                      help=f'a BERT size ({", ".join(sorted(MODEL_SIZES))}), '
+                      f'or an LFM2-MoE decoder: a preset '
+                      f'({", ".join(sorted(PRESETS))}) or a configuration '
+                      'file in its source\'s keys (*.json); a decoder '
+                      'trains on a causal next-token loss and needs '
+                      '--data-format packed')
   parser.add_argument('--attention', choices=ATTENTION_IMPLS,
                       default='dense')
   parser.add_argument('--remat', action='store_true',
@@ -970,7 +1028,6 @@ def main(args=None):
 
   from ..comm import get_backend
   from ..core.compile_cache import use_compile_cache
-  from ..models import BertConfig
   from ..parallel import make_mesh, mesh_summary
   from ..tokenization.wordpiece import load_bert_tokenizer
 
@@ -985,12 +1042,8 @@ def main(args=None):
   tokenizer = load_bert_tokenizer(
       vocab_file=args.vocab_file, hub_name=args.tokenizer, backend='hf')
   vocab = ((tokenizer.vocab_size + 63) // 64) * 64
-  cfg = BertConfig(
-      vocab_size=vocab,
-      max_position_embeddings=max(args.max_seq_length, 512),
-      attention_impl=args.attention,
-      remat=args.remat,
-      **MODEL_SIZES[args.model])
+  cfg = model_config(args.model, vocab, args.max_seq_length, args.attention,
+                     args.remat)
   mesh = make_mesh(data=args.dp, fsdp=args.fsdp, tensor=args.tp,
                    seq=args.sp)
   print(f'backend={jax.default_backend()} '

@@ -286,7 +286,10 @@ def test_recorded_steps_totals(recorded, recorded_device):
   assert 1_000_000 < d['device_clock_shift_ns'] < 2_000_000
 
 
-@pytest.mark.parametrize('module_class', capture.CLASSES)
+# The recording is BERT's: it holds no experts and no convolution
+# (tests/test_lfm2_family.py finds those classes in a decoder's step).
+@pytest.mark.parametrize('module_class', [
+    c for c in capture.CLASSES if c not in capture.DECODER_CLASSES])
 def test_recorded_steps_by_class(recorded, recorded_device, module_class):
   got = recorded_device['classes'][module_class]
   assert got == recorded['expected']['classes'][module_class]
