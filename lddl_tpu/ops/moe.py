@@ -25,6 +25,16 @@ to no group: the kernel visits none of their tiles and leaves them as the
 memory held them, so they are selected away on both sides of the
 products, forward and backward.
 
+Rows move to and from the sorted buffer by gathers alone, forward and
+backward. The stable sort's ``order`` (the assignment at each sorted row)
+and its inverse ``inv`` (the sorted row of each assignment, from the
+groups' offsets and a running count per expert) are one permutation and
+its transpose: the dispatch gathers token rows by ``order`` and its
+backward sums the sorted rows' gradients back by ``inv``; the combine
+sums each token's held rows by ``inv`` and its backward gathers by
+``order``. No ``[rows, hidden]`` array is scattered: on a v5e a
+scatter-add of 32,768 rows into 8,192 of 2,048 took 2.5 ms (PERF.md).
+
 On a v5e the grouped SwiGLU triple of a chunk of 32,768 rows of which
 8,192 are held (hidden 2,048, expert width 1,792) took 5.23 ms forward
 and backward with ``gmm`` at tiles of (512, 1024, 1024), 7.11 ms with
@@ -34,7 +44,8 @@ tiles; with every row held 14.4 / 19.6 ms (PERF.md, PR 41).
 Names for the capture summary (``telemetry/capture.py``): the layer is a
 flax module named ``moe``; the grouped products and the gate between
 them sit under the scope ``experts``, which the summary also counts on
-its own (``moe_experts_roofline``).
+its own (``moe_experts_roofline``), and the moves of rows under
+``dispatch`` and ``combine``.
 """
 
 import collections
@@ -106,6 +117,71 @@ def grouped_swiglu(x, w1, w3, w2, group_sizes):
     return _grouped(h, w2, group_sizes)
 
 
+# Both directions of the data movement are gathers: ``order[p]`` is the
+# assignment at sorted row ``p`` and ``inv[t, j]`` the sorted row of
+# assignment ``t * k + j``, so a gather by one is the transpose of a gather
+# by the other. JAX would transpose each gather into a scatter-add of
+# [rows, hidden], so the two moves below carry their own backward passes.
+# Each token's k rows are gathered as k arrays of [t, d]: one [t, k, d]
+# array would be laid out with k padded to the tile's 8 rows.
+
+
+@jax.custom_vjp
+def _dispatch(u, held, order, inv):
+  """Sorted row ``p`` is token ``order[p] // k``'s row where that
+  assignment is held, else 0."""
+  kept = held.reshape(-1)[order]
+  return jnp.where(kept[:, None], u[order // held.shape[1]], 0)
+
+
+def _dispatch_fwd(u, held, order, inv):
+  return _dispatch(u, held, order, inv), (held, order, inv)
+
+
+def _dispatch_bwd(res, dx):
+  held, order, inv = res
+  # A select, not a product: the rows past the groups (whatever the kernels
+  # left in them) are zeroed before any token gathers them.
+  dx = jnp.where(held.reshape(-1)[order][:, None], dx, 0)
+  du = sum(dx[inv[:, j]].astype(jnp.float32) for j in range(inv.shape[1]))
+  return du.astype(dx.dtype), None, None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(y, weights, held, order, inv):
+  """Each token's held assignments' sorted rows of ``y``, weighted and
+  summed in float32. A select, not a product, leaves out the rows past
+  the groups, which the assignments not held point at."""
+  out = 0.0
+  for j in range(held.shape[1]):
+    rows = y[inv[:, j]].astype(jnp.float32) * weights[:, j, None]
+    out = out + jnp.where(held[:, j, None], rows, 0.0)
+  return out
+
+
+def _combine_fwd(y, weights, held, order, inv):
+  return _combine(y, weights, held, order, inv), (y, weights, held, order,
+                                                   inv)
+
+
+def _combine_bwd(res, dout):
+  y, weights, held, order, inv = res
+  kept = held.reshape(-1)[order]
+  rows = dout[order // held.shape[1]]
+  dy = jnp.where(kept[:, None], rows * weights.reshape(-1)[order][:, None],
+                 0.0).astype(y.dtype)
+  # Each sorted row's weight gradient, then moved to its assignment: a
+  # gather of [t * k] scalars where a gather of rows would be.
+  dw = jnp.sum(rows * y.astype(jnp.float32), axis=-1)
+  return dy, jnp.where(held, dw[inv], 0.0), None, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
 def held_experts_mix(u, experts, weights, real, w1, w3, w2, first):
   """This chip's part of the experts' sum for tokens ``u`` [t, d]: the
   assignments to experts ``[first, first + len(w1))`` are sorted by
@@ -114,23 +190,23 @@ def held_experts_mix(u, experts, weights, real, w1, w3, w2, first):
   t, k = experts.shape
   count = w1.shape[0]
   with jax.named_scope('dispatch'):
-    local = experts.reshape(-1) - first
-    held = (local >= 0) & (local < count) & jnp.repeat(real, k)
-    key = jnp.where(held, local, count)  # not held: after every group
+    local = experts - first
+    held = (local >= 0) & (local < count) & real[:, None]
+    key = jnp.where(held, local, count).reshape(-1)  # not held: last
     order = jnp.argsort(key, stable=True)
-    group_sizes = jnp.bincount(key, length=count + 1)[:count].astype(
-        jnp.int32)
-    token = order // k
-    kept = jnp.take(held, order)
-    # A select, not a product: its backward sends nothing of the rows past
-    # the groups (whatever the kernels left in them) back to the tokens.
-    x = jnp.where(kept[:, None], jnp.take(u, token, axis=0), 0)
-  y = grouped_swiglu(x, w1, w3, w2, group_sizes)
+    # The stable sort's inverse with no second sort: an assignment's row is
+    # its key's offset plus the assignments of that key before it.
+    hits = key[None] == jnp.arange(count + 1, dtype=key.dtype)[:, None]
+    seen = jnp.cumsum(hits, axis=1, dtype=jnp.int32)  # [count + 1, t * k]
+    sizes = seen[:, -1]
+    offsets = jnp.cumsum(sizes) - sizes
+    inv = jnp.sum(jnp.where(hits, seen - 1 + offsets[:, None], 0), axis=0)
+    inv = inv.reshape(t, k)
+    x = _dispatch(u, held, order, inv)
+  y = grouped_swiglu(x, w1, w3, w2, sizes[:count])
   with jax.named_scope('combine'):
-    w = jnp.where(kept, jnp.take(weights.reshape(-1), order), 0.0)
-    y = jnp.where(kept[:, None], y.astype(jnp.float32), 0.0) * w[:, None]
-    out = jnp.zeros((t, u.shape[-1]), jnp.float32).at[token].add(y)
-  return out.astype(u.dtype), group_sizes
+    out = _combine(y, weights, held, order, inv)
+  return out.astype(u.dtype), sizes[:count]
 
 
 _ROUTED = collections.deque(maxlen=4096)
